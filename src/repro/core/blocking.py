@@ -13,9 +13,11 @@ On TPU the memory hierarchy is HBM -> VMEM -> vector registers, with a
 software-managed VMEM (~16 MiB per core on v5e) feeding a 128x128 MXU.  The
 analogous derivation (the "analytical modeling is enough" route of Low et
 al., which the paper cites as an alternative to its empirical search) picks
-Pallas block shapes ``(bm, bk, bn)`` such that the A-block, B-block and fp32
-accumulator — double-buffered for the HBM->VMEM pipeline — fit a VMEM
-budget, with MXU-aligned dimensions.
+Pallas block shapes ``(bm, bk, bn)`` such that the A-block, B-block, fp32
+accumulator and output block — A, B and the output double-buffered for the
+HBM<->VMEM pipeline, plus the compiler's own A operand copy — fit a VMEM
+budget below the compiler's default scoped-VMEM limit, with MXU-aligned
+dimensions.
 
 Both derivations live here:
 
@@ -166,7 +168,9 @@ class BlockConfig:
 
     ``bm x bk`` A-blocks and ``bk x bn`` B-blocks are staged HBM->VMEM
     (double buffered by the Pallas pipeline); a ``bm x bn`` fp32 accumulator
-    persists in VMEM across the K grid dimension.
+    persists in VMEM across the K grid dimension, and the ``bm x bn``
+    output block (operand dtype, the kernels' default ``out_dtype``) is
+    written back through a double-buffered pipeline slot.
     """
 
     bm: int
@@ -179,13 +183,19 @@ class BlockConfig:
         """Working set: ``double_buffer=False`` is the VMEM-lean k-streaming
         kernel (``gemm_pallas_lean``), which stages one A/B block at a time
         instead of the pipelined pair — half the input footprint, so larger
-        (bm, bn) panels fit the same budget."""
+        (bm, bn) panels fit the same budget.
+
+        Both kernels also hold what the TPU compiler adds on its own: the
+        output block's two pipeline buffers, and one more A block (the
+        MXU operand copy; compiling both kernels for a v5e measured
+        ``bm*bk*dtype_bytes`` plus under 0.4 MiB above the rest)."""
 
         mult = 2 if double_buffer else 1
         a = self.bm * self.bk * self.dtype_bytes
         b = self.bk * self.bn * self.dtype_bytes
         c = self.bm * self.bn * self.acc_bytes
-        return mult * (a + b) + c
+        out = 2 * self.bm * self.bn * self.dtype_bytes
+        return mult * (a + b) + a + c + out
 
     def fits(self, spec: TpuCoreSpec = TPU_V5E, *, double_buffer: bool = True) -> bool:
         return self.vmem_bytes(double_buffer) <= spec.vmem_bytes * spec.vmem_fill
@@ -312,14 +322,17 @@ def derive_block_config(
     while bm >= align:
         bn = pn
         while bn >= align:
-            # Largest aligned bk that fits the budget for this (bm, bn).
-            acc = bm * bn * 4
-            # A+B staging per unit bk: pipelined pair or one lean buffer.
-            per_k = (2 if double_buffer else 1) * (bm + bn) * dtype_bytes
-            if acc >= budget:
+            # Largest aligned bk that fits the budget for this (bm, bn)
+            # under BlockConfig.vmem_bytes: the fp32 accumulator and the
+            # double-buffered output block are fixed; A+B staging
+            # (pipelined pair or one lean buffer) and the compiler's A
+            # operand copy grow with bk.
+            fixed = bm * bn * (4 + 2 * dtype_bytes)
+            per_k = ((2 if double_buffer else 1) * (bm + bn) + bm) * dtype_bytes
+            if fixed >= budget:
                 bn //= 2
                 continue
-            bk = _round_down(min(pk, (budget - acc) // per_k), align)
+            bk = _round_down(min(pk, (budget - fixed) // per_k), align)
             cfg = BlockConfig(bm=bm, bk=bk, bn=bn, dtype_bytes=dtype_bytes)
             if cfg.fits(spec, double_buffer=double_buffer):
                 if best is None or cfg.arithmetic_intensity() > best.arithmetic_intensity():

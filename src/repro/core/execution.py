@@ -326,10 +326,7 @@ def validate_registry() -> list[str]:
 def on_tpu() -> bool:
     """The auto-probe: is the default JAX backend a TPU?"""
 
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def resolve_backend(name: str) -> str:
@@ -352,9 +349,17 @@ def resolve_backend(name: str) -> str:
 
 
 def resolve_paged_attn_backend(name: str) -> str:
-    """Collapse a paged-attention ``"auto"``; validate the op family."""
+    """Collapse a paged-attention ``"auto"``; validate the op family.
+
+    Under an active context whose tree runs the ``"xla"`` GEMM, ``"auto"``
+    is the XLA gather route too, so an xla context is the all-XLA
+    reference for a whole decode step; otherwise it probes the platform.
+    """
 
     if name == "auto":
+        ctx = current_context()
+        if ctx is not None and ctx.backend() == "xla":
+            return "paged_attn_xla"
         return "paged_attn_pallas" if on_tpu() else "paged_attn_xla"
     if backend_op(name) != "paged_attn":
         raise ValueError(
@@ -647,23 +652,19 @@ def current_context() -> Optional[ExecutionContext]:
 # ---------------------------------------------------------------------------
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, auto=frozenset()):
-    """``shard_map`` with replication checking off, across jax versions.
+def unchecked_shard_map(f, *, mesh, in_specs, out_specs, auto=frozenset()):
+    """``jax.shard_map`` with the varying-manual-axes check (``check_vma``) off.
 
-    The replication-check kwarg was renamed (``check_rep`` →
-    ``check_vma``); class-sharded bodies carry per-shard control flow the
-    checker cannot see through, so it is always disabled here.
+    Class-sharded bodies carry per-shard control flow the checker cannot
+    see through, so it is always disabled here.  ``auto`` names the mesh
+    axes left to GSPMD; the body is manual over every other axis.
     """
 
-    from jax.experimental.shard_map import shard_map
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if auto:
-        kwargs["auto"] = frozenset(auto)
-    try:
-        return shard_map(f, check_rep=False, **kwargs)
-    except TypeError:  # newer jax renamed the kwarg
-        return shard_map(f, check_vma=False, **kwargs)
+    manual = frozenset(mesh.axis_names) - frozenset(auto)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=manual, check_vma=False,
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -745,11 +746,13 @@ def class_sharded(
     The shard_map is **fully manual** by default: devices sharing a pod
     coordinate replicate that pod's program (exact, and free when the
     non-pod axes have extent 1 — the host realization).  Passing the
-    non-pod axes via ``auto`` would let GSPMD keep partitioning the
-    fine-grain Loop-4 math across them, but current XLA's partitioner
-    CHECK-fails on ``lax.scan`` inside a ``switch`` branch under a manual
-    subgroup (verified on 0.4.x), and every model in the zoo scans over
-    layers — so ``auto`` is opt-in until the partitioner supports it.
+    non-pod axes via ``auto`` (the body is then manual only over the
+    others, ``jax.shard_map``'s ``axis_names``) would let GSPMD keep
+    partitioning the fine-grain Loop-4 math across them.  XLA's
+    partitioner CHECK-failed on ``lax.scan`` inside a ``switch`` branch
+    under a manual subgroup with jax 0.4.x; with jax 0.9 a toy
+    scan-in-switch compiles on the CPU, but no zoo model has been tried
+    that way — so ``auto`` stays opt-in.
     """
 
     contexts = list(contexts)
@@ -838,7 +841,7 @@ def class_sharded(
 
     if pod_class_spec is None:
         pod_class_spec = P(axis)
-    smap = compat_shard_map(
+    smap = unchecked_shard_map(
         body,
         mesh=mesh,
         in_specs=(pod_class_spec,) + tuple(in_specs),
@@ -902,7 +905,6 @@ __all__ = [
     "backend_vocabulary",
     "validate_registry",
     "class_sharded",
-    "compat_shard_map",
     "context_for_tree",
     "current_context",
     "default_context",
@@ -916,4 +918,5 @@ __all__ = [
     "resolve_paged_attn_backend",
     "tuned_block_config",
     "tuned_kernel_backend",
+    "unchecked_shard_map",
 ]

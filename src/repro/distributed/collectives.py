@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core.execution import compat_shard_map
+from repro.core.execution import unchecked_shard_map
 
 
 def quantize_int8(x: jnp.ndarray):
@@ -67,9 +67,7 @@ def compressed_crosspod_mean(grads, err_tree, mesh: Mesh, *, axis: str = "pod"):
 
     def one(g, e):
         gspec = P(*([None] * g.ndim))
-        # compat_shard_map handles the check_rep→check_vma kwarg rename
-        # (the bare check_vma call was a TypeError on jax 0.4.x).
-        fn = compat_shard_map(
+        fn = unchecked_shard_map(
             functools.partial(_crosspod_mean_one, axis=axis),
             mesh=mesh,
             in_specs=(gspec, gspec),

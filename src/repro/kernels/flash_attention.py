@@ -23,14 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -125,15 +118,6 @@ def flash_attention(
         sk=sk,
         q_offset=sk - sq,  # causal alignment when the query is a suffix
     )
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")
-            )
-        except Exception:  # pragma: no cover
-            pass
-
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -145,12 +129,14 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, psq, d), q.dtype),
         scratch_shapes=[
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
     )(qb_, kb_, vb_)
 
     out = out.reshape(b, h, psq, d).transpose(0, 2, 1, 3)

@@ -30,15 +30,17 @@ blocking):
     little-VMEM classes: a 2-D grid over output tiles; K is streamed
     *inside* the kernel body with single-buffered manual DMA
     (``make_async_copy``) while one fp32 accumulator tile stays resident
-    (working set ``(A+B) + acc``).  Trading the double-buffering depth for
+    (working set ``(A+B) + acc``, plus what both kernels share: the
+    output block's pipeline buffers and the compiler's A operand copy —
+    ``BlockConfig.vmem_bytes``).  Trading the double-buffering depth for
     footprint lets a class like ``TPU_LITTLE`` run the full shared (bm, bn)
     panel instead of shrinking ``bm`` — at the cost of not overlapping the
     HBM streams with the MXU (the tuning cost model charges exactly that).
 
 The per-class ``BlockConfig`` (control tree) chooses (bm, bk, bn) exactly
-like the paper chooses (m_c, k_c) per core type.  On this CPU-only
-container the kernels are validated with ``interpret=True``; on TPU the
-same code JITs through Mosaic.
+like the paper chooses (m_c, k_c) per core type.  On the CPU the kernels
+are validated with ``interpret=True``; on TPU the same code JITs through
+Mosaic.
 """
 
 from __future__ import annotations
@@ -49,14 +51,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific helpers are importable on CPU; guard for API drift.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blocking import BlockConfig, _round_up, pad_to_blocks
 
@@ -95,7 +90,7 @@ def resolve_block_config(
 
 
 # ---------------------------------------------------------------------------
-# Shared pallas_call scaffolding (validation, padding, compiler params)
+# Shared pallas_call scaffolding (validation, padding)
 # ---------------------------------------------------------------------------
 
 
@@ -143,19 +138,6 @@ def _pad_operands(
     if (pk, pn) != (k, n):
         b = jnp.pad(b, ((0, pk - k), (0, pn - n)))
     return a, b, pm, pk, pn
-
-
-def _compiler_params(semantics: tuple[str, ...], interpret: bool) -> dict:
-    """``dimension_semantics`` for Mosaic; nothing in interpret mode."""
-
-    if pltpu is None or interpret:
-        return {}
-    try:
-        return {
-            "compiler_params": pltpu.CompilerParams(dimension_semantics=semantics)
-        }
-    except Exception:  # pragma: no cover - older API name
-        return {}
 
 
 # ---------------------------------------------------------------------------
@@ -206,12 +188,6 @@ def gemm_pallas(
     a, b, pm, pk, pn = _pad_operands(a, b, cfg)
     grid = (pm // cfg.bm, pn // cfg.bn, pk // cfg.bk)
 
-    scratch = (
-        [_VMEM((cfg.bm, cfg.bn), jnp.float32)]
-        if _VMEM is not None
-        else [pl.MemorySpace.ANY((cfg.bm, cfg.bn), jnp.float32)]  # pragma: no cover
-    )
-
     out = pl.pallas_call(
         _gemm_kernel,
         grid=grid,
@@ -221,9 +197,11 @@ def gemm_pallas(
         ],
         out_specs=pl.BlockSpec((cfg.bm, cfg.bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), out_dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((cfg.bm, cfg.bn), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(("parallel", "parallel", "arbitrary"), interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(a, b)
     return out[:m, :n]
 
@@ -288,8 +266,6 @@ def gemm_pallas_lean(
     pipelined default.
     """
 
-    if pltpu is None:  # pragma: no cover - non-TPU pallas builds
-        raise RuntimeError("gemm_pallas_lean needs jax.experimental.pallas.tpu")
     _check_operands(a, b)
     m, k = a.shape
     _, n = b.shape
@@ -305,8 +281,8 @@ def gemm_pallas_lean(
         _gemm_lean_kernel(cfg.bm, cfg.bk, cfg.bn, pk // cfg.bk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((cfg.bm, cfg.bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), out_dtype),
@@ -318,7 +294,9 @@ def gemm_pallas_lean(
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-        **_compiler_params(("parallel", "parallel"), interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
     )(a, b)
     return out[:m, :n]
 

@@ -42,14 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -212,28 +205,22 @@ def paged_attention_pallas(q, pages_k, pages_v, page_table, pos, *,
             (1, 1, g, d), lambda bb, h, j, t, pp: (bb, h, 0, 0)
         ),
         scratch_shapes=[
-            _VMEM((g, 1), jnp.float32),
-            _VMEM((g, 1), jnp.float32),
-            _VMEM((g, d), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_kernel, scale=1.0 / np.sqrt(d), ps=ps, s_cache=s_cache
     )
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        except Exception:  # pragma: no cover
-            pass
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(table, pos, q4, kt, vt)
     return out.reshape(b, hq, d)
 

@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.core.asymmetric import AsymmetricMesh, biglittle_classes
 from repro.distributed import sharding as SH
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model_zoo as Z
 
@@ -329,6 +330,7 @@ def main():
                     help="enable observability and write a metrics JSON "
                          "snapshot here")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.trace or args.metrics:
         from repro import observability as OBS

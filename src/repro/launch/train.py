@@ -21,6 +21,7 @@ from repro.configs import get_config
 from repro.core import execution
 from repro.core.asymmetric import AsymmetricMesh, DeviceClass, biglittle_classes
 from repro.distributed import sharding as SH
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -406,12 +406,7 @@ class ServingEngine:
         self.s_cache = TX.cache_len(cfg, self.seq_cap) if supported else self.seq_cap
         if self.paged:
             if page_size is None:
-                try:
-                    page_size = min(
-                        t.block.bm for t in asym.control_trees().values()
-                    )
-                except Exception:
-                    page_size = 16
+                page_size = min(t.block.bm for t in asym.control_trees().values())
             ps = divisor_page_size(self.s_cache, page_size)
             w = self.s_cache // ps
             # MoE capacity routing couples batch rows: pad lanes must own
@@ -965,12 +960,8 @@ class ServingEngine:
             return 0
         units = self._pod_active_before(active)
         t0 = time.perf_counter()
-        batch = {"tokens": self.tokens, "live": jnp.asarray(self._live)}
-        if self.pool is not None:
-            batch["page_table"] = jnp.asarray(self._step_table())
-        nxt, self.state = self._step(
-            self.params, batch, self.state, jnp.asarray(self._pos, jnp.int32)
-        )
+        batch, pos = self.step_inputs()
+        nxt, self.state = self._step(self.params, batch, self.state, pos)
         self.tokens = nxt
         toks = np.asarray(nxt)  # blocks: the step's wall time is real
         dt = time.perf_counter() - t0
@@ -1016,6 +1007,34 @@ class ServingEngine:
             if times is not None:
                 self.asym.observe_step(units, list(times))
         return n_active
+
+    def step_inputs(self) -> tuple[dict, jnp.ndarray]:
+        """``(batch, pos)`` of the next decode step: the resident tokens,
+        the lane-liveness mask, (paged) the page table, and the per-slot
+        positions."""
+
+        batch = {"tokens": self.tokens, "live": jnp.asarray(self._live)}
+        if self.pool is not None:
+            batch["page_table"] = jnp.asarray(self._step_table())
+        return batch, jnp.asarray(self._pos, jnp.int32)
+
+    def lower_step(self):
+        """The jitted decode step lowered at the next step's inputs (its
+        HLO and, once compiled, its memory analysis)."""
+
+        batch, pos = self.step_inputs()
+        return self._step.lower(self.params, batch, self.state, pos)
+
+    def step_logits(self) -> jnp.ndarray:
+        """``(n_slots, 1, vocab)`` logits of the next decode step, computed
+        by the engine's own step program without advancing or donating
+        the state."""
+
+        batch, pos = self.step_inputs()
+        core = self._core
+        return jax.jit(lambda p, b, s, q: core(p, b, s, q)[0])(
+            self.params, batch, self.state, pos
+        )
 
     def _pod_active_before(self, active_mask: np.ndarray) -> list[int]:
         act = active_mask.reshape(self.n_pods, self.c_max)

@@ -343,9 +343,9 @@ class TestTrainerMixedStep:
         return cfg, params, loss_fn, asym, batch, bw.layout
 
     def test_weighted_epilogue_equals_manual_split(self):
-        """The mixed step's gradients are bit-identical to splitting the
-        batch per pod in python and taking the mask-weighted sum — the
-        shard_map adds zero numerical deviation of its own."""
+        """The mixed step's gradients equal splitting the batch per pod in
+        python and taking the mask-weighted sum — the shard_map adds no
+        deviation of its own beyond float32 reduction order."""
 
         from repro.optim import adamw as O
         from repro.runtime.trainer import build_class_sharded_grad_step
@@ -367,8 +367,11 @@ class TestTrainerMixedStep:
         grad_fn = build_class_sharded_grad_step(loss_fn, asym, mesh)
         assert grad_fn.mixed
         _, _, g_mix = jax.jit(grad_fn)(params, batch)
+        # Not bit-equal: XLA orders the weighted psum's float32 reduction
+        # differently from the python sum (one ulp apart with jax 0.9).
         for a, b in zip(jax.tree.leaves(g_mix), jax.tree.leaves(manual)):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-9)
 
     def test_n_micro_accumulation_weighted_by_valid_tokens(self):
         """Regression: with n_micro > 1 a shard's tail micro-batches are

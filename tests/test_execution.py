@@ -198,6 +198,18 @@ class TestNoContextDefaults:
         assert src == "analytical"
         assert cfg == B.derive_block_config(256, 256, 256, dtype_bytes=4)
 
+    def test_paged_attention_auto_follows_xla_context(self, monkeypatch):
+        # As on a TPU: "auto" is the Pallas paged kernel, except under an
+        # xla context, which is the all-XLA reference for a whole step.
+        monkeypatch.setattr(X, "on_tpu", lambda: True)
+        assert X.resolve_paged_attn_backend("auto") == "paged_attn_pallas"
+        with _ctx(backend="pallas"):
+            assert X.resolve_paged_attn_backend("auto") == "paged_attn_pallas"
+        with _ctx(backend="xla"):
+            assert X.resolve_paged_attn_backend("auto") == "paged_attn_xla"
+            assert (X.resolve_paged_attn_backend("paged_attn_pallas")
+                    == "paged_attn_pallas")
+
 
 # ---------------------------------------------------------------------------
 # Per-class routing under a biglittle mesh
@@ -356,11 +368,12 @@ class TestTunedRouting:
         # A float32 call at the canonical shape of a bf16-keyed tree keeps
         # the tree's block *shapes* (shared bk intact), only re-labelling
         # the operand bytes — it must not silently re-derive per spec.
+        # (At 256^3 the float32 relabel fits little's VMEM.)
         monkeypatch.delenv(C.ENV_VAR, raising=False)
-        am = AsymmetricMesh(biglittle_classes(), tree_shape=(512, 512, 512))
+        am = AsymmetricMesh(biglittle_classes(), tree_shape=(256, 256, 256))
         trees = am.control_trees()
         little = am.execution_context("little")
-        cfg = little.block_config(512, 512, 512, "float32", 4)
+        cfg = little.block_config(256, 256, 256, "float32", 4)
         blk = trees["little"].block
         assert (cfg.bm, cfg.bk, cfg.bn) == (blk.bm, blk.bk, blk.bn)
         assert cfg.dtype_bytes == 4

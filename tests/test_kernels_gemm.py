@@ -89,10 +89,10 @@ def test_gemm_pallas_lean_single_buffer_fit_admits_bigger_panels():
 
     from repro.core.blocking import TPU_LITTLE
 
-    # (512, 1280, 1024) bf16: ~6.0 MiB single-buffered working set vs
-    # ~10.0 MiB double-buffered — lean-only inside little's 7.55 MiB
-    # budget, exactly the panel the control trees keep for little.
-    cfg = BlockConfig(bm=512, bk=1280, bn=1024, dtype_bytes=2)
+    # (512, 640, 1024) bf16: 6.5 MiB single-buffered working set vs
+    # 8.4 MiB double-buffered — lean-only inside little's 7.2 MiB budget,
+    # exactly the panel the control trees keep for little.
+    cfg = BlockConfig(bm=512, bk=640, bn=1024, dtype_bytes=2)
     assert not cfg.fits(TPU_LITTLE)
     assert cfg.fits(TPU_LITTLE, double_buffer=False)
     a, b = _rand((512, 1280), jnp.bfloat16), _rand((1280, 1024), jnp.bfloat16)

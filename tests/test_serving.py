@@ -239,6 +239,24 @@ class TestRouterAndSlots:
         assert np.array_equal(done[r1].tokens[:4], short)
         assert np.array_equal(done[r2].tokens[:9], long)
 
+    @pytest.mark.parametrize("paged", ["off", "on"])
+    def test_step_logits_are_the_next_steps_logits(self, zoo, paged):
+        """step_logits() evaluates the next decode step without advancing
+        the engine; its argmax is the token step() then emits per slot."""
+
+        cfg, eng = self._engine(zoo, asym=_single(), paged=paged)
+        for n in (4, 7):
+            eng.submit(RNG.integers(0, cfg.vocab, (n,), dtype=np.int32), 3)
+        eng.admit()
+        pos = eng.slot_pos.copy()
+        logits = np.asarray(eng.step_logits())
+        assert logits.shape == (eng.n_slots, 1, cfg.vocab)
+        assert np.array_equal(eng.slot_pos, pos)  # nothing advanced
+        # The lowered step is the donating program: the state aliases out.
+        assert eng.lower_step().compile().memory_analysis().alias_size_in_bytes > 0
+        assert eng.step() == 2
+        assert np.array_equal(np.asarray(eng.tokens)[:, 0], logits[:, -1].argmax(-1))
+
     def test_submit_validation(self, zoo):
         cfg, eng = self._engine(zoo, seq_cap=8)
         with pytest.raises(ValueError, match="seq_cap"):
@@ -433,6 +451,8 @@ class TestServeCLI:
                 "--batch", "4", "--prompt-len", "4", "--gen-len", "4",
                 "--class-sharded", "off", *extra]
         monkeypatch.setattr("sys.argv", argv)
+        # The persistent compile cache is the CLI's; keep this process off it.
+        monkeypatch.setattr(serve, "use_compile_cache", lambda: None)
         serve.main()
         out = capsys.readouterr().out.strip().splitlines()
         return json.loads(out[-1])

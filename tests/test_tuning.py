@@ -526,8 +526,8 @@ def test_lean_recorded_entry_never_reaches_pipelined_consumers(
 
     from repro.core import execution as X
 
-    # Lean-only on TPU_LITTLE: ~6.0 MiB single- vs ~10.0 MiB double-buffered.
-    cfg = B.BlockConfig(bm=512, bk=1280, bn=1024, dtype_bytes=2)
+    # Lean-only on TPU_LITTLE: 6.5 MiB single- vs 8.4 MiB double-buffered.
+    cfg = B.BlockConfig(bm=512, bk=640, bn=1024, dtype_bytes=2)
     assert not cfg.fits(B.TPU_LITTLE) and cfg.fits(B.TPU_LITTLE, double_buffer=False)
     path = str(tmp_path / "cache.json")
     cache = C.TuningCache(path=path)
