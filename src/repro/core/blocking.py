@@ -302,7 +302,8 @@ def derive_block_config(
     ``bk`` first (it amortizes both A and B traffic, like the paper grows
     ``k_c`` to fill L1), then balance ``bm``/``bn``.  All dims are
     MXU/lane aligned; dims are clamped to the (padded) problem size so tiny
-    problems do not claim VMEM they cannot use.
+    problems do not claim VMEM they cannot use, and ``bk`` divides the
+    lane-padded K so no K padding beyond the lane is computed.
 
     ``double_buffer=False`` derives for the VMEM-lean k-streaming kernel
     (single-buffered input staging): the same budget admits larger
@@ -316,6 +317,7 @@ def derive_block_config(
     pm = _round_up(min(m, max_bm), align)
     pn = _round_up(min(n, max_bn), align)
     pk = _round_up(min(k, max_bk), align)
+    lane_k = _round_up(k, align)
 
     best: Optional[BlockConfig] = None
     bm = pm
@@ -333,6 +335,10 @@ def derive_block_config(
                 bn //= 2
                 continue
             bk = _round_down(min(pk, (budget - fixed) // per_k), align)
+            # ... that divides the lane-padded K: any other bk pads K up to
+            # a multiple of bk, MXU work spent on zeros.
+            while lane_k % bk:
+                bk -= align
             cfg = BlockConfig(bm=bm, bk=bk, bn=bn, dtype_bytes=dtype_bytes)
             if cfg.fits(spec, double_buffer=double_buffer):
                 if best is None or cfg.arithmetic_intensity() > best.arithmetic_intensity():

@@ -349,17 +349,9 @@ def resolve_backend(name: str) -> str:
 
 
 def resolve_paged_attn_backend(name: str) -> str:
-    """Collapse a paged-attention ``"auto"``; validate the op family.
-
-    Under an active context whose tree runs the ``"xla"`` GEMM, ``"auto"``
-    is the XLA gather route too, so an xla context is the all-XLA
-    reference for a whole decode step; otherwise it probes the platform.
-    """
+    """Collapse a paged-attention ``"auto"``; validate the op family."""
 
     if name == "auto":
-        ctx = current_context()
-        if ctx is not None and ctx.backend() == "xla":
-            return "paged_attn_xla"
         return "paged_attn_pallas" if on_tpu() else "paged_attn_xla"
     if backend_op(name) != "paged_attn":
         raise ValueError(
@@ -383,9 +375,12 @@ def dispatch_paged_attention(
 
     The decode path's funnel: ``layers.decode_attention_paged`` calls this
     per layer, so the paged kernels live in the same vocabulary — and the
-    same parity harness — as the GEMM micro-kernels.
+    same parity harness — as the GEMM micro-kernels.  ``"auto"`` takes the
+    active context's ``paged_attn`` entry.
     """
 
+    if backend == "auto" and current_context() is not None:
+        backend = current_context().paged_attn
     return BACKENDS[resolve_paged_attn_backend(backend)](
         q, pages_k, pages_v, page_table, pos
     )
@@ -540,10 +535,13 @@ class ExecutionContext:
     spec).  ``tree.block`` itself is the canonical-shape config carrying
     the Section-5.3 shared-panel structure; per-call shapes re-resolve so
     a little-VMEM class never inherits a big-class block it cannot hold.
+    Paged decode attention under this context runs ``paged_attn``
+    (``"auto"``: the platform's kernel).
     """
 
     device_class: str
     tree: "ControlTree"
+    paged_attn: str = "auto"
 
     def __enter__(self) -> "ExecutionContext":
         # Token bookkeeping lives in _TOKENS (per-thread *and* per-task),
@@ -567,6 +565,11 @@ class ExecutionContext:
         """The concrete dispatch-table entry this context routes to."""
 
         return resolve_backend(self.tree.backend)
+
+    def paged_attn_backend(self) -> str:
+        """The paged-attention entry this context routes to."""
+
+        return resolve_paged_attn_backend(self.paged_attn)
 
     def block_config(
         self, m: int, k: int, n: int, dtype_name: str, dtype_bytes: int
@@ -870,6 +873,7 @@ def default_context(
     shape: tuple[int, int, int] = (1024, 1024, 1024),
     backend: str = "auto",
     device_class: Optional[str] = None,
+    paged_attn: str = "auto",
 ) -> ExecutionContext:
     """A single-class context for homogeneous runs (dry-run, plain serving).
 
@@ -885,7 +889,7 @@ def default_context(
     trees = build_control_trees(
         {name: spec}, *shape, backend=resolve_backend(backend)
     )
-    return ExecutionContext(device_class=name, tree=trees[name])
+    return ExecutionContext(device_class=name, tree=trees[name], paged_attn=paged_attn)
 
 
 __all__ = [

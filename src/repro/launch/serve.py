@@ -189,23 +189,38 @@ def truncate_at_eos(out: np.ndarray, prompt_len: int, eos_id: int):
     return out, n_eos, out.shape[0] - n_eos
 
 
-def _engine(cfg, params, asym, prompts, args, seq_cap):
-    """The persistent slot-table engine path (the default)."""
+def serving_mesh(args) -> AsymmetricMesh:
+    """The big/little two-pod mesh the engine schedules over, one chip per
+    pod."""
+
+    return AsymmetricMesh(biglittle_classes(chips_per_pod=1), strategy=args.strategy,
+                          batch_tile=1, objective=args.objective)
+
+
+def make_engine(cfg, params, asym, args, seq_cap, c_max):
+    """A ``ServingEngine`` configured from the CLI's arguments;
+    ``--slots-per-pod`` defaults to the request layout's ``c_max``."""
 
     from repro.runtime.serving import ServingEngine
 
-    layout = asym.batch_layout(args.batch)
-    print("request split across classes:", layout.sizes)
-    eng = ServingEngine(
+    return ServingEngine(
         cfg, params, asym,
         seq_cap=seq_cap,
-        slots_per_pod=args.slots_per_pod or layout.c_max,
+        slots_per_pod=args.slots_per_pod or c_max,
         class_sharded=args.class_sharded,
         paged=args.paged,
         page_size=args.page_size,
         pool_pages=args.pool_pages,
         eos_id=args.eos_id,
     )
+
+
+def _engine(cfg, params, asym, prompts, args, seq_cap):
+    """The persistent slot-table engine path (the default)."""
+
+    layout = asym.batch_layout(args.batch)
+    print("request split across classes:", layout.sizes)
+    eng = make_engine(cfg, params, asym, args, seq_cap, layout.c_max)
     out = eng.generate(prompts, args.gen_len)
     st = eng.stats
     # st.tokens counts active-slot tokens only — with fewer active slots
@@ -233,25 +248,12 @@ def _fleet(cfg, params, asym, prompts, args, seq_cap):
     per-engine throughput (see runtime/fleet.py)."""
 
     from repro.runtime.fleet import Fleet
-    from repro.runtime.serving import ServingEngine
 
     engines = []
     for _ in range(args.fleet):
-        a = AsymmetricMesh(
-            biglittle_classes(chips_per_pod=1), strategy=args.strategy,
-            batch_tile=1, objective=args.objective,
-        )
+        a = serving_mesh(args)
         layout = a.batch_layout(max(1, args.batch // args.fleet))
-        engines.append(ServingEngine(
-            cfg, params, a,
-            seq_cap=seq_cap,
-            slots_per_pod=args.slots_per_pod or layout.c_max,
-            class_sharded=args.class_sharded,
-            paged=args.paged,
-            page_size=args.page_size,
-            pool_pages=args.pool_pages,
-            eos_id=args.eos_id,
-        ))
+        engines.append(make_engine(cfg, params, a, args, seq_cap, layout.c_max))
     fleet = Fleet(engines, objective=args.objective)
     print("fleet rel_throughput:", [round(r, 3) for r in fleet.rel_throughput])
     out = fleet.generate(prompts, args.gen_len)
@@ -282,7 +284,7 @@ def _fleet(cfg, params, asym, prompts, args, seq_cap):
     return out, timings, device_class, exec_backend, None, engine_stats
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -329,7 +331,11 @@ def main():
     ap.add_argument("--metrics", default=None, metavar="PATH",
                     help="enable observability and write a metrics JSON "
                          "snapshot here")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
     use_compile_cache()
 
     if args.trace or args.metrics:
@@ -347,8 +353,7 @@ def main():
         raise SystemExit(f"{cfg.name}: serving demo targets token-in archs")
 
     # Asymmetric request routing: split the request batch across classes.
-    asym = AsymmetricMesh(biglittle_classes(chips_per_pod=1), strategy=args.strategy,
-                          batch_tile=1, objective=args.objective)
+    asym = serving_mesh(args)
     if args.one_shot and args.objective != "perf":
         raise SystemExit("--objective applies to the engine path only")
     if args.class_sharded == "on" and args.device_class is not None:

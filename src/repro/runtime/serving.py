@@ -1018,13 +1018,6 @@ class ServingEngine:
             batch["page_table"] = jnp.asarray(self._step_table())
         return batch, jnp.asarray(self._pos, jnp.int32)
 
-    def lower_step(self):
-        """The jitted decode step lowered at the next step's inputs (its
-        HLO and, once compiled, its memory analysis)."""
-
-        batch, pos = self.step_inputs()
-        return self._step.lower(self.params, batch, self.state, pos)
-
     def step_logits(self) -> jnp.ndarray:
         """``(n_slots, 1, vocab)`` logits of the next decode step, computed
         by the engine's own step program without advancing or donating

@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core import execution as X
 from repro.core.asymmetric import AsymmetricMesh, biglittle_classes
 from repro.kernels.gemm import gemm_pallas, gemm_pallas_lean
 from repro.kernels.paged_attention import paged_attention_pallas
@@ -96,18 +97,20 @@ def test_gemm_compiles(one_chip, kernel, mkn):
     _compile(kernel, a, b)
 
 
-def _engine_page_size() -> int:
-    """The page size the engine derives for chip_smoke.py's slot table."""
+def _engine_page_size(monkeypatch) -> int:
+    """The page size the engine derives for chip_smoke.py's slot table on a
+    TPU, where the control trees run the Pallas GEMM kernels."""
 
+    monkeypatch.setattr(X, "on_tpu", lambda: True)
     trees = AsymmetricMesh(biglittle_classes(chips_per_pod=1)).control_trees()
     return divisor_page_size(SEQ_CAP, min(t.block.bm for t in trees.values()))
 
 
 @pytest.mark.parametrize("page_size", ["engine", 128])
-def test_paged_attention_compiles(one_chip, page_size):
+def test_paged_attention_compiles(one_chip, page_size, monkeypatch):
     cfg = get_config("internlm2-1.8b")
     if page_size == "engine":
-        page_size = _engine_page_size()
+        page_size = _engine_page_size(monkeypatch)
     w = SEQ_CAP // page_size
     n_pages = 2 * (SLOTS // 2 + 1) * w  # per pod: 4 slot lanes + 1 phantom lane
     q = _spec((SLOTS, cfg.n_heads, cfg.head_dim), jnp.bfloat16, one_chip)

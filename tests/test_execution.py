@@ -199,16 +199,26 @@ class TestNoContextDefaults:
         assert cfg == B.derive_block_config(256, 256, 256, dtype_bytes=4)
 
     def test_paged_attention_auto_follows_xla_context(self, monkeypatch):
-        # As on a TPU: "auto" is the Pallas paged kernel, except under an
-        # xla context, which is the all-XLA reference for a whole step.
+        # As on a TPU: "auto" is the Pallas paged kernel whatever GEMM the
+        # context runs; only a context's own paged_attn entry (the all-XLA
+        # reference step sets it) routes dispatch elsewhere.
         monkeypatch.setattr(X, "on_tpu", lambda: True)
+        seen = []
+        for name in ("paged_attn_xla", "paged_attn_pallas"):
+            monkeypatch.setitem(X.BACKENDS, name, lambda *a, n=name: seen.append(n))
         assert X.resolve_paged_attn_backend("auto") == "paged_attn_pallas"
-        with _ctx(backend="pallas"):
-            assert X.resolve_paged_attn_backend("auto") == "paged_attn_pallas"
-        with _ctx(backend="xla"):
-            assert X.resolve_paged_attn_backend("auto") == "paged_attn_xla"
-            assert (X.resolve_paged_attn_backend("paged_attn_pallas")
-                    == "paged_attn_pallas")
+        with _ctx(backend="xla") as ctx:
+            assert ctx.paged_attn_backend() == "paged_attn_pallas"
+            X.dispatch_paged_attention(None, None, None, None, None)
+        ref = X.default_context(backend="xla", paged_attn="paged_attn_xla")
+        with ref:
+            assert ref.paged_attn_backend() == "paged_attn_xla"
+            X.dispatch_paged_attention(None, None, None, None, None)
+            X.dispatch_paged_attention(None, None, None, None, None,
+                                       backend="paged_attn_pallas")
+        assert seen == ["paged_attn_pallas", "paged_attn_xla", "paged_attn_pallas"]
+        with pytest.raises(ValueError, match="not a paged-attention"):
+            X.default_context(paged_attn="pallas").paged_attn_backend()
 
 
 # ---------------------------------------------------------------------------

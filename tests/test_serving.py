@@ -253,7 +253,9 @@ class TestRouterAndSlots:
         assert logits.shape == (eng.n_slots, 1, cfg.vocab)
         assert np.array_equal(eng.slot_pos, pos)  # nothing advanced
         # The lowered step is the donating program: the state aliases out.
-        assert eng.lower_step().compile().memory_analysis().alias_size_in_bytes > 0
+        batch, q = eng.step_inputs()
+        lowered = eng._step.lower(eng.params, batch, eng.state, q)
+        assert lowered.compile().memory_analysis().alias_size_in_bytes > 0
         assert eng.step() == 2
         assert np.array_equal(np.asarray(eng.tokens)[:, 0], logits[:, -1].argmax(-1))
 
