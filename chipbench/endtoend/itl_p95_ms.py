@@ -1,0 +1,9 @@
+"""95th percentile of the gaps between consecutive output tokens of a
+request, over every gap inside the window (host clock)."""
+
+from chipbench import readings, stats
+
+
+def read(run):
+    xs = readings.inter_token_gaps_ms(run)
+    return stats.percentile(xs, 95) if xs else None
