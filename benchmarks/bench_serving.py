@@ -329,9 +329,9 @@ def run(arch: str = "internlm2-1.8b", batch: int = 8, prompt_len: int = 8,
         rows.append(Row("serve_engine_mixed", 1e6 / max(mix_tps, 1e-9),
                         f"tokens_per_s={mix_tps:.1f}"))
     if obs:
-        # Informational: the engine with tracing + the default step-time
-        # probe active — the measured enabled-path overhead of the
-        # observability contract.  Not gated (the gate runs disabled).
+        # Informational: the engine with the trace buffer and metrics on —
+        # the measured enabled-path overhead of the observability
+        # contract.  Not gated (the gate runs disabled).
         from repro import observability as OBS
 
         OBS.enable()

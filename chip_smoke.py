@@ -285,10 +285,10 @@ def serve(cfg, params, prompts, *, paged: str, class_sharded: str, label: str,
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     step_ms = 1e3 * st.decode_s / max(st.decode_steps, 1)
     log(f"{label}: {len(done)}/{len(rids)} requests, {generated} tokens generated "
-        f"in {wall:.2f} s wall, compile {st.compile_s:.2f} s, "
-        f"{st.admission_rounds} admission rounds ({st.prefill_s:.2f} s of warm "
-        f"prefill), {st.decode_steps + 1} decode steps ({step_ms:.2f} ms per warm "
-        f"step) (smoke timing, not a benchmark); device 0 peak_bytes_in_use {peak}")
+        f"in {wall:.2f} s wall, {st.compiles} programs compiled in {st.compile_s:.2f} s, "
+        f"{st.admission_rounds} admission rounds ({st.prefill_s:.2f} s of prefill), "
+        f"{st.decode_steps} decode steps ({step_ms:.2f} ms per step), compiles excluded "
+        f"(smoke timing, not a benchmark); device 0 peak_bytes_in_use {peak}")
     tokens = {r: done[r].tokens for r in rids}
     return eng, logits, slot_rid, tokens
 

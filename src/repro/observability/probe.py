@@ -19,10 +19,10 @@ occupancy, which is exactly the quantity the paper's §5.2.2/§5.4
 feedback is defined over.
 
 The probe is the engine's default ``pod_time_hook`` but stays inert
-(returns ``None``; calibration frozen, zero work) until observability
-is enabled — keeping the off-is-free contract and the engine-vs-baseline
-bit-identity/bench gates untouched.  Pass ``always=True`` to measure
-regardless (tests, external telemetry loops).
+(returns ``None``; calibration frozen, zero work) unless it is built
+with ``always=True`` or :meth:`StepTimeProbe.refresh` is called: turning
+tracing on does not arm it, since its GEMMs would run inside the traced
+window and take device time from the steps being measured.
 """
 
 from __future__ import annotations
@@ -61,7 +61,7 @@ class StepTimeProbe:
         the callable is timed in place of the probe GEMM (still under
         the class's context, still normalized by ``probe_shape[0]``
         rows).  Lets tests and fleets probe with representative work.
-    always : measure even while observability is disabled.
+    always : measure on every call (otherwise the probe returns ``None``).
     """
 
     def __init__(
@@ -86,7 +86,7 @@ class StepTimeProbe:
         self.refreshes = 0
 
     def active(self) -> bool:
-        return self.always or T.enabled()
+        return self.always
 
     def _default_workload(self, ctx):
         import jax

@@ -1,4 +1,4 @@
-"""Trace spans over a bounded in-memory buffer, Chrome-trace exportable.
+"""Trace spans written to the JAX profiler's trace and to an in-memory buffer.
 
 The span API mirrors :class:`repro.core.execution.ExecutionContext`'s
 contextvar discipline: the active-span stack lives in a ``ContextVar``
@@ -8,13 +8,27 @@ runs in a copied context) nest and restore independently, and ``with``
 semantics make exit exception-safe (a failing span is recorded with its
 error class rather than leaked).
 
-Recording is cheap and lock-bounded: events append to a fixed-capacity
-deque (oldest events drop, counted in ``dropped``) and nothing here
-imports jax or numpy — the disabled fast path is a single module-global
-``None`` check, which is what lets hot loops call :func:`complete`
-unconditionally.
+A span has two sinks:
 
-Two export formats:
+  * the JAX profiler: while a profiler session is active (any
+    ``jax.profiler.trace`` / ``start_trace``), each span is a
+    ``jax.profiler.TraceAnnotation`` carrying its args as metadata, so it
+    lands in the ``.xplane.pb`` on the host's line, on the same clock as
+    the device's operations;
+  * the buffer, while :func:`enable` has one: events append to a
+    fixed-capacity deque (oldest events drop, counted in ``dropped``).
+
+With both off, :func:`span` returns a shared no-op object after one
+``None`` check and ``TraceAnnotation.is_enabled()``.  jax is imported on
+the first :func:`span` call, never at module import.  :func:`complete`,
+:func:`instant` and :func:`counter` write to the buffer only.
+
+Once a span has been recorded, every Python garbage collection during a
+profiler session is recorded too, as a ``host.gc`` annotation (profiler
+only) with its generation and the objects collected: a collection that
+stalls the device then has a name.
+
+Two export formats of the buffer:
 
   * :meth:`TraceBuffer.save` — the native ``{"version", "events"}`` JSON
     the ``python -m repro.observability.report`` CLI summarizes,
@@ -32,6 +46,7 @@ from __future__ import annotations
 import collections
 import contextvars
 import dataclasses
+import gc
 import os
 import threading
 import time
@@ -185,8 +200,8 @@ def get_buffer() -> Optional[TraceBuffer]:
 
 def complete(name: str, t0: float, dur: float, *, cat: str = "span", **args) -> None:
     """Record an already-measured interval (``t0`` = ``perf_counter`` at
-    start).  The hot-loop API: callers that already time themselves
-    (engine step, trainer step) record post hoc with zero control-flow
+    start) into the buffer.  For callers that already time themselves
+    (trainer step, tuning candidate): post hoc, with zero control-flow
     change; disabled cost is this ``None`` check."""
 
     buf = _BUFFER
@@ -249,7 +264,7 @@ def counter(name: str, *, cat: str = "metric", **values) -> None:
 
 
 class _NoopSpan:
-    """Returned by :func:`span` while tracing is off: zero state, reusable."""
+    """Returned by :func:`span` while both sinks are off: zero state, reusable."""
 
     __slots__ = ()
 
@@ -270,32 +285,45 @@ class Span:
     """One timed region; create via :func:`span`, use as a context manager.
 
     Entering pushes onto the contextvar stack (so children see their
-    parent); exiting pops, measures the duration, and records — tagged
-    with the exception class if the body raised.  A span object is
-    single-use.
+    parent) and, while a profiler session is active, opens the span's
+    ``TraceAnnotation``; exiting closes it, pops, measures the duration,
+    and records into the buffer if one is on — tagged with the exception
+    class if the body raised.  A span object is single-use.
     """
 
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args: dict):
         self.name = name
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def tag(self, **kw) -> "Span":
         """Attach tags after creation (e.g. results known mid-span)."""
 
         self.args.update(kw)
+        if self._ann is not None:
+            self._ann.set_metadata(**kw)
         return self
 
     def __enter__(self) -> "Span":
         _STACK.set(_STACK.get() + (self,))
+        ann = _annotation()
+        if ann.is_enabled():
+            self._ann = ann(self.name, **self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            if exc_type is not None:
+                self._ann.set_metadata(error=exc_type.__name__)
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         stack = _STACK.get()
         if stack and stack[-1] is self:
             _STACK.set(stack[:-1])
@@ -323,11 +351,61 @@ class Span:
 
 
 def span(name: str, *, cat: str = "span", **args):
-    """A context manager timing its body (no-op while tracing is off)."""
+    """A context manager timing its body: a profiler annotation while a
+    profiler session is active, a buffer event while the buffer is on,
+    and a shared no-op while neither is."""
 
-    if _BUFFER is None:
+    if _BUFFER is None and not _annotation().is_enabled():
         return _NOOP
+    if not _GC_HOOKED:
+        _hook_gc()
     return Span(name, cat, args)
+
+
+# -- the profiler sink -------------------------------------------------------
+
+_ANNOTATION = None   # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+_GC_HOOKED = False
+_GC_LOCK = threading.Lock()
+_GC_OPEN = None      # the host.gc annotation of the collection under way
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # Profiler only: a collection can start on any allocation, also while
+    # the collecting thread holds the buffer's lock, so writing to the
+    # buffer from here could deadlock.  CPython starts no collection while
+    # one runs, so one slot holds the open one.
+    global _GC_OPEN
+    if phase == "start":
+        ann = _annotation()
+        if ann.is_enabled():
+            _GC_OPEN = ann("host.gc", generation=info["generation"])
+            _GC_OPEN.__enter__()
+    elif _GC_OPEN is not None:
+        open_, _GC_OPEN = _GC_OPEN, None
+        open_.set_metadata(collected=info["collected"])
+        open_.__exit__(None, None, None)
+
+
+def _hook_gc() -> None:
+    """Install the ``host.gc`` hook once, on the first recorded span."""
+
+    global _GC_HOOKED
+    with _GC_LOCK:
+        if not _GC_HOOKED:
+            gc.callbacks.append(_on_gc)
+            _GC_HOOKED = True
 
 
 def current_span() -> Optional[Span]:

@@ -94,6 +94,7 @@ from repro.core.schedule import deficit_route
 from repro.distributed import sharding as SH
 from repro.models import model_zoo as Z
 from repro.models import transformer as TX
+from repro.observability import compiles
 from repro.observability import metrics as MET
 from repro.observability import trace as T
 from repro.runtime.paging import PagePool, PageSpec, SENTINEL, divisor_page_size
@@ -118,10 +119,12 @@ def _metrics():
                 labels=("device_class",)),
             "tokens": MET.counter(
                 "engine_tokens_total", "Tokens generated by decode steps"),
-            "tokens_per_s": MET.gauge(
-                "engine_tokens_per_s", "Decode throughput EMA (tokens/s)"),
             "step_seconds": MET.histogram(
                 "engine_decode_step_seconds", "Decode step wall time"),
+            "queue_wait": MET.histogram(
+                "engine_queue_wait_seconds",
+                "Submit-to-admission wait of each admitted request",
+                labels=("device_class",)),
             "rebalances": MET.counter(
                 "engine_rebalances_total",
                 "Slot-budget re-derivations past the drift hysteresis"),
@@ -135,9 +138,6 @@ def _metrics():
                 "engine_page_allocs_total",
                 "KV pages allocated at admission",
                 labels=("device_class",)),
-            "modeled_watts": MET.gauge(
-                "engine_modeled_watts",
-                "Modeled power draw over the last decode step (W)"),
             "pods_parked": MET.gauge(
                 "engine_pods_parked",
                 "Pods currently parked (power-gated) by the energy objective"),
@@ -187,6 +187,7 @@ class Request:
     rid: int
     prompt: np.ndarray        # (P,) int32
     max_new_tokens: int
+    submitted: float = 0.0    # perf_counter at submit
 
 
 @dataclasses.dataclass
@@ -204,13 +205,14 @@ class Completion:
 
 @dataclasses.dataclass
 class EngineStats:
-    """Timing/behavior counters (compile vs steady state split out)."""
+    """Timing/behavior counters (compiles split out of the call times)."""
 
-    compile_s: float = 0.0        # first prefill + first decode step (tracing+XLA)
-    prefill_s: float = 0.0        # steady-state bulk prefill seconds
-    decode_s: float = 0.0         # steady-state decode seconds (warmup excluded)
-    decode_steps: int = 0         # steady-state steps counted in decode_s
-    tokens: int = 0               # tokens generated in steady-state steps
+    compiles: int = 0             # programs compiled or cache-loaded inside engine calls
+    compile_s: float = 0.0        # seconds those compiles and loads took
+    prefill_s: float = 0.0        # bulk prefill seconds, compiles excluded
+    decode_s: float = 0.0         # decode step seconds, compiles excluded
+    decode_steps: int = 0         # steps counted in decode_s
+    tokens: int = 0               # tokens generated by those steps
     admitted: int = 0
     completed: int = 0
     completed_eos: int = 0        # retired by emitting eos_id
@@ -225,7 +227,7 @@ class EngineStats:
     host_relayouts: int = 0
     rebalances: int = 0           # slot-budget re-derivations past hysteresis
     # Modeled (power-model clock, not wall clock) energy accounting over
-    # the steady-state decode steps; deterministic across hosts.
+    # the decode steps; deterministic across hosts.
     energy_j: float = 0.0         # modeled joules burned by decode steps
     modeled_decode_s: float = 0.0 # modeled decode seconds those joules cover
     pod_parks: int = 0            # pods parked by the energy objective
@@ -233,13 +235,13 @@ class EngineStats:
 
     @property
     def tokens_per_s(self) -> float:
-        """Steady-state decode throughput (compile/warmup excluded)."""
+        """Decode throughput, compiles excluded."""
 
         return self.tokens / self.decode_s if self.decode_s > 0 else 0.0
 
     @property
     def tokens_per_j(self) -> float:
-        """Modeled energy efficiency of steady-state decode."""
+        """Modeled energy efficiency of decode."""
 
         return self.tokens / self.energy_j if self.energy_j > 0 else 0.0
 
@@ -289,11 +291,11 @@ class ServingEngine:
     eos_id : token id that stops a request mid-stream (its slot retires
         and — paged — its pages free immediately).  None disables.
     pod_time_hook : feeds the scheduler's straggler calibration.  The
-        default ``"auto"`` installs a
-        :class:`~repro.observability.probe.StepTimeProbe` that measures
-        each class's real per-row cost — but only while observability is
-        enabled (otherwise it returns ``None`` and the calibration stays
-        frozen, keeping the disabled path free).  A callable may take
+        default ``"auto"`` installs an inert
+        :class:`~repro.observability.probe.StepTimeProbe` (it returns
+        ``None`` and the calibration stays frozen, whatever tracing is
+        on); pass ``StepTimeProbe(asym, always=True)`` to measure each
+        class's real per-row cost.  A callable may take
         ``(step)`` (legacy) or ``(step, pod_units)`` and may return
         ``None`` to skip a step; ``None`` disables the feedback entirely
         — one SPMD step cannot be attributed per pod from the host.
@@ -341,8 +343,6 @@ class ServingEngine:
         self._hook_takes_units = (
             _hook_takes_units(pod_time_hook) if pod_time_hook is not None else False
         )
-        self._tps_ema: Optional[float] = None
-        self._shard_tags_cache: Optional[list[dict]] = None
 
         self.mixed = (
             class_sharded != "off"
@@ -434,7 +434,6 @@ class ServingEngine:
         self.tokens = jnp.zeros((self.n_slots, 1), jnp.int32)
         self._pos = np.zeros(self.n_slots, np.int64)  # device copy passed per step
         self._step_calls = 0
-        self._prefill_compiled: set[int] = set()
         self._build()
 
     # -- compiled programs --------------------------------------------------
@@ -577,7 +576,8 @@ class ServingEngine:
         if route_class is None:
             route_class = deficit_route(self._class_weights(), self._routed)
         self.queues[route_class].append(
-            Request(rid=rid, prompt=prompt, max_new_tokens=int(max_new_tokens))
+            Request(rid=rid, prompt=prompt, max_new_tokens=int(max_new_tokens),
+                    submitted=time.perf_counter())
         )
         self._routed[route_class] += 1
         return rid
@@ -784,113 +784,123 @@ class ServingEngine:
         cannot cover the head request defers it (FIFO) untouched.
         """
 
-        self._refresh_budgets()
-        busy_before = self.slot_rid >= 0
-        if not any(self.queues):
-            return 0
+        mark = compiles.read()
+        with T.span("engine.admit", cat="engine") as sp:
+            with T.span("engine.admit.route", cat="engine"):
+                self._refresh_budgets()
+                busy_before = self.slot_rid >= 0
+                if not any(self.queues):
+                    return 0
+                batch = self._take(budgeted=True)
+                if not batch and not busy_before.any():
+                    # Starvation guard: a queue whose class drew a zero
+                    # budget at low load must still make progress when
+                    # nothing is running (the scheduler's starvation
+                    # floor, at admission granularity).
+                    batch = self._take(budgeted=False)
+                if not batch:
+                    return 0
+                now = time.perf_counter()
+                waits = [now - req.submitted for _, req in batch]
+                rp = max(len(req.prompt) for _, req in batch)
+                sp.tag(admitted=len(batch), round_len=rp,
+                       rids=[req.rid for _, req in batch],
+                       queue_wait_max_s=max(waits))
 
-        def take(budgeted: bool) -> list[tuple[int, "Request"]]:
-            out = []
-            for ci, q in enumerate(self.queues):
-                pods = self._admission_pods(ci)
-                while q:
-                    req = q[0]
-                    slot = None
-                    for pod in pods:
-                        slot = (
-                            self._free_slot(pod)
-                            if budgeted
-                            else self._any_free_slot(pod)
-                        )
-                        if slot is not None:
-                            break
-                    if slot is None:
-                        break
-                    if self.pool is not None:
-                        need = min(
-                            len(req.prompt) + req.max_new_tokens, self.s_cache
-                        )
-                        if not self.pool.alloc(slot, need):
-                            # Pod partition exhausted: defer the head (it
-                            # keeps its FIFO turn; the pool and every live
-                            # slot are untouched — all-or-nothing alloc).
-                            self.stats.admission_deferrals += 1
-                            break
-                        self._note_page_alloc(slot, need)
-                    q.popleft()
-                    out.append((slot, req))
-                    self.slot_rid[slot] = req.rid  # reserve before next _free_slot
-            return out
+            t0 = time.perf_counter()
+            with T.span("engine.admit.inputs", cat="engine"):
+                prompts = np.zeros((self.n_slots, rp), np.int32)
+                plens = np.full(self.n_slots, rp, np.int32)
+                for slot, req in batch:
+                    prompts[slot, : len(req.prompt)] = req.prompt
+                    plens[slot] = len(req.prompt)
+                # Admitted slots plus every phantom (free) lane take the
+                # fresh prefill — see merge_fn.
+                take_new = ~busy_before
+                pbatch = {"tokens": jnp.asarray(prompts),
+                          "live": jnp.ones((self.n_slots,), bool)}
+                if self.pool is not None:
+                    table = self.phantom[self._phantom_rows_idx].copy()
+                    for slot, _ in batch:
+                        table[slot] = self.pool.table[slot]
+                    pbatch["page_table"] = jnp.asarray(self._localize(table))
+                plens_dev = jnp.asarray(plens)
+            with T.span("engine.admit.launch", cat="engine"):
+                if self.pool is not None:
+                    nxt, self.state = self._prefill(
+                        self.params, pbatch, self.state, plens_dev
+                    )
+                    self.tokens = jnp.where(
+                        jnp.asarray(take_new)[:, None], nxt, self.tokens
+                    )
+                else:
+                    nxt, fresh_state = self._prefill(self.params, pbatch, plens_dev)
+                    self.state, self.tokens = self._merge(
+                        self.state, fresh_state, self.tokens, nxt,
+                        jnp.asarray(take_new),
+                    )
+            with T.span("engine.admit.wait", cat="engine"):
+                first = np.asarray(nxt)  # blocks; first generated token per lane
+            with T.span("engine.admit.retire", cat="engine"):
+                dt = time.perf_counter() - t0
+                n_compiled, compile_s = compiles.since(mark)
+                sp.tag(compiles=n_compiled)
+                self.stats.compiles += n_compiled
+                self.stats.compile_s += compile_s
+                self.stats.prefill_s += dt - compile_s
+                if T.enabled():
+                    self._record_admit_metrics(batch, waits)
 
-        batch = take(budgeted=True)
-        if not batch and not busy_before.any():
-            # Starvation guard: a queue whose class drew a zero budget at
-            # low load must still make progress when nothing is running
-            # (the scheduler's starvation floor, at admission granularity).
-            batch = take(budgeted=False)
-        if not batch:
-            return 0
-
-        rp = max(len(req.prompt) for _, req in batch)
-        prompts = np.zeros((self.n_slots, rp), np.int32)
-        plens = np.full(self.n_slots, rp, np.int32)
-        for slot, req in batch:
-            prompts[slot, : len(req.prompt)] = req.prompt
-            plens[slot] = len(req.prompt)
-        # Admitted slots plus every phantom (free) lane take the fresh
-        # prefill — see merge_fn.
-        take_new = ~busy_before
-
-        t0 = time.perf_counter()
-        live_all = jnp.ones((self.n_slots,), bool)
-        if self.pool is not None:
-            table = self.phantom[self._phantom_rows_idx].copy()
-            for slot, _ in batch:
-                table[slot] = self.pool.table[slot]
-            pbatch = {
-                "tokens": jnp.asarray(prompts),
-                "page_table": jnp.asarray(self._localize(table)),
-                "live": live_all,
-            }
-            nxt, self.state = self._prefill(
-                self.params, pbatch, self.state, jnp.asarray(plens)
-            )
-            self.tokens = jnp.where(
-                jnp.asarray(take_new)[:, None], nxt, self.tokens
-            )
-        else:
-            pbatch = {"tokens": jnp.asarray(prompts), "live": live_all}
-            nxt, fresh_state = self._prefill(
-                self.params, pbatch, jnp.asarray(plens)
-            )
-            self.state, self.tokens = self._merge(
-                self.state, fresh_state, self.tokens, nxt, jnp.asarray(take_new)
-            )
-        first = np.asarray(nxt)  # blocks; first generated token per lane
-        dt = time.perf_counter() - t0
-        compiling = rp not in self._prefill_compiled
-        if compiling:
-            self._prefill_compiled.add(rp)
-            self.stats.compile_s += dt
-        else:
-            self.stats.prefill_s += dt
-        if T.enabled():
-            self._record_admit_telemetry(t0, dt, rp, batch, compiling)
-
-        self._live[take_new] = True
-        self._pos[take_new] = plens[take_new]
-        for slot, req in batch:
-            self.slot_pos[slot] = len(req.prompt)
-            self._slot_req[slot] = req
-            self._slot_toks[slot] = [int(first[slot, 0])]
-            self.slot_remaining[slot] = req.max_new_tokens - 1
-            self.stats.admitted += 1
-            if self.eos_id is not None and int(first[slot, 0]) == self.eos_id:
-                self._retire(slot, stop="eos")
-            elif self.slot_remaining[slot] == 0:
-                self._retire(slot, stop="budget")
-        self.stats.admission_rounds += 1
+                self._live[take_new] = True
+                self._pos[take_new] = plens[take_new]
+                for slot, req in batch:
+                    self.slot_pos[slot] = len(req.prompt)
+                    self._slot_req[slot] = req
+                    self._slot_toks[slot] = [int(first[slot, 0])]
+                    self.slot_remaining[slot] = req.max_new_tokens - 1
+                    self.stats.admitted += 1
+                    if self.eos_id is not None and int(first[slot, 0]) == self.eos_id:
+                        self._retire(slot, stop="eos")
+                    elif self.slot_remaining[slot] == 0:
+                        self._retire(slot, stop="budget")
+                self.stats.admission_rounds += 1
         return len(batch)
+
+    def _take(self, budgeted: bool) -> list[tuple[int, Request]]:
+        """Take queue heads into free slots (budgeted or any), reserving
+        each slot (and, paged, its pages) as it goes."""
+
+        out = []
+        for ci, q in enumerate(self.queues):
+            pods = self._admission_pods(ci)
+            while q:
+                req = q[0]
+                slot = None
+                for pod in pods:
+                    slot = (
+                        self._free_slot(pod)
+                        if budgeted
+                        else self._any_free_slot(pod)
+                    )
+                    if slot is not None:
+                        break
+                if slot is None:
+                    break
+                if self.pool is not None:
+                    need = min(
+                        len(req.prompt) + req.max_new_tokens, self.s_cache
+                    )
+                    if not self.pool.alloc(slot, need):
+                        # Pod partition exhausted: defer the head (it
+                        # keeps its FIFO turn; the pool and every live
+                        # slot are untouched — all-or-nothing alloc).
+                        self.stats.admission_deferrals += 1
+                        break
+                    self._note_page_alloc(slot, need)
+                q.popleft()
+                out.append((slot, req))
+                self.slot_rid[slot] = req.rid  # reserve before next _free_slot
+        return out
 
     def _retire(self, slot: int, stop: str = "budget"):
         req = self._slot_req.pop(slot)
@@ -959,53 +969,61 @@ class ServingEngine:
         if n_active == 0:
             return 0
         units = self._pod_active_before(active)
+        mark = compiles.read()
         t0 = time.perf_counter()
-        batch, pos = self.step_inputs()
-        nxt, self.state = self._step(self.params, batch, self.state, pos)
-        self.tokens = nxt
-        toks = np.asarray(nxt)  # blocks: the step's wall time is real
-        dt = time.perf_counter() - t0
-        if self._step_calls == 0:
-            self.stats.compile_s += dt
-        else:
-            self.stats.decode_s += dt
-            self.stats.decode_steps += 1
-            self.stats.tokens += n_active
-            self._account_energy(units)
-        self._step_calls += 1
-        self._pos += 1  # every slot ages (phantom rows match one-shot padding)
+        with T.span("engine.step", cat="engine", step=self._step_calls,
+                    active=n_active, rows=self.n_slots) as sp:
+            with T.span("engine.step.inputs", cat="engine"):
+                batch, pos = self.step_inputs()
+            with T.span("engine.step.launch", cat="engine"):
+                nxt, self.state = self._step(self.params, batch, self.state, pos)
+            with T.span("engine.step.wait", cat="engine"):
+                toks = np.asarray(nxt)  # blocks: the step's wall time is real
+            with T.span("engine.step.retire", cat="engine"):
+                self.tokens = nxt
+                dt = time.perf_counter() - t0
+                n_compiled, compile_s = compiles.since(mark)
+                sp.tag(compiles=n_compiled)
+                self.stats.compiles += n_compiled
+                self.stats.compile_s += compile_s
+                self.stats.decode_s += dt - compile_s
+                self.stats.decode_steps += 1
+                self.stats.tokens += n_active
+                self._account_energy(units)
+                self._step_calls += 1
+                self._pos += 1  # every slot ages (phantom rows match one-shot padding)
 
-        for slot in np.nonzero(active)[0]:
-            slot = int(slot)
-            tok = int(toks[slot, 0])
-            self._slot_toks[slot].append(tok)
-            self.slot_remaining[slot] -= 1
-            if self.eos_id is not None and tok == self.eos_id:
-                self._retire(slot, stop="eos")
-            elif self.slot_remaining[slot] == 0:
-                self._retire(slot, stop="budget")
+                for slot in np.nonzero(active)[0]:
+                    slot = int(slot)
+                    tok = int(toks[slot, 0])
+                    self._slot_toks[slot].append(tok)
+                    self.slot_remaining[slot] -= 1
+                    if self.eos_id is not None and tok == self.eos_id:
+                        self._retire(slot, stop="eos")
+                    elif self.slot_remaining[slot] == 0:
+                        self._retire(slot, stop="budget")
 
-        if T.enabled():
-            self._record_step_telemetry(t0, dt, n_active, active,
-                                        self._step_calls - 1)
+                if T.enabled():
+                    self._record_step_metrics(dt, n_active, units)
 
-        # Straggler feedback: per-pod timings re-calibrate the scheduler
-        # (budgets only re-derive at admission, past hysteresis).  One
-        # SPMD step yields one wall time, not per-pod times — without a
-        # hook there is no per-pod signal, and fabricating equal times
-        # would read occupancy as speed and erode the calibrated ratios
-        # (at full occupancy every pod shows the same units/dt), so the
-        # calibration comes only from a hook (the default StepTimeProbe
-        # measures each class's real per-row cost, and stays inert —
-        # returning None — while observability is off).
-        if self.pod_time_hook is not None:
-            times = (
-                self.pod_time_hook(self._step_calls - 1, units)
-                if self._hook_takes_units
-                else self.pod_time_hook(self._step_calls - 1)
-            )
-            if times is not None:
-                self.asym.observe_step(units, list(times))
+            # Straggler feedback: per-pod timings re-calibrate the scheduler
+            # (budgets only re-derive at admission, past hysteresis).  One
+            # SPMD step yields one wall time, not per-pod times — without a
+            # hook there is no per-pod signal, and fabricating equal times
+            # would read occupancy as speed and erode the calibrated ratios
+            # (at full occupancy every pod shows the same units/dt), so the
+            # calibration comes only from a hook (the default StepTimeProbe
+            # is inert; one built with ``always=True`` measures each class's
+            # real per-row cost).
+            with T.span("engine.step.calibrate", cat="engine"):
+                if self.pod_time_hook is not None:
+                    times = (
+                        self.pod_time_hook(self._step_calls - 1, units)
+                        if self._hook_takes_units
+                        else self.pod_time_hook(self._step_calls - 1)
+                    )
+                    if times is not None:
+                        self.asym.observe_step(units, list(times))
         return n_active
 
     def step_inputs(self) -> tuple[dict, jnp.ndarray]:
@@ -1063,8 +1081,6 @@ class ServingEngine:
                 watts += self._poll_w[p]
         self.stats.energy_j += watts * span
         self.stats.modeled_decode_s += span
-        if T.enabled():
-            _metrics()["modeled_watts"].set(watts)
 
     # -- KV memory accounting ---------------------------------------------------
 
@@ -1100,69 +1116,23 @@ class ServingEngine:
             "dense_kv_bytes": per_tok * self.n_slots * self.s_cache * itemsize,
         }
 
-    # -- telemetry (every method below only runs while tracing is enabled) ----
+    # -- metrics (every method below only runs while tracing is enabled) ------
 
-    def _shard_tags(self) -> list[dict]:
-        """Per-class provenance tags for decode-shard spans: device class,
-        backend variant, block_source, and the pods running it."""
-
-        if self._shard_tags_cache is None:
-            by_class: dict[str, dict] = {}
-            if self.mixed and self.provenance:
-                for p in self.provenance:
-                    t = by_class.setdefault(p.device_class, {
-                        "device_class": p.device_class,
-                        "backend": p.backend,
-                        "block_source": p.block_source,
-                        "pods": [],
-                    })
-                    t["pods"].append(p.pod)
-            else:
-                ctx = self.asym.execution_context()
-                by_class[ctx.device_class] = {
-                    "device_class": ctx.device_class,
-                    "backend": ctx.backend(),
-                    "block_source": ctx.tree.block_source,
-                    "pods": list(range(self.n_pods)),
-                }
-            self._shard_tags_cache = list(by_class.values())
-        return self._shard_tags_cache
-
-    def _record_step_telemetry(self, t0, dt, n_active, active_mask, step_idx):
+    def _record_step_metrics(self, dt, n_active, per_pod):
         m = _metrics()
-        T.complete("engine.decode_step", t0, dt, cat="engine",
-                   step=step_idx, active=n_active)
-        per_pod = self._pod_active_before(active_mask)
-        for tags in self._shard_tags():
-            T.complete("engine.decode_shard", t0, dt, cat="engine",
-                       device_class=tags["device_class"],
-                       backend=tags["backend"],
-                       block_source=tags["block_source"],
-                       slots=int(sum(per_pod[p] for p in tags["pods"])))
         for ci, c in enumerate(self.asym.classes):
             m["queue_depth"].labels(device_class=c.name).set(len(self.queues[ci]))
         for pod, occ in enumerate(per_pod):
             m["slot_occupancy"].labels(pod=str(pod)).set(occ)
         m["tokens"].inc(n_active)
         m["step_seconds"].observe(dt)
-        if dt > 0:
-            inst = n_active / dt
-            self._tps_ema = (
-                inst if self._tps_ema is None
-                else 0.8 * self._tps_ema + 0.2 * inst
-            )
-            m["tokens_per_s"].set(self._tps_ema)
 
-    def _record_admit_telemetry(self, t0, dt, plen, batch, compiling):
+    def _record_admit_metrics(self, batch, waits):
         m = _metrics()
-        T.complete("engine.prefill", t0, dt, cat="engine", plen=plen,
-                   admitted=len(batch), compiled=compiling)
-        per_class: dict[str, int] = {}
-        for slot, _ in batch:
+        for (slot, _), wait in zip(batch, waits):
             name = self.asym.class_of_pod(slot // self.c_max).name
-            per_class[name] = per_class.get(name, 0) + 1
-        for name, n in per_class.items():
-            m["admissions"].labels(device_class=name).inc(n)
+            m["admissions"].labels(device_class=name).inc()
+            m["queue_wait"].labels(device_class=name).observe(wait)
         for ci, c in enumerate(self.asym.classes):
             m["queue_depth"].labels(device_class=c.name).set(len(self.queues[ci]))
 

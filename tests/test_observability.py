@@ -12,9 +12,10 @@ The ISSUE-6 acceptance criteria, as tests:
   * the step-time probe is inert while observability is off (off-is-free)
     and, when active, reports per-pod times proportional to the units
     each pod ran — occupancy cancels in the scheduler's rate;
-  * a traced engine run emits per-class decode spans and the engine
-    metric families, and ``EngineStats.snapshot()`` is the one JSON
-    reporting surface;
+  * a traced engine run emits the engine's step and admission spans
+    (to the buffer, and to the profiler's trace on the device's clock)
+    and the engine metric families, and ``EngineStats.snapshot()`` is the
+    one JSON reporting surface;
   * the calibration loop CLOSES: with the probe measuring real wall
     times that contradict the typed big:little ratio, the dynamic
     scheduler drifts and re-derives the chunk table — a rebalance driven
@@ -319,7 +320,7 @@ class TestBufferAndExport:
 
     def test_report_cli_main(self, tmp_path, capsys):
         T.enable()
-        with T.span("engine.decode_step"):
+        with T.span("engine.step"):
             pass
         T.instant("scheduler.rebalance")
         T.disable().save(str(tmp_path / "t.json"))
@@ -327,7 +328,7 @@ class TestBufferAndExport:
         rc = report.main([str(tmp_path / "t.json"), "--chrome", str(out_chrome)])
         assert rc == 0
         text = capsys.readouterr().out
-        assert "engine.decode_step" in text
+        assert "engine.step" in text
         assert "scheduler.rebalance" in text
         assert json.loads(out_chrome.read_text())["traceEvents"]
 
@@ -514,16 +515,19 @@ class TestEngineTelemetry:
             buf = T.disable()
 
         names = [e.name for e in buf.events]
-        assert "engine.prefill" in names
-        assert names.count("engine.decode_step") >= 3
-        shards = [e for e in buf.events if e.name == "engine.decode_shard"]
-        # Post-hoc completes (zero hot-loop control flow): one shard span
-        # per decode step, time-contained in its step.
-        assert len(shards) == names.count("engine.decode_step")
-        # Single-program mode: the primary class's provenance tags.
-        tags = shards[0].args
-        assert tags["device_class"] == "big"
-        assert "backend" in tags and "block_source" in tags
+        assert "engine.admit" in names
+        steps = [e for e in buf.events if e.name == "engine.step"]
+        assert len(steps) >= 3
+        # One SPMD step, one span: its args say how many slot-table rows
+        # the program ran over and how many of them were live.
+        assert [e.args["step"] for e in steps] == list(range(len(steps)))
+        assert all(e.args["rows"] == eng.n_slots for e in steps)
+        assert steps[0].args["active"] == 4
+        assert all(0 < e.args["active"] <= e.args["rows"] for e in steps)
+        assert {e.name for e in buf.events if e.parent == "engine.step"} == {
+            "engine.step.inputs", "engine.step.launch", "engine.step.wait",
+            "engine.step.retire", "engine.step.calibrate",
+        }
 
         snap = MET.REGISTRY.snapshot()
         for key in (
@@ -532,6 +536,7 @@ class TestEngineTelemetry:
             "engine_decode_step_seconds",
         ):
             assert key in snap, key
+        assert "engine_tokens_per_s" not in snap and "engine_modeled_watts" not in snap
         adm = {
             s["labels"]["device_class"]: s["value"]
             for s in snap["engine_admissions_total"]["samples"]
@@ -557,6 +562,178 @@ class TestEngineTelemetry:
         # Calibration stayed frozen at the typed ratios.
         rates = eng.asym.scheduler.rates
         assert rates[0] == pytest.approx(1.0) and rates[1] == pytest.approx(0.25)
+
+
+# ---------------------------------------------------------------------------
+# The profiler sink: engine spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+
+def _host_spans(trace_dir, prefixes=("engine.", "host.", "test.")):
+    """``[(name, start_ns, end_ns, stats)]`` of the host plane's events
+    whose names start with ``prefixes``, from a profiler session's
+    ``.xplane.pb``, in start order."""
+
+    import glob
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefixes):
+                    out.append((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _children(spans, parent):
+    """The spans strictly inside ``parent``'s interval, in start order."""
+
+    _, s0, e0, _ = parent
+    return [sp for sp in spans if sp is not parent and s0 <= sp[1] and sp[2] <= e0]
+
+
+class TestProfilerSink:
+    def test_profiler_session_records_spans_with_args(self, tmp_path):
+        # Buffer off: outside a profiler session the span is the no-op;
+        # inside one it is real, and its args (also those tagged late, and
+        # the error class of a failing body) become the event's metadata.
+        assert T.span("test.a") is T.span("test.b")
+        with jax.profiler.trace(str(tmp_path)):
+            sp = T.span("test.outer", step=3)
+            assert sp is not T.span("test.b")
+            with sp:
+                sp.tag(compiles=1)
+                with pytest.raises(KeyError):
+                    with T.span("test.inner"):
+                        raise KeyError("x")
+        assert T.span("test.a") is T.span("test.b")
+        assert not T.enabled() and len(T._STACK.get()) == 0
+        (outer, inner) = _host_spans(tmp_path, ("test.",))
+        assert outer[0] == "test.outer" and outer[3] == {"step": 3, "compiles": 1}
+        assert inner[0] == "test.inner" and inner[3] == {"error": "KeyError"}
+        assert outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    def test_engine_spans_nest_on_the_profiler_clock(self, small_model, tmp_path):
+        cfg, params = small_model
+        eng = ServingEngine(
+            cfg, params, _biglittle(), seq_cap=24, slots_per_pod=4,
+            class_sharded="off", pod_time_hook=None, paged="on", page_size=8,
+        )
+        prompts = np.asarray(
+            np.random.default_rng(6).integers(0, cfg.vocab, (3, 5)), np.int32
+        )
+        with jax.profiler.trace(str(tmp_path)):
+            eng.generate(prompts, 3)
+        spans = _host_spans(tmp_path, ("engine.",))
+
+        steps = [sp for sp in spans if sp[0] == "engine.step"]
+        assert len(steps) == 2
+        for i, step in enumerate(steps):
+            assert step[3]["step"] == i
+            assert step[3]["active"] == 3 and step[3]["rows"] == eng.n_slots
+            assert step[3]["compiles"] == (1 if i == 0 else 0)
+            kids = _children(spans, step)
+            assert [k[0] for k in kids] == [
+                "engine.step.inputs", "engine.step.launch", "engine.step.wait",
+                "engine.step.retire", "engine.step.calibrate",
+            ]
+            # One after another, on one clock.
+            assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+
+        (admit,) = [sp for sp in spans if sp[0] == "engine.admit"]
+        assert admit[3]["admitted"] == 3 and admit[3]["round_len"] == 5
+        assert admit[3]["compiles"] >= 1
+        assert [k[0] for k in _children(spans, admit)] == [
+            "engine.admit.route", "engine.admit.inputs", "engine.admit.launch",
+            "engine.admit.wait", "engine.admit.retire",
+        ]
+        assert admit[2] <= steps[0][1]
+
+    def test_gc_collection_is_named_in_the_trace(self, tmp_path):
+        import gc
+
+        with jax.profiler.trace(str(tmp_path)):
+            with T.span("test.outer"):
+                gc.collect()
+        spans = _host_spans(tmp_path)
+        outer = next(sp for sp in spans if sp[0] == "test.outer")
+        collections = [sp for sp in _children(spans, outer) if sp[0] == "host.gc"]
+        assert collections and collections[-1][3]["generation"] == 2
+        assert "collected" in collections[-1][3]
+
+
+class TestEngineAccounting:
+    def test_probe_stays_inert_under_tracing(self):
+        # Tracing on does not arm the default probe: no probe GEMM may run
+        # inside a traced window.  Only always=True measures.
+        T.enable()
+        probe = StepTimeProbe(
+            _biglittle(), reps=1,
+            workloads={"big": lambda: None, "little": lambda: None},
+        )
+        assert not probe.active()
+        assert probe(0, [1, 1]) is None
+        assert probe.refreshes == 0
+        armed = StepTimeProbe(
+            _biglittle(), reps=1,
+            workloads={"big": lambda: None, "little": lambda: None},
+            always=True,
+        )
+        assert armed(0, [1, 1]) is not None and armed.refreshes == 1
+
+    def test_compile_counter_counts_only_new_programs(self, small_model):
+        # A prefill length compiles once; its second admission compiles
+        # nothing, and its time goes to prefill_s, not compile_s.
+        cfg, params = small_model
+        eng = ServingEngine(
+            cfg, params, _biglittle(), seq_cap=24, slots_per_pod=4,
+            class_sharded="off", pod_time_hook=None,
+        )
+        rng = np.random.default_rng(7)
+        T.enable()
+        try:
+            counts = []
+            for _ in range(2):
+                eng.submit(rng.integers(0, cfg.vocab, 6), 1)
+                before = eng.stats.compiles, eng.stats.compile_s, eng.stats.prefill_s
+                assert eng.admit() == 1
+                counts.append(eng.stats.compiles - before[0])
+        finally:
+            buf = T.disable()
+        assert counts[0] >= 1 and counts[1] == 0
+        assert eng.stats.compile_s - before[1] == 0.0
+        assert eng.stats.prefill_s > before[2] > 0.0
+        admits = [e for e in buf.events if e.name == "engine.admit"]
+        assert [e.args["compiles"] for e in admits] == counts
+
+    def test_queue_wait_is_observed_at_admission(self, small_model):
+        cfg, params = small_model
+        eng = ServingEngine(
+            cfg, params, _biglittle(), seq_cap=24, slots_per_pod=4,
+            class_sharded="off", pod_time_hook=None,
+        )
+        rng = np.random.default_rng(8)
+
+        def waits():
+            fam = MET.REGISTRY.snapshot().get("engine_queue_wait_seconds")
+            return sum(s["count"] for s in fam["samples"]) if fam else 0
+
+        T.enable()
+        try:
+            before = waits()
+            rids = [eng.submit(rng.integers(0, cfg.vocab, 4), 2) for _ in range(3)]
+            time.sleep(0.02)
+            assert eng.admit() == 3
+        finally:
+            buf = T.disable()
+        assert waits() == before + 3
+        (admit,) = [e for e in buf.events if e.name == "engine.admit"]
+        assert admit.args["rids"] == rids
+        assert admit.args["queue_wait_max_s"] >= 0.02
 
 
 # ---------------------------------------------------------------------------
