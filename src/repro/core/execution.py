@@ -97,23 +97,23 @@ def _pallas_lean_interpret_gemm(a2, b, config, out_dtype):
     return gemm_pallas_lean(a2, b, config, out_dtype=out_dtype, interpret=True)
 
 
-def _paged_attn_xla(q, pages_k, pages_v, page_table, pos):
+def _paged_attn_xla(q, pages_k, pages_v, page_table, pos, layer):
     from repro.kernels.paged_attention import paged_attention_xla
 
-    return paged_attention_xla(q, pages_k, pages_v, page_table, pos)
+    return paged_attention_xla(q, pages_k, pages_v, page_table, pos, layer)
 
 
-def _paged_attn_pallas(q, pages_k, pages_v, page_table, pos):
+def _paged_attn_pallas(q, pages_k, pages_v, page_table, pos, layer):
     from repro.kernels.paged_attention import paged_attention_pallas
 
-    return paged_attention_pallas(q, pages_k, pages_v, page_table, pos)
+    return paged_attention_pallas(q, pages_k, pages_v, page_table, pos, layer)
 
 
-def _paged_attn_pallas_interpret(q, pages_k, pages_v, page_table, pos):
+def _paged_attn_pallas_interpret(q, pages_k, pages_v, page_table, pos, layer):
     from repro.kernels.paged_attention import paged_attention_pallas
 
     return paged_attention_pallas(
-        q, pages_k, pages_v, page_table, pos, interpret=True
+        q, pages_k, pages_v, page_table, pos, layer, interpret=True
     )
 
 
@@ -122,7 +122,7 @@ def _paged_attn_pallas_interpret(q, pages_k, pages_v, page_table, pos):
 # :func:`resolve_paged_attn_backend`, never a table entry.  Entries span
 # more than one *op family* now (GEMM micro-kernels take
 # ``(a2, b, config, out_dtype)``; paged-attention decode kernels take
-# ``(q, pages_k, pages_v, page_table, pos)``) — :data:`BACKEND_OPS` tags
+# ``(q, pages_k, pages_v, page_table, pos, layer)``) — :data:`BACKEND_OPS` tags
 # each name with its family and the dispatch funnels validate the tag, so
 # a tree or CLI flag can never route a GEMM into an attention kernel.
 BACKENDS: dict[str, Callable] = {
@@ -369,20 +369,21 @@ def dispatch_gemm(a2, b, *, config=None, backend: str = "auto", out_dtype=None):
 
 
 def dispatch_paged_attention(
-    q, pages_k, pages_v, page_table, pos, *, backend: str = "auto"
+    q, pages_k, pages_v, page_table, pos, layer, *, backend: str = "auto"
 ):
     """Route a paged decode-attention call through the backend table.
 
     The decode path's funnel: ``layers.decode_attention_paged`` calls this
-    per layer, so the paged kernels live in the same vocabulary — and the
-    same parity harness — as the GEMM micro-kernels.  ``"auto"`` takes the
-    active context's ``paged_attn`` entry.
+    per layer with the stacked arenas and the layer to read, so the paged
+    kernels live in the same vocabulary — and the same parity harness — as
+    the GEMM micro-kernels.  ``"auto"`` takes the active context's
+    ``paged_attn`` entry.
     """
 
     if backend == "auto" and current_context() is not None:
         backend = current_context().paged_attn
     return BACKENDS[resolve_paged_attn_backend(backend)](
-        q, pages_k, pages_v, page_table, pos
+        q, pages_k, pages_v, page_table, pos, layer
     )
 
 

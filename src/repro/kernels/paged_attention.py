@@ -21,10 +21,18 @@ registered in ``execution.BACKENDS`` (op family ``"paged_attn"``):
 
 Shapes (one decode token per row):
 
-  q           (B, Hq, Dh)        the new token's query heads
-  pages_k/v   (P, ps, Hkv, Dh)   the page arena (one layer's)
-  page_table  (B, W)  int32      per-row page ids; ``W * ps == s_cache``
-  pos         (B,)    int32      per-row absolute positions
+  q           (B, Hq, Dh)            the new token's query heads
+  pages_k/v   (L, P, Hkv, ps, Dh)    the stacked arena, every layer's
+  page_table  (B, W)  int32          per-row page ids; ``W * ps == s_cache``
+  pos         (B,)    int32          per-row absolute positions
+  layer       ()      int32          the layer whose pages are read
+
+The arena is the decode state's own leaf, read where it lies: the layer
+loop carries it and updates it in place, and every backend indexes
+``(layer, page)`` itself, so nothing the size of a layer's pool is sliced
+out for the call.  It is head-major, so one ``(page, head)`` pair is one
+contiguous ``(ps, Dh)`` tile: the Pallas kernel's block, with no
+transpose.
 
 Masking: a row attends its logical cache prefix ``[0, min(pos+1,
 s_cache))`` — equivalent to the dense path's linear mask *and* its ring
@@ -49,7 +57,7 @@ NEG_INF = -1e30
 
 def _check_shapes(q, pages_k, pages_v, page_table, pos):
     b, hq, d = q.shape
-    p, ps, hkv, d2 = pages_k.shape
+    _, p, hkv, ps, d2 = pages_k.shape
     if pages_v.shape != pages_k.shape:
         raise ValueError(f"k/v arenas differ: {pages_k.shape} vs {pages_v.shape}")
     if d2 != d or hq % hkv:
@@ -61,18 +69,20 @@ def _check_shapes(q, pages_k, pages_v, page_table, pos):
     return b, hq, d, p, ps, hkv, page_table.shape[1]
 
 
-def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """Materialize per-row dense views: (P, ps, H, D) → (B, W·ps, H, D).
+def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray, layer) -> jnp.ndarray:
+    """Materialize one layer's per-row dense views: (L, P, H, ps, D) →
+    (B, W·ps, H, D), the dense cache lane's layout.
 
-    Sentinel entries clip to the last page; the caller's validity mask
-    must exclude every position they back (the pool's invariant).
+    Only the table's pages are gathered.  Sentinel entries clip to the
+    last page; the caller's validity mask must exclude every position
+    they back (the pool's invariant).
     """
 
-    p, ps, h, d = pages.shape
+    _, p, h, ps, d = pages.shape
     b, w = page_table.shape
     idx = jnp.clip(page_table, 0, p - 1)
-    view = pages[idx]  # (B, W, ps, H, D)
-    return view.reshape(b, w * ps, h, d)
+    view = pages[layer, idx]  # (B, W, H, ps, D)
+    return view.transpose(0, 1, 3, 2, 4).reshape(b, w * ps, h, d)
 
 
 def _valid_mask(pos: jnp.ndarray, s_cache: int) -> jnp.ndarray:
@@ -89,7 +99,7 @@ def _valid_mask(pos: jnp.ndarray, s_cache: int) -> jnp.ndarray:
     return k_idx[None, :] < limit
 
 
-def paged_attention_xla(q, pages_k, pages_v, page_table, pos):
+def paged_attention_xla(q, pages_k, pages_v, page_table, pos, layer):
     """Gather fallback — the dense decode arithmetic over a paged gather.
 
     Primitive-for-primitive the same sequence as
@@ -104,8 +114,8 @@ def paged_attention_xla(q, pages_k, pages_v, page_table, pos):
     s_cache = w * ps
     g = hq // hkv
     ct = pages_k.dtype  # the cache/compute dtype (bf16 policy)
-    view_k = paged_gather(pages_k, page_table)  # (B, s_cache, Hkv, Dh)
-    view_v = paged_gather(pages_v, page_table)
+    view_k = paged_gather(pages_k, page_table, layer)  # (B, s_cache, Hkv, Dh)
+    view_v = paged_gather(pages_v, page_table, layer)
     qg = q.reshape(b, 1, hkv, g, d).astype(ct)
     s = jnp.einsum(
         "bqhgd,bshd->bhgqs", qg, view_k.astype(ct),
@@ -126,7 +136,7 @@ def paged_attention_xla(q, pages_k, pages_v, page_table, pos):
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(table_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale, ps, s_cache):
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -138,8 +148,8 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]  # (G, Dh)
-    k = k_ref[0, 0]  # (ps, Dh) — this step's page
-    v = v_ref[0, 0]
+    k = k_ref[0, 0, 0]  # (ps, Dh) — this step's (page, head) tile
+    v = v_ref[0, 0, 0]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -166,16 +176,17 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         )
 
 
-def paged_attention_pallas(q, pages_k, pages_v, page_table, pos, *,
+def paged_attention_pallas(q, pages_k, pages_v, page_table, pos, layer, *,
                            interpret: bool = False):
     """Pallas route: grid ``(B, Hkv, W)``, the page dim sequential.
 
-    The page table and positions ride as scalar-prefetch operands
-    (``PrefetchScalarGridSpec``), so each grid step's K/V *block index* —
-    which arena page to DMA — is computed from the table before the body
-    runs: ragged, data-dependent paging without host round-trips.
-    Sentinel entries clip to the last page; the in-kernel prefix mask
-    zeroes their contribution.
+    The page table, positions and layer ride as scalar-prefetch operands
+    (``PrefetchScalarGridSpec``), in that order, so each grid step's K/V
+    *block index* — which ``(layer, page, head)`` tile of the stacked
+    arena to DMA — is computed from the table before the body runs:
+    ragged, data-dependent paging without host round-trips, and no copy
+    of the arena.  Sentinel entries clip to the last page; the in-kernel
+    prefix mask zeroes their contribution.
     """
 
     b, hq, d, p_total, ps, hkv, w = _check_shapes(
@@ -184,26 +195,26 @@ def paged_attention_pallas(q, pages_k, pages_v, page_table, pos, *,
     g = hq // hkv
     s_cache = w * ps
     q4 = q.reshape(b, hkv, g, d)
-    # Page-major → head-major pages so one (page, head) pair is one block.
-    kt = pages_k.transpose(0, 2, 1, 3)  # (P, Hkv, ps, Dh)
-    vt = pages_v.transpose(0, 2, 1, 3)
     table = jnp.asarray(page_table, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def page_index(bb, h, j, table_ref, pos_ref):
-        return (jnp.clip(table_ref[bb, j], 0, p_total - 1), h, 0, 0)
+    def page_index(bb, h, j, table_ref, pos_ref, layer_ref):
+        page = jnp.clip(table_ref[bb, j], 0, p_total - 1)
+        return (layer_ref[0], page, h, 0, 0)
+
+    def row_index(bb, h, j, table_ref, pos_ref, layer_ref):
+        return (bb, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, w),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, h, j, t, pp: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, d), page_index),
-            pl.BlockSpec((1, 1, ps, d), page_index),
+            pl.BlockSpec((1, 1, g, d), row_index),
+            pl.BlockSpec((1, 1, 1, ps, d), page_index),
+            pl.BlockSpec((1, 1, 1, ps, d), page_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda bb, h, j, t, pp: (bb, h, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, 1, g, d), row_index),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -221,12 +232,12 @@ def paged_attention_pallas(q, pages_k, pages_v, page_table, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-    )(table, pos, q4, kt, vt)
+    )(table, pos, layer, q4, pages_k, pages_v)
     return out.reshape(b, hq, d)
 
 
-def paged_attention_pallas_interpret(q, pages_k, pages_v, page_table, pos):
-    return paged_attention_pallas(q, pages_k, pages_v, page_table, pos,
+def paged_attention_pallas_interpret(q, pages_k, pages_v, page_table, pos, layer):
+    return paged_attention_pallas(q, pages_k, pages_v, page_table, pos, layer,
                                   interpret=True)
 
 

@@ -118,25 +118,32 @@ def paged_attention_ref(
     pages_v: jnp.ndarray,
     page_table: jnp.ndarray,
     pos: jnp.ndarray,
+    layer,
 ) -> jnp.ndarray:
     """Paged single-token decode-attention oracle (fp32 end to end).
 
     Deliberately *not* the production op order: ungrouped fp32 einsums
-    over an eagerly gathered dense view, so both the XLA gather route and
-    the online-softmax Pallas kernel are checked against independent
-    arithmetic.  Shapes as in ``kernels.paged_attention``: ``q`` is
-    ``(B, Hq, Dh)``, the arenas ``(P, ps, Hkv, Dh)``, the table
-    ``(B, W)`` with ``W·ps`` the logical cache length, ``pos`` ``(B,)``.
+    over an eagerly sliced layer and gathered dense view, so both the XLA
+    gather route and the online-softmax Pallas kernel are checked against
+    independent arithmetic.  Shapes as in ``kernels.paged_attention``:
+    ``q`` is ``(B, Hq, Dh)``, the stacked arenas ``(L, P, Hkv, ps, Dh)``,
+    the table ``(B, W)`` with ``W·ps`` the logical cache length, ``pos``
+    ``(B,)``, ``layer`` the arena layer read.
     """
 
     b, hq, d = q.shape
-    p, ps, hkv, _ = pages_k.shape
+    _, p, hkv, ps, _ = pages_k.shape
     w = page_table.shape[1]
     s_cache = w * ps
     g = hq // hkv
     idx = jnp.clip(page_table, 0, p - 1)
-    view_k = pages_k[idx].reshape(b, s_cache, hkv, d).astype(jnp.float32)
-    view_v = pages_v[idx].reshape(b, s_cache, hkv, d).astype(jnp.float32)
+
+    def view(pages):
+        v = jnp.take(pages, layer, axis=0)[idx]  # (B, W, Hkv, ps, Dh)
+        v = jnp.moveaxis(v, 2, 3).reshape(b, s_cache, hkv, d)
+        return v.astype(jnp.float32)
+
+    view_k, view_v = view(pages_k), view(pages_v)
     qf = q.reshape(b, hkv, g, d).astype(jnp.float32)
     s = jnp.einsum("bhgd,bshd->bhgs", qf, view_k) / np.sqrt(d)
     limit = jnp.minimum(jnp.asarray(pos, jnp.int32)[:, None] + 1, s_cache)

@@ -312,35 +312,40 @@ def decode_attention(p, x, cfg: AttnConfig, cache_k, cache_v, pos, *, live=None)
 
 
 def decode_attention_paged(
-    p, x, cfg: AttnConfig, pages_k, pages_v, page_table, pos, *,
+    p, x, cfg: AttnConfig, pages_k, pages_v, layer, page_table, pos, *,
     live=None, backend: str = "auto",
 ):
-    """Single-token decode against a *paged* KV pool (one layer's arena).
+    """Single-token decode of one layer against the *paged* KV pool.
 
-    x: (B, 1, D); pages_k/v: (P, page_size, Hkv, Dh) — the shared page
-    arena; page_table: (B, W) int32 — each row's pages, where
-    ``W · page_size`` is the logical cache length ``S_cache``; pos: (B,)
-    int32 per-row absolute positions (always per-slot — the paged path
-    only exists for the serving engine).
+    x: (B, 1, D); pages_k/v: (L, P, Hkv, page_size, Dh) — the stacked,
+    head-major page arena of every layer, updated and read in place;
+    layer: int32 scalar — the arena layer this block owns; page_table:
+    (B, W) int32 — each row's pages, where ``W · page_size`` is the
+    logical cache length ``S_cache``; pos: (B,) int32 per-row absolute
+    positions (always per-slot — the paged path only exists for the
+    serving engine).
 
-    Write side mirrors the dense per-slot scatter: the new K/V lands at
-    logical slot ``pos % S_cache`` (ring) / ``pos`` (linear) inside the
-    row's page for that slot; rows whose table entry is unallocated
-    (SENTINEL) or whose linear position is past the cache write nothing
-    (``mode="drop"`` — same semantics as the dense phantom-lane drop).
-    Rows sharing a page (the engine's shared phantom lane) write
-    *identical* values by construction, so scatter order cannot matter.
+    Write side mirrors the dense per-slot scatter: the new K/V row lands
+    at ``(layer, page, head, off)`` for logical slot ``pos % S_cache`` (ring)
+    / ``pos`` (linear) — one scatter of ``B · Hkv · Dh`` values into the
+    stacked arena, in place under donation; rows whose table entry is
+    unallocated (SENTINEL) or whose linear position is past the cache
+    write nothing (``mode="drop"`` — same semantics as the dense
+    phantom-lane drop).  Rows sharing a page (the engine's shared phantom
+    lane) write *identical* values by construction, so scatter order
+    cannot matter.
 
-    Read side routes through ``execution.dispatch_paged_attention``: the
-    XLA gather route reproduces the dense arithmetic bit-for-bit; the
-    Pallas route streams pages with an online softmax (tolerance-tested).
-    ``live`` as in :func:`decode_attention`.
+    Read side routes the stacked arena and the layer through
+    ``execution.dispatch_paged_attention``: the XLA gather route
+    reproduces the dense arithmetic bit-for-bit; the Pallas route streams
+    pages with an online softmax (tolerance-tested).  ``live`` as in
+    :func:`decode_attention`.
     """
 
     from repro.core.execution import dispatch_paged_attention
 
     b = x.shape[0]
-    n_pages, page_size = pages_k.shape[0], pages_k.shape[1]
+    n_pages, page_size = pages_k.shape[1], pages_k.shape[3]
     w = page_table.shape[1]
     s_cache = w * page_size
     pos = jnp.asarray(pos, jnp.int32)
@@ -354,11 +359,17 @@ def decode_attention_paged(
     # makes the scatter drop the row).
     page = jnp.where(slot < s_cache, page, jnp.int32(n_pages))
     off = slot % page_size
-    pages_k = pages_k.at[page, off].set(k[:, 0].astype(pages_k.dtype), mode="drop")
-    pages_v = pages_v.at[page, off].set(v[:, 0].astype(pages_v.dtype), mode="drop")
+    # One scatter window of Dh per (row, head): with the window Dh alone
+    # XLA keeps the arena in its default head-major layout, the kernel's;
+    # a (Hkv, Dh) window makes it lay the arena out page-major and copy
+    # it to and from the kernel's layout every layer.
+    heads = jnp.arange(cfg.n_kv_heads)[None, :]
+    at = (layer, page[:, None], heads, off[:, None])  # (B, Hkv) rows of Dh
+    pages_k = pages_k.at[at].set(k[:, 0].astype(pages_k.dtype), mode="drop")
+    pages_v = pages_v.at[at].set(v[:, 0].astype(pages_v.dtype), mode="drop")
 
     o = dispatch_paged_attention(
-        q[:, 0], pages_k, pages_v, page_table, pos, backend=backend
+        q[:, 0], pages_k, pages_v, page_table, pos, layer, backend=backend
     )  # (B, Hq, Dh)
     if live is not None:
         o = jnp.where(live[:, None, None], o, jnp.zeros((), o.dtype))

@@ -267,9 +267,12 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int):
 def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int):
     """Paged decode cache: one shared page arena per layer, no batch dim.
 
-    Replaces the dense ``(L, B, S_cache, Hkv, Dh)`` lanes with
-    ``(L, n_pages, page_size, Hkv, Dh)`` arenas; rows find their cache
+    Replaces the dense ``(L, B, S_cache, Hkv, Dh)`` lanes with stacked
+    ``(L, n_pages, Hkv, page_size, Dh)`` arenas; rows find their cache
     through the ``batch["page_table"]`` passed to :func:`decode_step`.
+    Head-major, so one ``(page, head)`` pair is a contiguous
+    ``(page_size, Dh)`` tile — the paged kernel's block, read in place —
+    and pages stay at dim 1, where the pod partition shards them.
     Only the pure KV-cache families page — recurrent (Mamba2) state is
     constant-size per slot already, and the hybrid/encdec caches carry
     extra leaves the page pool does not cover.
@@ -281,7 +284,7 @@ def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int):
             f"paged KV state requires a pure KV-cache family, not "
             f"{cfg.family!r} (recurrent state has no pages to allocate)"
         )
-    kv_shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    kv_shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
     return {
         "pages_k": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
         "pages_v": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
@@ -305,12 +308,12 @@ def _decode_attn_block(p, x, cfg, ck, cv, pos, *, with_moe: bool, window=None,
     return x + h, ck, cv
 
 
-def _decode_attn_block_paged(p, x, cfg, pk, pv, table, pos, *, with_moe: bool,
-                             live=None):
+def _decode_attn_block_paged(p, x, cfg, pk, pv, layer, table, pos, *,
+                             with_moe: bool, live=None):
     acfg = attn_config(cfg)
     h, (pk, pv) = L.decode_attention_paged(
         p["attn"], L.rms_norm(x, p["ln1"], cfg.norm_eps), acfg, pk, pv,
-        table, pos, live=live,
+        layer, table, pos, live=live,
     )
     x = x + h
     if with_moe:
@@ -382,15 +385,22 @@ def decode_step(params, cfg: ArchConfig, batch, state, pos):
         if "pages_k" in state:
             table = batch["page_table"]
 
-            def body(x, inputs):
-                p, pk, pv = inputs
+            # The stacked arenas ride in the carry, never in xs/ys: each
+            # layer scatters its rows into them in place and the kernel
+            # reads its pages where they lie, so no layer's pool is
+            # sliced out, written back or copied.
+            def body(carry, inputs):
+                x, pk, pv = carry
+                p, layer = inputs
                 x, pk, pv = _decode_attn_block_paged(
-                    p, x, cfg, pk, pv, table, pos, with_moe=with_moe, live=live
+                    p, x, cfg, pk, pv, layer, table, pos,
+                    with_moe=with_moe, live=live,
                 )
-                return x, (pk, pv)
+                return (x, pk, pv), None
 
-            x, (pks, pvs) = jax.lax.scan(
-                body, x, (params["blocks"], state["pages_k"], state["pages_v"])
+            (x, pks, pvs), _ = jax.lax.scan(
+                body, (x, state["pages_k"], state["pages_v"]),
+                (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
             )
             new_state = {"pages_k": pks, "pages_v": pvs}
         else:

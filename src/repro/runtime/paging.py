@@ -14,8 +14,10 @@ reuses them immediately.
 
 Host-side only: the device never sees this object.  The engine passes a
 fresh ``(B, W)`` int32 page-table array into every jitted step (exactly
-like the per-slot position vector from PR 5), and the arena itself is a
-donated decode-state leaf ``(L, n_pages, page_size, Hkv, Dh)``.
+like the per-slot position vector), and the arena itself is a donated
+decode-state leaf ``(L, n_pages, Hkv, page_size, Dh)`` — head-major, so
+one ``(page, head)`` is a contiguous ``(page_size, Dh)`` tile — that the
+decode step updates and reads in place.
 
 Layout invariants the decode path relies on:
 

@@ -27,6 +27,7 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import execution as X
@@ -120,24 +121,54 @@ PAGED_CASES = [
     (4, 2, 2, 64, 32, 1, 6),
 ]
 
+# Every case reads the last layer of a stacked arena whose layers hold
+# different values: a backend that ignored the layer (or offset it
+# wrongly) would read another layer's keys.
+ARENA_LAYERS = 3
+
 
 def _paged_case(b, hq, hkv, dh, ps, w, pages, dtype, seed=0):
-    """Random arena + per-row tables: allocated prefix pages, SENTINEL
-    beyond, positions drawn past ``s_cache`` too (the ring-wrapped row
-    attends its whole logical cache)."""
+    """Random stacked head-major arena ``(L, P, Hkv, ps, Dh)`` + per-row
+    tables: allocated prefix pages, SENTINEL beyond, positions drawn past
+    ``s_cache`` too (the ring-wrapped row attends its whole logical
+    cache)."""
 
     from repro.runtime.paging import SENTINEL
 
     rng = np.random.default_rng(seed + b * 131 + hq * 17 + ps)
+    shape = (ARENA_LAYERS, pages, hkv, ps, dh)
     q = jnp.asarray(rng.normal(size=(b, hq, dh)), dtype)
-    pk = jnp.asarray(rng.normal(size=(pages, ps, hkv, dh)), dtype)
-    pv = jnp.asarray(rng.normal(size=(pages, ps, hkv, dh)), dtype)
+    pk = jnp.asarray(rng.normal(size=shape), dtype)
+    pv = jnp.asarray(rng.normal(size=shape), dtype)
     pos = rng.integers(0, w * ps + ps, size=(b,))
     table = np.full((b, w), SENTINEL, np.int32)
     for r in range(b):
         need = min(int(pos[r]) // ps + 1, w)
         table[r, :need] = rng.choice(pages, size=need, replace=False)
     return q, pk, pv, jnp.asarray(table), jnp.asarray(pos, jnp.int32)
+
+
+def _paged_numpy(q, pk, pv, table, pos, layer):
+    """The layout spelled out element by element: row ``b``'s key ``t``
+    is ``arena[layer, table[b, t // ps], h, t % ps]``; plain float64
+    softmax attention over its valid prefix."""
+
+    q, pk, pv = (np.asarray(a, np.float64) for a in (q, pk, pv))
+    table, pos = np.asarray(table), np.asarray(pos)
+    b, hq, d = q.shape
+    _, _, hkv, ps, _ = pk.shape
+    s_cache = table.shape[1] * ps
+    out = np.zeros((b, hq, d))
+    for r in range(b):
+        n = min(int(pos[r]) + 1, s_cache)
+        keys = np.stack([pk[layer, table[r, t // ps], :, t % ps] for t in range(n)])
+        vals = np.stack([pv[layer, table[r, t // ps], :, t % ps] for t in range(n)])
+        for h in range(hq):
+            kv = h // (hq // hkv)
+            sc = keys[:, kv] @ q[r, h] / np.sqrt(d)
+            pr = np.exp(sc - sc.max())
+            out[r, h] = (pr / pr.sum()) @ vals[:, kv]
+    return out
 
 
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
@@ -147,12 +178,36 @@ def _paged_case(b, hq, hkv, dh, ps, w, pages, dtype, seed=0):
 @pytest.mark.parametrize("backend", PAGED_BACKENDS)
 def test_paged_attention_matches_oracle(backend, case, dtype):
     q, pk, pv, table, pos = _paged_case(*case, dtype)
-    out = X.BACKENDS[X.interpret_twin(backend)](q, pk, pv, table, pos)
-    expect = ref.paged_attention_ref(q, pk, pv, table, pos)
+    layer = ARENA_LAYERS - 1
+    out = X.BACKENDS[X.interpret_twin(backend)](q, pk, pv, table, pos, layer)
+    expect = ref.paged_attention_ref(q, pk, pv, table, pos, layer)
     assert out.shape == expect.shape and out.dtype == expect.dtype
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(expect, np.float32), **TOLS[dtype]
     )
+
+
+PAGED_ROUTES = {
+    **{name: X.BACKENDS[X.interpret_twin(name)] for name in PAGED_BACKENDS},
+    "oracle": ref.paged_attention_ref,
+}
+
+
+@pytest.mark.parametrize("layer", range(ARENA_LAYERS))
+@pytest.mark.parametrize("route", sorted(PAGED_ROUTES))
+def test_paged_attention_reads_its_layer(route, layer):
+    """Each backend — and the oracle itself — against the layout spelled
+    out element by element, at every layer of the stacked arena, with the
+    layer passed as a traced scalar as the decode loop passes it."""
+
+    q, pk, pv, table, pos = _paged_case(*PAGED_CASES[0], jnp.float32)
+    out = jax.jit(PAGED_ROUTES[route])(q, pk, pv, table, pos, jnp.int32(layer))
+    expect = _paged_numpy(q, pk, pv, table, pos, layer)
+    np.testing.assert_allclose(np.asarray(out, np.float64), expect,
+                               rtol=1e-4, atol=1e-4)
+    if layer:  # the layers differ, so reading the wrong one would show
+        other = _paged_numpy(q, pk, pv, table, pos, 0)
+        assert not np.allclose(expect, other, rtol=1e-2, atol=1e-2)
 
 
 def test_paged_dispatch_routes_by_op_family():
@@ -160,10 +215,10 @@ def test_paged_dispatch_routes_by_op_family():
     result matches the oracle (tolerance — auto may pick either route)."""
 
     q, pk, pv, table, pos = _paged_case(*PAGED_CASES[0], jnp.float32)
-    out = X.dispatch_paged_attention(q, pk, pv, table, pos, backend="auto")
+    out = X.dispatch_paged_attention(q, pk, pv, table, pos, 1, backend="auto")
     np.testing.assert_allclose(
         np.asarray(out, np.float32),
-        np.asarray(ref.paged_attention_ref(q, pk, pv, table, pos), np.float32),
+        np.asarray(ref.paged_attention_ref(q, pk, pv, table, pos, 1), np.float32),
         rtol=1e-4, atol=1e-4,
     )
 

@@ -6,7 +6,10 @@ what it would refuse on the chip — a block over the scoped-VMEM limit, a
 slice off the tiling, a program over the chip's HBM.  Shapes are
 internlm2-1.8b's published widths (d_model 2048, d_ff 8192, vocab 92544,
 16/8 heads of 128) at the serving engine's ``chip_smoke.py`` slot table
-(2 pods x 4 slots, 1024-token lanes).
+(2 pods x 4 slots, 1024-token lanes), and for the paged KV arena's memory
+guards deepseek-7b's (32/32 heads of 128, d_ff 11008, vocab 102400; 3
+layers) at the benchmark's slot table (2 pods x 4 slots, 4096-token
+lanes).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -15,6 +18,7 @@ imports this file.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -97,13 +101,14 @@ def test_gemm_compiles(one_chip, kernel, mkn):
     _compile(kernel, a, b)
 
 
-def _engine_page_size(monkeypatch) -> int:
-    """The page size the engine derives for chip_smoke.py's slot table on a
-    TPU, where the control trees run the Pallas GEMM kernels."""
+def _engine_page_size(monkeypatch, seq_cap: int = SEQ_CAP) -> int:
+    """The page size the engine derives for a slot table of ``seq_cap``
+    lanes on a TPU, where the control trees run the Pallas GEMM kernels
+    and paged attention the Pallas kernel."""
 
     monkeypatch.setattr(X, "on_tpu", lambda: True)
     trees = AsymmetricMesh(biglittle_classes(chips_per_pod=1)).control_trees()
-    return divisor_page_size(SEQ_CAP, min(t.block.bm for t in trees.values()))
+    return divisor_page_size(seq_cap, min(t.block.bm for t in trees.values()))
 
 
 @pytest.mark.parametrize("page_size", ["engine", 128])
@@ -114,11 +119,12 @@ def test_paged_attention_compiles(one_chip, page_size, monkeypatch):
     w = SEQ_CAP // page_size
     n_pages = 2 * (SLOTS // 2 + 1) * w  # per pod: 4 slot lanes + 1 phantom lane
     q = _spec((SLOTS, cfg.n_heads, cfg.head_dim), jnp.bfloat16, one_chip)
-    pages = _spec((n_pages, page_size, cfg.n_kv_heads, cfg.head_dim),
+    pages = _spec((cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim),
                   jnp.bfloat16, one_chip)
     table = _spec((SLOTS, w), jnp.int32, one_chip)
     pos = _spec((SLOTS,), jnp.int32, one_chip)
-    _compile(paged_attention_pallas, q, pages, pages, table, pos)
+    layer = _spec((), jnp.int32, one_chip)
+    _compile(paged_attention_pallas, q, pages, pages, table, pos, layer)
 
 
 def test_decode_step_compiles(one_chip):
@@ -145,3 +151,61 @@ def test_decode_step_compiles(one_chip):
              "live": _spec((SLOTS,), jnp.bool_, one_chip)}
     pos = _spec((SLOTS,), jnp.int32, one_chip)
     _compile(step, params, batch, state, pos, donate_argnums=(2,))
+
+
+LONG_LANE = 4096   # chipbench's deepseek-7b-1chip: 2 pods x 4 slots of 4096
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_paged_arena_is_updated_in_place(one_chip, program, monkeypatch):
+    """The engine's paged decode step and its bulk prefill (a 16-token
+    prompt), arena donated, at deepseek-7b's widths over 3 layers: the
+    stacked K/V arenas alias input to output, and no program keeps a
+    temporary as large as one layer's K pool — the arena is carried
+    through the layer loop and updated in place, never sliced out per
+    layer, transposed for the kernel, written back or copied.
+
+    Weights are bfloat16 here, so the float32 weights' converts (the
+    LM head's alone is 839 MB) do not hide the arena's copies."""
+
+    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=3)
+    page_size = _engine_page_size(monkeypatch, LONG_LANE)
+    w = LONG_LANE // page_size
+    n_pages = 2 * (SLOTS // 2 + 1) * w
+    ctx = AsymmetricMesh(biglittle_classes(chips_per_pod=1)).execution_context()
+    assert ctx.paged_attn_backend() == "paged_attn_pallas"
+    decode = Z.make_decode_fn(cfg)
+
+    def core(params, batch, state, pos):
+        with ctx:
+            return decode(params, batch, state, pos)
+
+    if program == "step":
+        def fn(params, batch, state, pos):
+            logits, state = core(params, batch, state, pos)
+            return jnp.argmax(logits[:, -1], axis=-1), state
+        tokens, extra = (SLOTS, 1), _spec((SLOTS,), jnp.int32, one_chip)
+    else:
+        bulk = Z.bulk_prefill_from_decode(core)
+
+        def fn(params, batch, state, plens):
+            pos0 = jnp.zeros((SLOTS,), jnp.int32)
+            logits, state = bulk(params, batch, state, pos0, plens=plens)
+            return jnp.argmax(logits[:, -1], axis=-1), state
+        tokens, extra = (SLOTS, 16), _spec((SLOTS,), jnp.int32, one_chip)
+
+    place = lambda t, dt=None: jax.tree.map(  # noqa: E731
+        lambda s: _spec(s.shape, dt or s.dtype, one_chip), t)
+    params = place(jax.eval_shape(lambda: Z.init_params(jax.random.PRNGKey(0), cfg)),
+                   jnp.bfloat16)
+    state = place(jax.eval_shape(
+        lambda: Z.init_decode_state_paged(cfg, n_pages, page_size)))
+    batch = {"tokens": _spec(tokens, jnp.int32, one_chip),
+             "page_table": _spec((SLOTS, w), jnp.int32, one_chip),
+             "live": _spec((SLOTS,), jnp.bool_, one_chip)}
+    ma = _compile(fn, params, batch, state, extra, donate_argnums=(2,))
+
+    arena_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(state))
+    layer_pool_bytes = arena_bytes // (2 * cfg.n_layers)
+    assert ma.alias_size_in_bytes >= arena_bytes
+    assert ma.temp_size_in_bytes < layer_pool_bytes

@@ -209,12 +209,12 @@ class TestNoContextDefaults:
         assert X.resolve_paged_attn_backend("auto") == "paged_attn_pallas"
         with _ctx(backend="xla") as ctx:
             assert ctx.paged_attn_backend() == "paged_attn_pallas"
-            X.dispatch_paged_attention(None, None, None, None, None)
+            X.dispatch_paged_attention(None, None, None, None, None, 0)
         ref = X.default_context(backend="xla", paged_attn="paged_attn_xla")
         with ref:
             assert ref.paged_attn_backend() == "paged_attn_xla"
-            X.dispatch_paged_attention(None, None, None, None, None)
-            X.dispatch_paged_attention(None, None, None, None, None,
+            X.dispatch_paged_attention(None, None, None, None, None, 0)
+            X.dispatch_paged_attention(None, None, None, None, None, 0,
                                        backend="paged_attn_pallas")
         assert seen == ["paged_attn_pallas", "paged_attn_xla", "paged_attn_pallas"]
         with pytest.raises(ValueError, match="not a paged-attention"):
