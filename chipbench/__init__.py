@@ -7,7 +7,9 @@ configuration, one traffic mix or one metric is a file of its own, found by
 the name ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json``  the configuration's sizes as run, its source,
-  what was cut, and the engine geometry;
+  what was cut, its model family, and the engine geometry;
+* ``families/<family>.py``   the family's parameter tree, plain reference
+  and work counts (``spec.load_family`` lists what it defines);
 * ``traffic/<mix>.json``     the parameters the one generator
   (:mod:`chipbench.traffic`) reads;
 * ``endtoend/<metric>.py`` and ``metrics/<metric>.py``  one reader each.

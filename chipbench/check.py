@@ -3,7 +3,8 @@
 After the window closes, a sample of the requests that were served (drawn
 from the seed, with the longest and one request of every slot that served
 in it) is run once through the
-reference (``reference.py``, float32 at ``highest``), each request as its
+plain reference of the cell's model family (``families/<family>.py``,
+float32 at ``highest``), each request as its
 prompt followed by the tokens it was served.  At every served position the
 number read is the *gap*: how far the served token's reference logit lies
 below the reference's best logit there.  The engine decodes greedily, so a
@@ -26,8 +27,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from chipbench import reference as R
 
 # Positions of the LM head evaluated at once: (HEAD_CHUNK, vocab) float32.
 HEAD_CHUNK = 256
@@ -71,22 +70,22 @@ def verdict(sample: list[Served], widest_gap: float, compiles: int, limits: dict
     return bool(sample) and widest_gap <= limits["widest_gap"] and compiles == 0
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "bits"))
-def _gaps(params, dims, tokens, idx, served, valid, bits: Optional[int]):
+@functools.partial(jax.jit, static_argnames=("family", "dims", "bits"))
+def _gaps(params, family, dims, tokens, idx, served, valid, bits: Optional[int]):
     """Per position of ``idx``: the reference's gap of ``served`` and, with
     ``bits``, the gap of the token the rounded reference puts first."""
 
     with jax.default_matmul_precision("highest"):
-        h = R.hidden(params, dims, tokens)[idx]
-        hc = R.hidden(params, dims, tokens, bits=bits)[idx] if bits is not None else h
+        h = family.hidden(params, dims, tokens)[idx]
+        hc = family.hidden(params, dims, tokens, bits=bits)[idx] if bits is not None else h
         n = idx.shape[0]
 
         def chunk(i):
             sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * HEAD_CHUNK, HEAD_CHUNK)  # noqa: E731
-            lg = R.logits(params, sl(h))
+            lg = family.logits(params, sl(h))
             best = lg.max(-1)
             got = jnp.take_along_axis(lg, sl(served)[:, None], -1)[:, 0]
-            pick = R.logits(params, sl(hc), bits=bits).argmax(-1) if bits is not None else lg.argmax(-1)
+            pick = family.logits(params, sl(hc), bits=bits).argmax(-1) if bits is not None else lg.argmax(-1)
             ctrl = jnp.take_along_axis(lg, pick[:, None], -1)[:, 0]
             return best - got, best - ctrl, lg.argmax(-1) == sl(served)
 
@@ -97,9 +96,11 @@ def _gaps(params, dims, tokens, idx, served, valid, bits: Optional[int]):
                 jnp.where(v, flat(agree), False).sum())
 
 
-def gaps(params, dims, lane: int, sample: list[Served], *, bits: Optional[int] = None) -> dict:
-    """Widest gap over ``sample``: of the served tokens, and (``bits``) of
-    the rounded reference's first choices; with the argmax agreement."""
+def gaps(params, family, dims, lane: int, sample: list[Served], *,
+         bits: Optional[int] = None) -> dict:
+    """Widest gap over ``sample`` by ``family``'s reference: of the served
+    tokens, and (``bits``) of the rounded reference's first choices; with
+    the argmax agreement."""
 
     widest, widest_ctrl, agree, n = 0.0, 0.0, 0, 0
     n_idx = -(-lane // HEAD_CHUNK) * HEAD_CHUNK
@@ -117,7 +118,7 @@ def gaps(params, dims, lane: int, sample: list[Served], *, bits: Optional[int] =
         served = np.zeros(n_idx, np.int32)
         served[:k] = s.tokens
         valid = np.arange(n_idx) < k
-        g, c, a = _gaps(params, dims, jnp.asarray(tokens), jnp.asarray(idx),
+        g, c, a = _gaps(params, family, dims, jnp.asarray(tokens), jnp.asarray(idx),
                         jnp.asarray(served), jnp.asarray(valid), bits)
         widest, widest_ctrl = max(widest, float(g)), max(widest_ctrl, float(c))
         agree += int(a)

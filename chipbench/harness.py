@@ -1,6 +1,7 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
-Set-up makes the weights on the device from the seed, builds the engine as
+Set-up makes the weights of the cell's model family (``families/<family>.py``)
+on the device from the seed, builds the engine as
 ``repro.launch.serve.make_engine`` builds it (``class_sharded="off"``, no
 tuning cache), warms every prefill length the cell's traffic can produce
 and the decode step by running them, and admits the mix's set-up sessions.
@@ -24,12 +25,13 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Optional
 
 import numpy as np
 
 from chipbench import check, traffic
-from chipbench.model import Dims, Geometry, arch_config, make_params
+from chipbench.model import Geometry, make_params
 from chipbench.spec import Cell
 
 TRACE_SPAN_S = 15.0
@@ -71,7 +73,8 @@ class Run:
     """What a metric reader sees."""
 
     cell: Cell
-    dims: Dims
+    family: ModuleType        # the cell's families/<family>.py
+    dims: object              # the family's dims(config)
     geometry: Geometry
     record: Record
     device_kind: str
@@ -319,13 +322,14 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, root: Path, t_sta
     os.environ.pop("REPRO_TUNING_SPEC", None)
     SH.use_mesh_for_activations(None)
     dev = jax.devices()[0]
-    dims = Dims.from_config(cell.config)
+    family = cell.family
+    dims = family.dims(cell.config)
     geometry = Geometry.from_config(cell.config)
-    cfg = arch_config(cell.config)
+    cfg = family.arch_config(cell.config, dims)
     log(f"{cell.name}: {dev.device_kind} x {jax.device_count()}, compile cache {cache}")
     counter = CompileCounter()
 
-    params = make_params(seed, dims)
+    params = make_params(seed, family, dims)
     eng = build_engine(cfg, params, geometry)
     if engine_hook is not None:
         engine_hook(eng)
@@ -368,8 +372,8 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, root: Path, t_sta
         summary = tracefile.summarize(tracefile.load(path))
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    run_ = Run(cell=cell, dims=dims, geometry=geometry, record=rec, device_kind=dev.device_kind,
-               trace=summary)
+    run_ = Run(cell=cell, family=family, dims=dims, geometry=geometry, record=rec,
+               device_kind=dev.device_kind, trace=summary)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = m.read(run_)
@@ -383,7 +387,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, root: Path, t_sta
     picked = check.sample(served, traffic.rng_for(seed, "check"),
                           min_tokens=CHECK_MIN_TOKENS, min_requests=CHECK_MIN_REQUESTS)
     t_check = time.perf_counter()
-    got = check.gaps(params, dims, geometry.lane, picked, bits=control_bits)
+    got = check.gaps(params, family, dims, geometry.lane, picked, bits=control_bits)
     limits = cell.config["chipbench"]["limits"]
     checks = {
         "widest_gap": {"value": got["widest_gap"], "limit": limits["widest_gap"]},

@@ -3,7 +3,8 @@
 Host-clock readings come from the window's record: every token's host
 stamp and every engine call.  Device
 readings come from the trace summary (``tracefile.Summary``) and the work
-the configuration's shapes imply (``work.py``), against the chip's peaks
+the configuration's shapes imply (the cell's family's counts and
+``work.py``), against the chip's peaks
 (``peaks.py``).  A reader that finds nothing to read returns ``None``.
 """
 
@@ -66,11 +67,11 @@ def _gemm_shapes(run):
     """Every GEMM the traced calls ran: one decode step per step call, one
     per scanned prompt position per admission round."""
 
-    d = run.dims
+    f, d = run.family, run.dims
     shapes = []
     for c in run.calls_in_trace():
         steps = c.round_len if c.kind == "admit" else 1
-        shapes += work.step_gemms(d, c.rows) * steps
+        shapes += f.step_gemms(d, c.rows) * steps
     return shapes
 
 
@@ -93,7 +94,7 @@ def paged_attn_roofline_pct(run):
     if not n or not steps:
         return None
     p = peaks.for_kind(run.device_kind)
-    t = sum(work.paged_attn_roofline_s(run.dims, c.contexts, p.bf16_flops, p.hbm_bytes_per_s)
+    t = sum(run.family.attn_roofline_s(run.dims, c.contexts, p.bf16_flops, p.hbm_bytes_per_s)
             for c in steps)
     return 100.0 * t / (ns * 1e-9)
 
@@ -104,13 +105,13 @@ def step_mfu_pct(run):
 
     if not _traced(run):
         return None
-    d = run.dims
+    f, d = run.family, run.dims
     flops = 0.0
     for c in run.calls_in_trace():
         if c.kind == "admit":
-            flops += sum(work.prompt_flops(d, p) for p in c.contexts)
+            flops += sum(f.prompt_flops(d, p) for p in c.contexts)
         else:
-            flops += sum(work.token_flops(d, k) for k in c.contexts)
+            flops += sum(f.token_flops(d, k) for k in c.contexts)
     if not flops:
         return None
     p = peaks.for_kind(run.device_kind)

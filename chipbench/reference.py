@@ -1,21 +1,15 @@
-"""The plain reference: a dense decoder's forward pass in float32.
+"""What every family's plain reference is built from, in float32.
 
-Written from the published equations of the LLaMA-style dense decoder that
-both configurations are (InternLM2, arXiv:2403.17297; DeepSeek LLM,
-arXiv:2401.02954), with nothing taken from the program under test:
+A family's reference (``families/<family>.py``: ``hidden`` and ``logits``)
+is written from its published equations with these parts, and takes
+nothing from the program under test.  Every product runs in float32 at
+``precision="highest"``.
 
-    h   = x + Wo · attn(RoPE(Wq · n1), RoPE(Wk · n1), Wv · n1),  n1 = RMSNorm(x)
-    out = h + W2 · (silu(W1 · n2) * (W3 · n2)),                   n2 = RMSNorm(h)
-
-with causal grouped-query attention (``n_heads / n_kv_heads`` query heads
-per key head), rotary embeddings on pairs ``(i, i + d/2)`` at the
-configuration's theta, and a final RMSNorm before the untied LM head.
-Every product runs in float32 at ``precision="highest"``.
-
-``mantissa_bits`` is the control: it rounds every weight and every
-matmul input to that many explicit mantissa bits (3 is float8 e4m3's
-mantissa) by arithmetic, so that no compiler pass can fold the rounding
-away, and keeps the exponent range unlimited.
+``round_mantissa`` is the control: with ``bits`` it rounds every weight and
+every matmul input to that many explicit mantissa bits (3 is float8
+e4m3's mantissa) by arithmetic, so that no compiler pass can fold the
+rounding away, and keeps the exponent range unlimited.  A family honours
+``bits`` by making its products through ``_mm``.
 """
 
 from __future__ import annotations
@@ -78,31 +72,3 @@ def attention(q, k, v, *, q_chunk: int):
 
     out = jax.lax.map(chunk, (jnp.arange(s // q_chunk), qg))
     return out.reshape(s, hq * d)
-
-
-def hidden(params, dims, tokens, *, bits: Optional[int] = None, q_chunk: int = 256):
-    """Final-normed hidden states ``(S, d_model)`` of ``tokens`` (S,)."""
-
-    s = tokens.shape[0]
-    q_chunk = min(q_chunk, s)
-    x = params["embed"][tokens].astype(jnp.float32)
-
-    def layer(x, p):
-        a = p["attn"]
-        n1 = rms_norm(x, p["ln1"], dims.norm_eps)
-        q = _mm(n1, a["wq"], bits).reshape(s, dims.n_heads, dims.d_head)
-        k = _mm(n1, a["wk"], bits).reshape(s, dims.n_kv_heads, dims.d_head)
-        v = _mm(n1, a["wv"], bits).reshape(s, dims.n_kv_heads, dims.d_head)
-        o = attention(rope(q, dims.rope_theta), rope(k, dims.rope_theta), v, q_chunk=q_chunk)
-        x = x + _mm(o, a["wo"], bits)
-        m = p["mlp"]
-        n2 = rms_norm(x, p["ln2"], dims.norm_eps)
-        x = x + _mm(jax.nn.silu(_mm(n2, m["w1"], bits)) * _mm(n2, m["w3"], bits), m["w2"], bits)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, params["blocks"])
-    return rms_norm(x, params["final_norm"], dims.norm_eps)
-
-
-def logits(params, h, *, bits: Optional[int] = None):
-    return _mm(h, params["lm_head"], bits)

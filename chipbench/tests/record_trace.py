@@ -40,16 +40,17 @@ def main(argv=None) -> int:
     import jax
 
     from chipbench import harness, spec, traffic
-    from chipbench.model import Dims, Geometry, arch_config, make_params
+    from chipbench.model import Geometry, make_params
 
     if jax.devices()[0].platform != "tpu":
         print("record_trace: no TPU", file=sys.stderr)
         return 1
     cell = spec.load_cell(args.workload, ROOT)
     harness.use_compile_cache(ROOT)
-    dims, geometry = Dims.from_config(cell.config), Geometry.from_config(cell.config)
-    params = make_params(args.seed, dims)
-    eng = harness.build_engine(arch_config(cell.config), params, geometry)
+    family = cell.family
+    dims, geometry = family.dims(cell.config), Geometry.from_config(cell.config)
+    params = make_params(args.seed, family, dims)
+    eng = harness.build_engine(family.arch_config(cell.config, dims), params, geometry)
     plan = traffic.plan(cell.traffic, args.seed, 1.0, vocab=dims.vocab, lane=geometry.lane,
                         pods=geometry.pods, slots_per_pod=geometry.slots_per_pod)
     rec = harness.Record(t_start=time.perf_counter())

@@ -6,9 +6,10 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from chipbench import check, readings, stats, tracefile, traffic, work
+from chipbench import check, readings, spec, stats, tracefile, traffic, work
 from chipbench.harness import Call, Record, Run
-from chipbench.model import Dims
+
+dense = spec.load_family("dense")
 
 
 @pytest.mark.parametrize("n", [1, 2, 7, 100, 1001])
@@ -26,7 +27,7 @@ def test_percentile_takes_all_samples():
 
 
 def _run(rec):
-    return Run(cell=None, dims=None, geometry=None, record=rec, device_kind="cpu")
+    return Run(cell=None, family=None, dims=None, geometry=None, record=rec, device_kind="cpu")
 
 
 def test_window_arithmetic():
@@ -124,12 +125,12 @@ def test_sample_fills_to_the_token_floor():
     assert len(got) == 10 and len({s.rid for s in got}) == 10
 
 
-DIMS = Dims(n_layers=2, d_model=8, n_heads=2, n_kv_heads=1, d_head=4, d_ff=16, vocab=10,
+DIMS = dense.Dims(n_layers=2, d_model=8, n_heads=2, n_kv_heads=1, d_head=4, d_ff=16, vocab=10,
             rope_theta=1e4, norm_eps=1e-6)
 
 
 def test_work_counts():
-    shapes = work.step_gemms(DIMS, rows=3)
+    shapes = dense.step_gemms(DIMS, rows=3)
     assert len(shapes) == 2 * 7 + 1 and shapes[-1] == (3, 8, 10)
     # One GEMM 4x4x4 in bf16: 128 FLOP, 96 bytes.
     assert work.gemm_roofline_s([(4, 4, 4)], 128.0, 1e9) == pytest.approx(1.0)
@@ -137,11 +138,11 @@ def test_work_counts():
     # Paged attention, two rows of 3 and 5 keys, 2 layers: K and V of 8
     # keys x 1 head x 4 dims x 2 bytes x 2 = 128 bytes; q and out 2 rows x
     # 2 heads x 4 x 2 bytes x 2 = 64 bytes; per layer.
-    assert work.paged_attn_roofline_s(DIMS, [3, 5], 1e30, 1.0) == pytest.approx(2 * 192)
+    assert dense.attn_roofline_s(DIMS, [3, 5], 1e30, 1.0) == pytest.approx(2 * 192)
     # Non-embedding parameters: 2 layers x (64+32+32+64 + 3*128 + 16) + 8 + 80.
     n = 2 * (64 + 32 + 32 + 64 + 3 * 128 + 16) + 8 + 80
-    assert work.token_flops(DIMS, 5) == 2 * n + 4 * 2 * 5 * 2 * 4
-    assert work.prompt_flops(DIMS, 3) == sum(work.token_flops(DIMS, k) for k in (1, 2, 3))
+    assert dense.token_flops(DIMS, 5) == 2 * n + 4 * 2 * 5 * 2 * 4
+    assert dense.prompt_flops(DIMS, 3) == sum(dense.token_flops(DIMS, k) for k in (1, 2, 3))
 
 
 def test_mfu_counts_prompts_and_tokens():
@@ -153,9 +154,9 @@ def test_mfu_counts_prompts_and_tokens():
     class Trace:
         window_ns = 1e9
 
-    run = Run(cell=None, dims=DIMS, geometry=None, record=rec, device_kind="TPU v5 lite",
+    run = Run(cell=None, family=dense, dims=DIMS, geometry=None, record=rec, device_kind="TPU v5 lite",
               trace=Trace())
-    flops = (work.prompt_flops(DIMS, 3) + work.prompt_flops(DIMS, 2)
-             + work.token_flops(DIMS, 4) + work.token_flops(DIMS, 3))
+    flops = (dense.prompt_flops(DIMS, 3) + dense.prompt_flops(DIMS, 2)
+             + dense.token_flops(DIMS, 4) + dense.token_flops(DIMS, 3))
     assert readings.step_mfu_pct(run) == pytest.approx(100 * flops / 197e12)
     assert len(readings._gemm_shapes(run)) == (3 + 1) * 15

@@ -13,14 +13,14 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import hostspans, readings, tracefile, work
+from chipbench import hostspans, readings, spec, tracefile
 from chipbench.harness import Call, Record, Run
-from chipbench.model import Dims
 
+dense = spec.load_family("dense")
 DATA = Path(__file__).parent / "data"
 TRACE = DATA / "v5e_longctx_3layer.xplane.pb.gz"
 CALLS = json.loads((DATA / "v5e_longctx_3layer.calls.json").read_text())
-DIMS = Dims(**CALLS["dims"])
+DIMS = dense.Dims(**CALLS["dims"])
 STEPS, ROWS = 3, 8
 CHILDREN = ["engine.step.inputs", "engine.step.launch", "engine.step.wait",
             "engine.step.retire", "engine.step.calibrate"]
@@ -40,7 +40,7 @@ def _run(summary):
     calls = [Call(**{**c, "contexts": tuple(c["contexts"])}) for c in CALLS["calls"]]
     rec = Record(t_start=0.0, t0=calls[0].t0, t1=calls[-1].t1, trace_t0=calls[0].t0)
     rec.calls = calls
-    return Run(cell=None, dims=DIMS, geometry=None, record=rec, device_kind="TPU v5 lite",
+    return Run(cell=None, family=dense, dims=DIMS, geometry=None, record=rec, device_kind="TPU v5 lite",
                trace=summary)
 
 
@@ -49,7 +49,7 @@ def test_paged_kernels_by_stable_name(profile):
     assert s.module_ns(readings.STEP_PROGRAM)[1] == STEPS
     # One paged-attention kernel per layer and step; the step's GEMMs.
     assert _count(s, readings.PAGED_ATTN_KERNEL) == STEPS * DIMS.n_layers
-    assert _count(s, readings.GEMM_KERNEL) == STEPS * len(work.step_gemms(DIMS, ROWS))
+    assert _count(s, readings.GEMM_KERNEL) == STEPS * len(dense.step_gemms(DIMS, ROWS))
 
 
 def test_readers_on_the_paged_trace(profile):

@@ -10,12 +10,12 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import readings, tracefile, work
+from chipbench import readings, spec, tracefile
 from chipbench.harness import Call, Record, Run
-from chipbench.model import Dims
 
+dense = spec.load_family("dense")
 TRACE = Path(__file__).parent / "data" / "v5e_engine_2layer.xplane.pb.gz"
-DIMS = Dims(n_layers=2, d_model=2048, n_heads=16, n_kv_heads=8, d_head=128, d_ff=8192,
+DIMS = dense.Dims(n_layers=2, d_model=2048, n_heads=16, n_kv_heads=8, d_head=128, d_ff=8192,
             vocab=92544, rope_theta=1e6, norm_eps=1e-5)
 ROUND, STEPS, ROWS = 64, 3, 8
 
@@ -53,7 +53,7 @@ def test_programs_and_kernels_by_stable_name(summary):
     # Every GEMM of the model, found by the kernel's name: one decode
     # step's (7 per layer and the head) per scanned prompt position and
     # per decode step.
-    per_step = len(work.step_gemms(DIMS, ROWS))
+    per_step = len(dense.step_gemms(DIMS, ROWS))
     assert per_step == 2 * 7 + 1
     assert own_ns(s, readings.GEMM_KERNEL)[1] == (ROUND + STEPS) * per_step
     # A kernel's time takes in the operations that stage its operands into
@@ -87,7 +87,7 @@ def _run(summary):
                       contexts=(64, 40))]
     rec.calls += [Call("step", 0.4 + i / 10, 0.45 + i / 10, ROWS, contexts=(65 + i, 41 + i))
                   for i in range(STEPS)]
-    return Run(cell=None, dims=DIMS, geometry=None, record=rec, device_kind="TPU v5 lite",
+    return Run(cell=None, family=dense, dims=DIMS, geometry=None, record=rec, device_kind="TPU v5 lite",
                trace=summary)
 
 
