@@ -16,6 +16,7 @@ import dataclasses
 import importlib
 from typing import Optional
 
+from repro.models.layers import MLAConfig
 from repro.models.moe import MoEConfig
 from repro.models.ssm import SSMConfig
 
@@ -58,6 +59,8 @@ class ArchConfig:
     embed_inputs: bool = False  # pixtral: backbone consumes patch embeddings
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None  # latent attention in place of GQA (DeepSeek-V2)
+    first_k_dense: int = 0      # leading layers with a dense MLP (d_ff) before MoE
     shared_attn_every: int = 0  # zamba2: shared attn+mlp block cadence
     enc_layers: int = 0         # whisper: encoder depth (decoder = n_layers)
     enc_frames: int = 1500      # whisper: cross-attention KV length at decode
@@ -85,9 +88,7 @@ class ArchConfig:
         """Analytic total parameter count (used by MODEL_FLOPS)."""
 
         d, f, v, L = self.d_model, self.d_ff, self.vocab, self.n_layers
-        hq = self.n_heads * self.head_dim
-        hkv = self.n_kv_heads * self.head_dim
-        attn = d * hq + 2 * d * hkv + hq * d
+        attn = self._attn_params()
         glu = 3 * d * f
         n = 0
         if not self.embed_inputs:
@@ -97,10 +98,11 @@ class ArchConfig:
             n += L * (attn + glu + 2 * d)
         elif self.family == "moe":
             m = self.moe
-            per = attn + 2 * d + d * m.n_experts + 3 * m.n_experts * d * m.d_ff_expert
+            per = attn + 2 * d + d * m.router_width + 3 * m.n_experts * d * m.d_ff_expert
             if m.d_ff_shared:
-                per += 3 * d * m.d_ff_shared + d
-            n += L * per
+                per += 3 * d * m.d_ff_shared + d * m.shared_gate
+            kd = self.first_k_dense
+            n += (L - kd) * per + kd * (attn + glu + 2 * d)
         elif self.family == "ssm":
             n += L * self._mamba_params()
         elif self.family == "hybrid":
@@ -111,6 +113,18 @@ class ArchConfig:
             n += self.enc_layers * (attn + mlp + 2 * d)
             n += L * (attn + (d * hkv * 2 + d * hq + hq * d) + mlp + 3 * d)
         return n
+
+    def _attn_params(self) -> int:
+        d = self.d_model
+        if self.mla is not None:
+            a = self.mla
+            h = a.n_heads
+            return (d * h * (a.qk_nope_head_dim + a.qk_rope_head_dim) + d * a.latent
+                    + a.kv_lora_rank + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+                    + h * a.v_head_dim * d)
+        hq = self.n_heads * self.head_dim
+        hkv = self.n_kv_heads * self.head_dim
+        return d * hq + 2 * d * hkv + hq * d
 
     def _mamba_params(self) -> int:
         s = self.ssm
@@ -132,13 +146,12 @@ class ArchConfig:
             return self.param_count()
         m = self.moe
         d, L = self.d_model, self.n_layers
-        hq = self.n_heads * self.head_dim
-        hkv = self.n_kv_heads * self.head_dim
-        attn = d * hq + 2 * d * hkv + hq * d
-        per = attn + 2 * d + d * m.n_experts + 3 * m.top_k * d * m.d_ff_expert
+        attn = self._attn_params()
+        per = attn + 2 * d + d * m.router_width + 3 * m.top_k * d * m.d_ff_expert
         if m.d_ff_shared:
-            per += 3 * d * m.d_ff_shared + d
-        return self.vocab * d * 2 + L * per
+            per += 3 * d * m.d_ff_shared + d * m.shared_gate
+        kd = self.first_k_dense
+        return self.vocab * d * 2 + (L - kd) * per + kd * (attn + 3 * d * self.d_ff + 2 * d)
 
     def reduced(self) -> "ArchConfig":
         """Tiny same-family config for CPU smoke tests."""
@@ -168,7 +181,14 @@ class ArchConfig:
                 top_k=min(self.moe.top_k, 2),
                 d_ff_expert=32,
                 d_ff_shared=64 if self.moe.d_ff_shared else 0,
+                norm_topk_prob=self.moe.norm_topk_prob,
+                shared_gate=self.moe.shared_gate,
             )
+        if self.mla is not None:
+            kw["mla"] = dataclasses.replace(
+                self.mla, d_model=64, n_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16)
+            kw["first_k_dense"] = min(self.first_k_dense, 1)
         if self.ssm is not None:
             kw["ssm"] = SSMConfig(d_model=64, d_state=16, headdim=16, expand=2, chunk=8)
         return ArchConfig(**kw)
@@ -185,6 +205,7 @@ _REGISTRY = {
     "mixtral-8x7b": "mixtral_8x7b",
     "qwen2-moe-a2.7b": "qwen2_moe_a2p7b",
     "mamba2-1.3b": "mamba2_1p3b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

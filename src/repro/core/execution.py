@@ -387,6 +387,24 @@ def dispatch_paged_attention(
     )
 
 
+def dispatch_paged_mla(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer, *,
+                       scale: float, backend: str = "auto"):
+    """Route a paged latent-attention decode call (``layers.decode_mla_paged``)
+    to the latent twin of the resolved paged-attention backend: the XLA
+    gather, the Pallas kernel, or its interpreter."""
+
+    from repro.kernels import paged_attention as PA
+
+    if backend == "auto" and current_context() is not None:
+        backend = current_context().paged_attn
+    fn = {
+        "paged_attn_xla": PA.paged_mla_xla,
+        "paged_attn_pallas": PA.paged_mla_pallas,
+        "paged_attn_pallas_interpret": PA.paged_mla_pallas_interpret,
+    }[resolve_paged_attn_backend(backend)]
+    return fn(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer, scale=scale)
+
+
 # ---------------------------------------------------------------------------
 # Block-config resolution (tuned cache -> analytical fallback)
 # ---------------------------------------------------------------------------

@@ -241,9 +241,145 @@ def paged_attention_pallas_interpret(q, pages_k, pages_v, page_table, pos, layer
                                   interpret=True)
 
 
+# ---------------------------------------------------------------------------
+# Paged latent attention (MLA decode, absorbed form)
+# ---------------------------------------------------------------------------
+#
+#   q_c         (B, H, R)          absorbed query q_nope·W_UK, R = kv_lora_rank
+#   q_pe        (B, H, E)          roped query, E = qk_rope_head_dim
+#   pages_c     (L, P, ps, R)      normed c_kv rows, one per token
+#   pages_pe    (L, P, E, ps)      roped k_pe, the page's positions minor
+#   page_table  (B, W), pos (B,), layer ()  as above
+#   returns     (B, H, R) float32: softmax(scale·(q_c·cᵀ + q_pe·pe)) · c
+#
+# Every head reads the same latent rows, so a page is one (ps, R) and one
+# (E, ps) tile for all H heads of a row.
+
+
+def paged_mla_xla(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer, *, scale: float):
+    """Gather route: one layer's pages of each table, masked softmax."""
+
+    from repro.models.layers import mla_latent_attention
+
+    _, p, ps, r = pages_c.shape
+    b, w = page_table.shape
+    idx = jnp.clip(page_table, 0, p - 1)
+    c = pages_c[layer, idx].reshape(b, w * ps, r)
+    pe = pages_pe[layer, idx].transpose(0, 1, 3, 2).reshape(b, w * ps, -1)
+    valid = _valid_mask(jnp.asarray(pos, jnp.int32), w * ps)
+    return mla_latent_attention(q_c, q_pe, c, pe, valid, scale)
+
+
+def _paged_mla_kernel(table_ref, pos_ref, layer_ref, qc_ref, qpe_ref, c_ref, pe_ref, o_ref,
+                      m_ref, l_ref, acc_ref, *, scale, ps, s_cache):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    pos = pos_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # Pages past the row's last live token hold nothing it attends: their
+    # block index repeats the last live page's (no DMA) and they compute
+    # nothing.
+    @pl.when(j * ps <= pos)
+    def _page():
+        c = c_ref[0, 0]                    # (ps, R)
+        s = jax.lax.dot_general(
+            qc_ref[0], c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) + jax.lax.dot_general(
+            qpe_ref[0], pe_ref[0, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                  # (H, ps)
+        s = s * scale
+        idx = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(idx < jnp.minimum(pos + 1, s_cache), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _store():
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def paged_mla_pallas(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer, *,
+                     scale: float, interpret: bool = False):
+    """Pallas route: grid ``(B, W)``, the page dim sequential, the table,
+    positions and layer scalar-prefetched (in that order) so each step
+    DMAs its ``(layer, page)`` tiles of the arenas where they lie."""
+
+    b, h, r = q_c.shape
+    e = q_pe.shape[-1]
+    _, p_total, ps, r2 = pages_c.shape
+    w = page_table.shape[1]
+    if (r2 != r or pages_pe.shape != (pages_c.shape[0], p_total, e, ps)
+            or page_table.shape[0] != b or pos.shape != (b,)):
+        raise ValueError(f"q {q_c.shape}/{q_pe.shape}, pages {pages_c.shape}/"
+                         f"{pages_pe.shape}, table {page_table.shape}, pos {pos.shape}")
+    s_cache = w * ps
+    table = jnp.asarray(page_table, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def page_index(bb, j, table_ref, pos_ref, layer_ref):
+        last = jnp.minimum(pos_ref[bb], s_cache - 1) // ps
+        page = jnp.clip(table_ref[bb, jnp.minimum(j, last)], 0, p_total - 1)
+        return (layer_ref[0], page, 0, 0)
+
+    def row_index(bb, j, table_ref, pos_ref, layer_ref):
+        return (bb, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, w),
+        in_specs=[
+            pl.BlockSpec((1, h, r), row_index),
+            pl.BlockSpec((1, h, e), row_index),
+            pl.BlockSpec((1, 1, ps, r), page_index),
+            pl.BlockSpec((1, 1, e, ps), page_index),
+        ],
+        out_specs=pl.BlockSpec((1, h, r), row_index),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, r), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_paged_mla_kernel, scale=float(scale), ps=ps, s_cache=s_cache)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, r), jnp.float32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+    )(table, pos, layer, q_c, q_pe, pages_c, pages_pe)
+
+
+def paged_mla_pallas_interpret(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer, *,
+                               scale: float):
+    return paged_mla_pallas(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer,
+                            scale=scale, interpret=True)
+
+
 __all__ = [
     "paged_attention_xla",
     "paged_attention_pallas",
     "paged_attention_pallas_interpret",
     "paged_gather",
+    "paged_mla_xla",
+    "paged_mla_pallas",
+    "paged_mla_pallas_interpret",
 ]

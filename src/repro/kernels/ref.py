@@ -154,10 +154,32 @@ def paged_attention_ref(
     return o.reshape(b, hq, d).astype(q.dtype)
 
 
+def paged_mla_ref(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer, *, scale: float):
+    """Paged latent-attention oracle (fp32 end to end): the layer sliced
+    out eagerly, each row's pages gathered, the keys rebuilt as
+    ``[c | pe]`` rows and one plain masked softmax.  Shapes as in
+    ``kernels.paged_attention.paged_mla_pallas``."""
+
+    b = q_c.shape[0]
+    _, p, ps, r = pages_c.shape
+    w = page_table.shape[1]
+    idx = jnp.clip(page_table, 0, p - 1)
+    c = jnp.take(pages_c, layer, axis=0).astype(jnp.float32)[idx].reshape(b, w * ps, r)
+    pe = jnp.take(pages_pe, layer, axis=0).astype(jnp.float32)[idx]      # (B, W, E, ps)
+    pe = jnp.swapaxes(pe, 2, 3).reshape(b, w * ps, -1)
+    keys = jnp.concatenate([c, pe], axis=-1)
+    q = jnp.concatenate([q_c, q_pe], axis=-1).astype(jnp.float32)
+    s = jnp.einsum("bhc,bsc->bhs", q, keys) * scale
+    limit = jnp.minimum(jnp.asarray(pos, jnp.int32)[:, None] + 1, w * ps)
+    s = jnp.where((jnp.arange(w * ps)[None, :] < limit)[:, None, :], s, -1e30)
+    return jnp.einsum("bhs,bsc->bhc", jax.nn.softmax(s, axis=-1), c)
+
+
 __all__ = [
     "gemm_ref",
     "blocked_gemm_ref",
     "blocked_gemm_tpu_ref",
     "attention_ref",
     "paged_attention_ref",
+    "paged_mla_ref",
 ]

@@ -104,7 +104,8 @@ def chunked_attention(
 ):
     """GQA-native attention, chunked over queries (scores ≤ q_chunk × S_k).
 
-    q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D) with Hq % Hkv == 0.  Grouped
+    q: (B, Sq, Hq, D); k: (B, Sk, Hkv, D); v: (B, Sk, Hkv, Dv) with
+    Hq % Hkv == 0.  Grouped
     einsums keep the KV-head dim explicit — repeating KV heads materializes
     a G×-larger tensor and (sharded) triggers involuntary SPMD
     rematerialization, measured at +115 GiB/device on mixtral decode
@@ -158,8 +159,8 @@ def chunked_attention(
         o = jnp.einsum("bhgqs,bhsd->bhgqd", p, vc, preferred_element_type=jnp.float32)
         return o.astype(q.dtype)
 
-    out = jax.lax.map(one_chunk, jnp.arange(n_chunks))  # (n,B,Hkv,G,qc,D)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, n_chunks * q_chunk, hq, d)
+    out = jax.lax.map(one_chunk, jnp.arange(n_chunks))  # (n,B,Hkv,G,qc,Dv)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, n_chunks * q_chunk, hq, v.shape[-1])
     return out[:, :sq]
 
 
@@ -377,6 +378,237 @@ def decode_attention_paged(
     return ops.linear(o, p["wo"].astype(COMPUTE_DTYPE)), (pages_k, pages_v)
 
 
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling as DeepSeek-V2's ``rope_scaling`` states it."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn: Optional[Yarn]) -> np.ndarray:
+    """Rotary frequencies of ``dim / 2`` pairs; with ``yarn``, the pairs
+    below the correction range keep ``f``, those above it take ``f /
+    factor``, and a linear ramp blends the range between."""
+
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if yarn is None:
+        return f.astype(np.float32)
+
+    def corr(rot):
+        return dim * np.log(yarn.original_max_position / (rot * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(corr(yarn.beta_fast))), 0)
+    high = min(int(np.ceil(corr(yarn.beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (f / yarn.factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_pairs(x, positions, inv_freq):
+    """Rotate the pairs ``(2i, 2i+1)`` of ``x`` (..., S, H, D) by
+    ``positions`` (..., S) times ``inv_freq`` (D/2,)."""
+
+    d = x.shape[-1]
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    xp = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    x1, x2 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Latent attention without query compression (``q_lora_rank`` null).
+
+    The cache holds one latent row per token: the normed ``c_kv``
+    (``kv_lora_rank``) then the roped shared key ``k_pe``
+    (``qk_rope_head_dim``)."""
+
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    yarn: Optional[Yarn] = None
+
+    @property
+    def latent(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def scale(self) -> float:
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.yarn is not None and self.yarn.mscale_all_dim:
+            s *= yarn_mscale(self.yarn.factor, self.yarn.mscale_all_dim) ** 2
+        return s
+
+    def inv_freq(self) -> np.ndarray:
+        return yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta, self.yarn)
+
+
+def init_mla(key, cfg: MLAConfig):
+    ks = jax.random.split(key, 4)
+    h = cfg.n_heads
+    return {
+        "wq": dense_init(ks[0], (cfg.d_model, h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))),
+        "wkv_a": dense_init(ks[1], (cfg.d_model, cfg.latent)),
+        "kv_norm": jnp.ones((cfg.kv_lora_rank,), PARAM_DTYPE),
+        "wkv_b": dense_init(ks[2], (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+        "wo": dense_init(ks[3], (h * cfg.v_head_dim, cfg.d_model)),
+    }
+
+
+def _mla_q_latent(p, x, cfg: MLAConfig, positions):
+    """``q`` split into (nope, roped pe) heads and the token's latent row
+    ``[RMSNorm(c_kv) | RoPE(k_pe)]``.  x: (B, S, D)."""
+
+    b, s, _ = x.shape
+    c = lambda w: w.astype(COMPUTE_DTYPE)
+    nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    inv = cfg.inv_freq()
+    q = ops.linear(x, c(p["wq"])).reshape(b, s, cfg.n_heads, nope + rope_d)
+    q_pe = rope_pairs(q[..., nope:], positions, inv)
+    kv = ops.linear(x, c(p["wkv_a"]))
+    c_kv = rms_norm(kv[..., : cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    k_pe = rope_pairs(kv[..., None, cfg.kv_lora_rank:], positions, inv)[..., 0, :]
+    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], axis=-1)
+
+
+def apply_mla(p, x, cfg: MLAConfig, *, positions=None):
+    """Full-form latent attention over the sequence (training / prefill):
+    keys and values expanded per head from the latent.  x: (B, S, D)."""
+
+    b, s, _ = x.shape
+    if positions is None:
+        positions = jnp.arange(s)[None, :]
+    h, nope, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q_nope, q_pe, lat = _mla_q_latent(p, x, cfg, positions)
+    kv = ops.linear(lat[..., :r], p["wkv_b"].astype(COMPUTE_DTYPE))
+    kv = kv.reshape(b, s, h, nope + cfg.v_head_dim)
+    k_pe = jnp.broadcast_to(lat[..., None, r:], (b, s, h, cfg.qk_rope_head_dim))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_pe.astype(kv.dtype)], axis=-1)
+    with jax.named_scope("mla.attn"):
+        o = chunked_attention(q, k, kv[..., nope:], causal=True, scale=cfg.scale)
+    o = o.reshape(b, s, h * cfg.v_head_dim)
+    return ops.linear(o, p["wo"].astype(COMPUTE_DTYPE)), lat
+
+
+def _mla_absorb(p, cfg: MLAConfig):
+    """``W_UK`` and ``W_UV`` of ``wkv_b``, each (kv_lora_rank, H, 128)."""
+
+    w = p["wkv_b"].astype(COMPUTE_DTYPE).reshape(
+        cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _mla_decode_q(p, x, cfg: MLAConfig, pos):
+    """Absorbed query ``q_nope · W_UK`` (B, H, rank) and roped ``q_pe``
+    (B, H, rope), and the new token's latent row (B, rank + rope), at
+    per-row positions ``pos`` (B,)."""
+
+    q_nope, q_pe, lat = _mla_q_latent(p, x, cfg, pos[:, None])
+    w_uk, _ = _mla_absorb(p, cfg)
+    q_c = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_uk, preferred_element_type=jnp.float32)
+    return q_c.astype(COMPUTE_DTYPE), q_pe[:, 0].astype(COMPUTE_DTYPE), lat[:, 0]
+
+
+def _mla_decode_out(p, o_c, cfg: MLAConfig, live):
+    """``W_UV`` after the weighted latent sum, then ``o_proj``.
+    o_c: (B, H, kv_lora_rank)."""
+
+    b = o_c.shape[0]
+    if live is not None:
+        o_c = jnp.where(live[:, None, None], o_c, jnp.zeros((), o_c.dtype))
+    _, w_uv = _mla_absorb(p, cfg)
+    o = jnp.einsum("bhc,chd->bhd", o_c.astype(COMPUTE_DTYPE), w_uv,
+                   preferred_element_type=jnp.float32).astype(COMPUTE_DTYPE)
+    o = o.reshape(b, 1, cfg.n_heads * cfg.v_head_dim)
+    return ops.linear(o, p["wo"].astype(COMPUTE_DTYPE))
+
+
+def mla_latent_attention(q_c, q_pe, c, pe, valid, scale: float):
+    """Absorbed attention over latent rows: ``q_c`` (B, H, rank) against
+    ``c`` (B, S, rank) plus ``q_pe`` (B, H, rope) against ``pe`` (B, S,
+    rope); ``valid`` (B, S).  Returns the weighted sum of ``c``, (B, H,
+    rank), in float32."""
+
+    ct = q_c.dtype
+    s = jnp.einsum("bhc,bsc->bhs", q_c, c.astype(ct), preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("bhr,bsr->bhs", q_pe, pe.astype(ct), preferred_element_type=jnp.float32)
+    s = jnp.where(valid[:, None, :], s * scale, -1e30)
+    pr = jax.nn.softmax(s, axis=-1).astype(ct)
+    return jnp.einsum("bhs,bsc->bhc", pr, c.astype(ct), preferred_element_type=jnp.float32)
+
+
+def decode_mla(p, x, cfg: MLAConfig, cache_c, cache_pe, pos, *, live=None):
+    """One decode token against dense latent lanes, absorbed form.
+
+    x: (B, 1, D); cache_c: (B, S_cache, rank); cache_pe: (B, S_cache,
+    rope); pos: (B,) per-row positions (a row past the lane writes
+    nothing)."""
+
+    b, s_cache = x.shape[0], cache_c.shape[1]
+    r = cfg.kv_lora_rank
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    with jax.named_scope("mla.attn"):
+        q_c, q_pe, row = _mla_decode_q(p, x, cfg, pos)
+        rows = jnp.arange(b)
+        cache_c = cache_c.at[rows, pos].set(row[:, :r].astype(cache_c.dtype), mode="drop")
+        cache_pe = cache_pe.at[rows, pos].set(row[:, r:].astype(cache_pe.dtype), mode="drop")
+        valid = jnp.arange(s_cache)[None, :] <= jnp.minimum(pos[:, None], s_cache - 1)
+        o_c = mla_latent_attention(q_c, q_pe, cache_c, cache_pe, valid, cfg.scale)
+    return _mla_decode_out(p, o_c, cfg, live), (cache_c, cache_pe)
+
+
+def decode_mla_paged(p, x, cfg: MLAConfig, pages_c, pages_pe, layer, page_table, pos, *,
+                     live=None, backend: str = "auto"):
+    """One decode token of one layer against the stacked latent arenas,
+    updated and read in place: ``pages_c`` (L, P, page_size, rank) holds
+    the normed ``c_kv`` rows, ``pages_pe`` (L, P, rope, page_size) the
+    roped ``k_pe`` with the page's positions minor (a minor dim of 64
+    would be laid out transposed by the compiler, and copied for the
+    kernel every layer).  The row is written at ``(layer, page, off)``
+    (dropped past the lane or on an unallocated page), then the paged
+    latent kernel attends each row's pages with the absorbed query."""
+
+    from repro.core.execution import dispatch_paged_mla
+
+    b = x.shape[0]
+    n_pages, page_size = pages_c.shape[1], pages_c.shape[2]
+    w = page_table.shape[1]
+    r = cfg.kv_lora_rank
+    pos = jnp.asarray(pos, jnp.int32)
+    with jax.named_scope("mla.attn"):
+        q_c, q_pe, row = _mla_decode_q(p, x, cfg, pos)
+        page = page_table[jnp.arange(b), jnp.clip(pos // page_size, 0, w - 1)]
+        page = jnp.where(pos < w * page_size, page, jnp.int32(n_pages))
+        off = pos % page_size
+        pages_c = pages_c.at[layer, page, off].set(row[:, :r].astype(pages_c.dtype), mode="drop")
+        pages_pe = pages_pe.at[layer, page, :, off].set(row[:, r:].astype(pages_pe.dtype),
+                                                        mode="drop")
+        o_c = dispatch_paged_mla(q_c, q_pe, pages_c, pages_pe, page_table, pos, layer,
+                                 scale=cfg.scale, backend=backend)
+    return _mla_decode_out(p, o_c, cfg, live), (pages_c, pages_pe)
+
+
 def cross_attention(p, x, enc_k, enc_v, cfg: AttnConfig):
     """Decoder→encoder attention (Whisper). enc_k/v precomputed (B,Se,Hkv,Dh)."""
 
@@ -469,6 +701,15 @@ __all__ = [
     "apply_attention",
     "decode_attention",
     "decode_attention_paged",
+    "Yarn",
+    "MLAConfig",
+    "yarn_inv_freq",
+    "rope_pairs",
+    "init_mla",
+    "apply_mla",
+    "decode_mla",
+    "decode_mla_paged",
+    "mla_latent_attention",
     "cross_attention",
     "init_cross_kv",
     "encode_cross_kv",

@@ -153,12 +153,14 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int):
     return T.init_decode_state(cfg, batch, seq_len)
 
 
-def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int):
-    """Paged decode cache (pure KV-cache families only; see transformer)."""
+def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int,
+                            rows=None):
+    """Paged decode cache (pure KV-cache families only; see transformer);
+    ``rows``, the step's row count, sizes a MoE family's routing leaf."""
 
     if cfg.family == "encdec":
         raise ValueError("paged KV state does not cover the encdec cross-KV cache")
-    return T.init_decode_state_paged(cfg, n_pages, page_size)
+    return T.init_decode_state_paged(cfg, n_pages, page_size, rows=rows)
 
 
 def batch_spec(cfg: ArchConfig, shape: ShapeSpec) -> dict:

@@ -1,4 +1,4 @@
-"""Mixture-of-Experts FFN with capacity-based dispatch.
+"""Mixture-of-Experts FFN: capacity dispatch for training, dropless for serving.
 
 Implements top-k routing (Mixtral 8×top-2; Qwen2-MoE 60×top-4 + shared
 experts) via the scatter/gather capacity formulation: tokens are grouped by
@@ -38,20 +38,56 @@ class MoEConfig:
     # wide-expert MoE like Mixtral; measured to HURT fine-grained-expert
     # MoE, whose weights are FSDP-only — see EXPERIMENTS.md §Perf C).
     rs_output: bool = True
+    n_router: Optional[int] = None  # router outputs (all experts); None: n_experts
+    first_expert: int = 0           # the first held expert's router index
+    norm_topk_prob: bool = True     # renormalise the top-k weights to sum to 1
+    shared_gate: bool = True        # sigmoid gate on the shared expert
+
+    @property
+    def router_width(self) -> int:
+        return self.n_router or self.n_experts
 
 
 def init_moe(key, cfg: MoEConfig):
     ks = jax.random.split(key, 5)
     p = {
-        "router": L.dense_init(ks[0], (cfg.d_model, cfg.n_experts), scale=0.02),
+        "router": L.dense_init(ks[0], (cfg.d_model, cfg.router_width), scale=0.02),
         "w1": L.dense_init(ks[1], (cfg.n_experts, cfg.d_model, cfg.d_ff_expert)),
         "w3": L.dense_init(ks[2], (cfg.n_experts, cfg.d_model, cfg.d_ff_expert)),
         "w2": L.dense_init(ks[3], (cfg.n_experts, cfg.d_ff_expert, cfg.d_model)),
     }
     if cfg.d_ff_shared:
         p["shared"] = L.init_glu(ks[4], cfg.d_model, cfg.d_ff_shared)
-        p["shared_gate"] = L.dense_init(ks[4], (cfg.d_model, 1), scale=0.02)
+        if cfg.shared_gate:
+            p["shared_gate"] = L.dense_init(ks[4], (cfg.d_model, 1), scale=0.02)
     return p
+
+
+def route(p, x2, cfg: MoEConfig):
+    """Router of tokens ``x2`` (T, D): softmax over the full router width
+    in float32, greedy top-k, optionally renormalised.  Returns ``(probs (T, n_router), weights (T, k),
+    held-local expert ids (T, k), held (T, k) bool)``."""
+
+    logits = jnp.einsum("td,de->te", x2.astype(jnp.float32), p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    local = idx - cfg.first_expert
+    held = (local >= 0) & (local < cfg.n_experts)
+    return probs, w, local, held
+
+
+def _shared(p, xc, cfg: MoEConfig):
+    y = L.apply_glu(p["shared"], xc)
+    if not cfg.shared_gate:
+        return y
+    g = jax.nn.sigmoid(
+        jnp.einsum("...d,do->...o", xc, p["shared_gate"].astype(L.COMPUTE_DTYPE),
+                   preferred_element_type=jnp.float32)
+    ).astype(L.COMPUTE_DTYPE)
+    return g * y
 
 
 def _capacity(tokens_per_group: int, cfg: MoEConfig) -> int:
@@ -85,27 +121,30 @@ def apply_moe(p, x, cfg: MoEConfig):
     cap = _capacity(s, cfg)
 
     xc = x.astype(L.COMPUTE_DTYPE)
-    logits = jnp.einsum(
-        "bsd,de->bse", xc, p["router"].astype(L.COMPUTE_DTYPE),
-        preferred_element_type=jnp.float32,
-    )
-    probs = jax.nn.softmax(logits, axis=-1)               # (B,S,E) fp32
-    gate_w, expert_idx = jax.lax.top_k(probs, kk)         # (B,S,k)
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    er = cfg.router_width
+    probs, gate_w, expert_idx, held = route(p, xc.reshape(b * s, d), cfg)
+    probs = probs.reshape(b, s, er)                       # (B,S,E_router) fp32
+    gate_w = gate_w.reshape(b, s, kk)
+    held = held.reshape(b, s, kk)
+    router_idx = (expert_idx + cfg.first_expert).reshape(b, s, kk)
+    expert_idx = jnp.where(held, expert_idx.reshape(b, s, kk), 0)   # held-local
 
-    # Load-balance auxiliary loss (Switch-style), computed per group.
-    me = probs.mean(axis=1)                               # (B,E)
-    ce = jnp.zeros((b, e), jnp.float32)
+    # Load-balance auxiliary loss (Switch-style), computed per group over
+    # the router's full width.
+    me = probs.mean(axis=1)                               # (B,E_router)
+    ce = jnp.zeros((b, er), jnp.float32)
     for j in range(kk):  # k is tiny (2 or 4)
-        ce = ce + jax.nn.one_hot(expert_idx[..., j], e, dtype=jnp.float32).mean(axis=1)
-    aux = (me * ce).sum(-1).mean() * e * cfg.router_aux_weight
+        ce = ce + jax.nn.one_hot(router_idx[..., j], er, dtype=jnp.float32).mean(axis=1)
+    aux = (me * ce).sum(-1).mean() * er * cfg.router_aux_weight
 
-    # Position of each routing decision inside its expert's capacity buffer.
+    # Position of each routing decision inside its expert's capacity buffer
+    # (choices of experts held elsewhere take no place).
     flat_e = expert_idx.reshape(b, s * kk)                # (B, S*k)
-    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)       # (B, S*k, E)
+    flat_held = held.reshape(b, s * kk)
+    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32) * flat_held[..., None]  # (B, S*k, E)
     pos = jnp.cumsum(oh, axis=1) - 1                      # (B, S*k, E)
     pos = jnp.take_along_axis(pos, flat_e[..., None], axis=-1)[..., 0]  # (B, S*k)
-    keep = (pos < cap).astype(jnp.float32) * gate_w.reshape(b, s * kk)
+    keep = (flat_held & (pos < cap)).astype(jnp.float32) * gate_w.reshape(b, s * kk)
 
     # Scatter tokens into (E, C, D) buffers per group.
     xr = jnp.repeat(xc, kk, axis=1)                        # (B, S*k, D)
@@ -141,22 +180,58 @@ def apply_moe(p, x, cfg: MoEConfig):
     y = y.reshape(b, s, kk, d).sum(axis=2)
 
     if cfg.d_ff_shared:
-        g = jax.nn.sigmoid(
-            jnp.einsum("bsd,do->bso", xc, p["shared_gate"].astype(L.COMPUTE_DTYPE),
-                       preferred_element_type=jnp.float32)
-        ).astype(L.COMPUTE_DTYPE)
-        y = y + g * L.apply_glu(p["shared"], xc)
+        y = y + _shared(p, xc, cfg)
     return y.astype(x.dtype), aux
+
+
+def apply_moe_dropless(p, x, cfg: MoEConfig):
+    """Dropless expert layer: x (..., D) -> (y, routed (T, n_experts) int32).
+
+    Every token's top-k choices that name a held expert are computed; the
+    choices are sorted by expert and each held expert's group runs through
+    ``ragged_dot``, which reads that expert's weights once for its whole
+    group.  Choices of experts held elsewhere sort last, past every group,
+    and add nothing.  ``routed[t, e]`` is 1 where token ``t`` chose held
+    expert ``e`` (the per-row routing the serving engine counts).
+    """
+
+    shape = x.shape
+    d = shape[-1]
+    xc = x.reshape(-1, d).astype(L.COMPUTE_DTYPE)
+    t, kk, e = xc.shape[0], cfg.top_k, cfg.n_experts
+    c = lambda w: w.astype(L.COMPUTE_DTYPE)
+    with jax.named_scope("moe.route"):
+        _, w, local, held = route(p, xc, cfg)
+        key = jnp.where(held, local, e).reshape(-1)           # (T*k,), e = not held
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+        rows = order // kk                                     # token of each sorted choice
+        gate = jnp.where(held, w, 0.0).reshape(-1)[order]
+        xs = xc[rows]                                          # (T*k, D)
+        routed = jax.nn.one_hot(key, e + 1, dtype=jnp.int32)[:, :e].reshape(t, kk, e).sum(1)
+    with jax.named_scope("moe.experts"):
+        h1 = jax.lax.ragged_dot(xs, c(p["w1"]), sizes, preferred_element_type=jnp.float32)
+        h3 = jax.lax.ragged_dot(xs, c(p["w3"]), sizes, preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(h1) * h3).astype(L.COMPUTE_DTYPE)
+        out = jax.lax.ragged_dot(h, c(p["w2"]), sizes, preferred_element_type=jnp.float32)
+    with jax.named_scope("moe.combine"):
+        inside = jnp.arange(t * kk) < sizes.sum()
+        contrib = jnp.where(inside[:, None], out * gate[:, None], 0.0)
+        y = jnp.zeros((t, d), jnp.float32).at[rows].add(contrib)
+    if cfg.d_ff_shared:
+        y = y + _shared(p, xc, cfg).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(shape), routed
 
 
 def moe_active_params(cfg: MoEConfig) -> int:
     """Per-token active parameter count (for MODEL_FLOPS = 6·N_active·D)."""
 
     expert = 3 * cfg.d_model * cfg.d_ff_expert
-    n = cfg.top_k * expert + cfg.d_model * cfg.n_experts
+    n = cfg.top_k * expert + cfg.d_model * cfg.router_width
     if cfg.d_ff_shared:
-        n += 3 * cfg.d_model * cfg.d_ff_shared + cfg.d_model
+        n += 3 * cfg.d_model * cfg.d_ff_shared + cfg.d_model * cfg.shared_gate
     return n
 
 
-__all__ = ["MoEConfig", "init_moe", "apply_moe", "moe_active_params"]
+__all__ = ["MoEConfig", "init_moe", "route", "apply_moe", "apply_moe_dropless",
+           "moe_active_params"]

@@ -11,7 +11,11 @@ Structure:
     ``shared_attn_every`` with a weight-shared attention+MLP block between
     groups,
   * decode carries caches through the same scan (KV ring buffers for SWA,
-    constant-size SSD states for Mamba2).
+    constant-size SSD states for Mamba2, latent rows for MLA),
+  * ``first_k_dense`` leading layers (DeepSeek-V2) are a dense-MLP stack
+    of their own (``params["dense"]``) scanned before the MoE stack,
+  * serving (decode and inference prefill) routes MoE tokens dropless;
+    training keeps capacity dispatch.
 """
 
 from __future__ import annotations
@@ -59,19 +63,25 @@ def block_kind(cfg: ArchConfig) -> str:
 # ---------------------------------------------------------------------------
 
 
+def _init_attn(key, cfg: ArchConfig):
+    if cfg.mla is not None:
+        return L.init_mla(key, cfg.mla)
+    return L.init_attention(key, attn_config(cfg))
+
+
 def _init_block(key, cfg: ArchConfig, kind: str):
     ks = jax.random.split(key, 4)
     if kind == "attn_mlp":
         return {
             "ln1": jnp.ones((cfg.d_model,), L.PARAM_DTYPE),
-            "attn": L.init_attention(ks[0], attn_config(cfg)),
+            "attn": _init_attn(ks[0], cfg),
             "ln2": jnp.ones((cfg.d_model,), L.PARAM_DTYPE),
             "mlp": L.init_glu(ks[1], cfg.d_model, cfg.d_ff),
         }
     if kind == "attn_moe":
         return {
             "ln1": jnp.ones((cfg.d_model,), L.PARAM_DTYPE),
-            "attn": L.init_attention(ks[0], attn_config(cfg)),
+            "attn": _init_attn(ks[0], cfg),
             "ln2": jnp.ones((cfg.d_model,), L.PARAM_DTYPE),
             "moe": M.init_moe(ks[1], cfg.moe),
         }
@@ -87,7 +97,8 @@ def init_lm(key, cfg: ArchConfig):
     kind = block_kind(cfg)
     k_emb, k_blocks, k_head, k_shared = jax.random.split(key, 4)
     layer_keys = jax.random.split(k_blocks, cfg.n_layers)
-    blocks = jax.vmap(lambda k: _init_block(k, cfg, kind))(layer_keys)
+    kd = cfg.first_k_dense
+    blocks = jax.vmap(lambda k: _init_block(k, cfg, kind))(layer_keys[kd:])
     params: dict[str, Any] = {
         "blocks": blocks,
         "final_norm": jnp.ones((cfg.d_model,), L.PARAM_DTYPE),
@@ -97,7 +108,23 @@ def init_lm(key, cfg: ArchConfig):
         params["embed"] = L.embed_init(k_emb, (cfg.vocab, cfg.d_model))
     if cfg.shared_attn_every:
         params["shared"] = _init_block(k_shared, cfg, "attn_mlp")
+    if kd:
+        params["dense"] = jax.vmap(lambda k: _init_block(k, cfg, "attn_mlp"))(layer_keys[:kd])
     return params
+
+
+def stages(cfg: ArchConfig) -> list[tuple[str, int, int, bool]]:
+    """``(params key, first layer, end layer, MoE?)`` of each scanned stack
+    of an attention family, in order: the leading dense layers, then the
+    rest."""
+
+    kd = cfg.first_k_dense
+    out = [("dense", 0, kd, False)] if kd else []
+    return out + [("blocks", kd, cfg.n_layers, block_kind(cfg) == "attn_moe")]
+
+
+def n_moe_layers(cfg: ArchConfig) -> int:
+    return cfg.n_layers - cfg.first_k_dense if block_kind(cfg) == "attn_moe" else 0
 
 
 # ---------------------------------------------------------------------------
@@ -105,23 +132,28 @@ def init_lm(key, cfg: ArchConfig):
 # ---------------------------------------------------------------------------
 
 
-def _apply_attn_block(p, x, cfg: ArchConfig, positions, *, with_moe: bool):
-    acfg = attn_config(cfg)
-    h, kv = L.apply_attention(p["attn"], L.rms_norm(x, p["ln1"], cfg.norm_eps), acfg,
-                              positions=positions)
+def _apply_attn_block(p, x, cfg: ArchConfig, positions, *, with_moe: bool,
+                      dropless: bool = False):
+    n1 = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+    if cfg.mla is not None:
+        h, kv = L.apply_mla(p["attn"], n1, cfg.mla, positions=positions)
+    else:
+        h, kv = L.apply_attention(p["attn"], n1, attn_config(cfg), positions=positions)
     # Pin each sub-block output to the (sequence-sharded) residual layout
     # in bf16 *before* the residual add: GSPMD then reduce-scatters the
     # bf16 row-parallel partials instead of all-reducing an fp32
     # intermediate (EXPERIMENTS.md §Perf A).
     x = x + constrain_batch(h)
-    if with_moe:
+    if with_moe and dropless:
+        h, aux = M.apply_moe_dropless(p["moe"], L.rms_norm(x, p["ln2"], cfg.norm_eps), cfg.moe)[0], 0.0
+    elif with_moe:
         h, aux = M.apply_moe(p["moe"], L.rms_norm(x, p["ln2"], cfg.norm_eps), cfg.moe)
     else:
         h, aux = L.apply_glu(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps)), 0.0
     return x + constrain_batch(h), aux, kv
 
 
-def _layer_fn(cfg: ArchConfig, kind: str, positions):
+def _layer_fn(cfg: ArchConfig, kind: str, positions, dropless: bool = False):
     def f(x, p):
         # Pin batch-sharding (and, when enabled, sequence-sharding) of the
         # residual carry at every layer boundary.  Mamba blocks keep the
@@ -130,7 +162,8 @@ def _layer_fn(cfg: ArchConfig, kind: str, positions):
         if kind == "attn_mlp":
             x, aux, _ = _apply_attn_block(p, x, cfg, positions, with_moe=False)
         elif kind == "attn_moe":
-            x, aux, _ = _apply_attn_block(p, x, cfg, positions, with_moe=True)
+            x, aux, _ = _apply_attn_block(p, x, cfg, positions, with_moe=True,
+                                          dropless=dropless)
         elif kind == "mamba":
             h, _ = S.apply_mamba2(p["mamba"], L.rms_norm(x, p["ln"], cfg.norm_eps), cfg.ssm)
             x, aux = x + h, 0.0
@@ -159,8 +192,10 @@ def _cast_params(tree):
     )
 
 
-def forward_lm(params, cfg: ArchConfig, batch, *, remat: bool = True):
-    """Returns (logits_bf16, aux_loss)."""
+def forward_lm(params, cfg: ArchConfig, batch, *, remat: bool = True,
+               dropless: bool = False):
+    """Returns (logits_bf16, aux_loss).  ``dropless``: MoE layers route
+    dropless (inference), else by capacity (training)."""
 
     x = embed_tokens(params, cfg, batch)
     b, s, _ = x.shape
@@ -168,11 +203,15 @@ def forward_lm(params, cfg: ArchConfig, batch, *, remat: bool = True):
     kind = block_kind(cfg)
     params = dict(params, blocks=_cast_params(params["blocks"]),
                   lm_head=_cast_params(params["lm_head"]))
-    if "shared" in params:
-        params = dict(params, shared=_cast_params(params["shared"]))
-    body = _layer_fn(cfg, kind, positions)
+    for extra in ("shared", "dense"):
+        if extra in params:
+            params = dict(params, **{extra: _cast_params(params[extra])})
+    body = _layer_fn(cfg, kind, positions, dropless)
     if remat:
         body = jax.checkpoint(body)
+    if "dense" in params:
+        lead = _layer_fn(cfg, "attn_mlp", positions)
+        x, _ = jax.lax.scan(jax.checkpoint(lead) if remat else lead, x, params["dense"])
 
     if cfg.shared_attn_every:
         # Zamba2: groups of `every` mamba layers + a weight-shared attn block.
@@ -246,6 +285,12 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int):
         st = S.init_mamba2_state(batch, cfg.ssm)
         state = {"mamba": jax.tree.map(
             lambda a: jnp.zeros((ll,) + a.shape, a.dtype), st)}
+    elif cfg.mla is not None:
+        sc = cache_len(cfg, seq_len)
+        state = {
+            "c": jnp.zeros((ll, batch, sc, cfg.mla.kv_lora_rank), L.COMPUTE_DTYPE),
+            "pe": jnp.zeros((ll, batch, sc, cfg.mla.qk_rope_head_dim), L.COMPUTE_DTYPE),
+        }
     else:
         sc = cache_len(cfg, seq_len)
         kv_shape = (ll, batch, sc, cfg.n_kv_heads, cfg.head_dim)
@@ -253,6 +298,7 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int):
             "k": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
             "v": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
         }
+    state.update(_routed_state(cfg, batch))
     if cfg.shared_attn_every:
         n_apps = cfg.n_layers // cfg.shared_attn_every
         # The shared attention block sees the full sequence; cap its cache
@@ -264,7 +310,21 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int):
     return state
 
 
-def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int):
+def _routed_state(cfg: ArchConfig, batch: Optional[int]) -> dict:
+    """MoE decode state's ``moe_routed`` (n_moe_layers, B, n_experts)
+    int32: which held experts each row's token chose at the last step
+    (the step's output; its input value is never read)."""
+
+    n = n_moe_layers(cfg)
+    if not n:
+        return {}
+    if batch is None:
+        raise ValueError(f"{cfg.name}: a MoE decode state needs its row count")
+    return {"moe_routed": jnp.zeros((n, batch, cfg.moe.n_experts), jnp.int32)}
+
+
+def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int,
+                            rows: Optional[int] = None):
     """Paged decode cache: one shared page arena per layer, no batch dim.
 
     Replaces the dense ``(L, B, S_cache, Hkv, Dh)`` lanes with stacked
@@ -273,9 +333,13 @@ def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int):
     Head-major, so one ``(page, head)`` pair is a contiguous
     ``(page_size, Dh)`` tile — the paged kernel's block, read in place —
     and pages stay at dim 1, where the pod partition shards them.
-    Only the pure KV-cache families page — recurrent (Mamba2) state is
-    constant-size per slot already, and the hybrid/encdec caches carry
-    extra leaves the page pool does not cover.
+    Latent attention (MLA) pages its latent rows instead:
+    ``(L, n_pages, page_size, kv_lora_rank)`` and the roped shared key
+    ``(L, n_pages, qk_rope_head_dim, page_size)`` (see
+    ``layers.decode_mla_paged``).  A MoE family adds ``moe_routed`` with
+    ``rows`` rows.  Only the pure KV-cache families page — recurrent
+    (Mamba2) state is constant-size per slot already, and the
+    hybrid/encdec caches carry extra leaves the page pool does not cover.
     """
 
     kind = block_kind(cfg)
@@ -284,15 +348,25 @@ def init_decode_state_paged(cfg: ArchConfig, n_pages: int, page_size: int):
             f"paged KV state requires a pure KV-cache family, not "
             f"{cfg.family!r} (recurrent state has no pages to allocate)"
         )
-    kv_shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
-    return {
-        "pages_k": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
-        "pages_v": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
-    }
+    if cfg.mla is not None:
+        m = cfg.mla
+        state = {
+            "pages_c": jnp.zeros((cfg.n_layers, n_pages, page_size, m.kv_lora_rank),
+                                 L.COMPUTE_DTYPE),
+            "pages_pe": jnp.zeros((cfg.n_layers, n_pages, m.qk_rope_head_dim, page_size),
+                                  L.COMPUTE_DTYPE),
+        }
+    else:
+        kv_shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+        state = {
+            "pages_k": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
+            "pages_v": jnp.zeros(kv_shape, L.COMPUTE_DTYPE),
+        }
+    state.update(_routed_state(cfg, rows))
+    return state
 
 
-def _decode_attn_block(p, x, cfg, ck, cv, pos, *, with_moe: bool, window=None,
-                       live=None):
+def _decode_attn_block(p, x, cfg, ck, cv, pos, *, window=None, live=None):
     acfg = attn_config(cfg)
     if window is not None:
         acfg = L.AttnConfig(**{**acfg.__dict__, "window": window})
@@ -301,26 +375,8 @@ def _decode_attn_block(p, x, cfg, ck, cv, pos, *, with_moe: bool, window=None,
         live=live,
     )
     x = x + h
-    if with_moe:
-        h, _ = M.apply_moe(p["moe"], L.rms_norm(x, p["ln2"], cfg.norm_eps), cfg.moe)
-    else:
-        h = L.apply_glu(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps))
+    h = L.apply_glu(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps))
     return x + h, ck, cv
-
-
-def _decode_attn_block_paged(p, x, cfg, pk, pv, layer, table, pos, *,
-                             with_moe: bool, live=None):
-    acfg = attn_config(cfg)
-    h, (pk, pv) = L.decode_attention_paged(
-        p["attn"], L.rms_norm(x, p["ln1"], cfg.norm_eps), acfg, pk, pv,
-        layer, table, pos, live=live,
-    )
-    x = x + h
-    if with_moe:
-        h, _ = M.apply_moe(p["moe"], L.rms_norm(x, p["ln2"], cfg.norm_eps), cfg.moe)
-    else:
-        h = L.apply_glu(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps))
-    return x + h, pk, pv
 
 
 def decode_step(params, cfg: ArchConfig, batch, state, pos):
@@ -364,7 +420,6 @@ def decode_step(params, cfg: ArchConfig, batch, state, pos):
                 sc = state["shared_k"].shape[2]
                 x2, ck, cv = _decode_attn_block(
                     shared, x, cfg, state["shared_k"][g], state["shared_v"][g], pos,
-                    with_moe=False,
                     window=sc if sc < 524288 else None,
                 )
                 x = x2
@@ -379,52 +434,88 @@ def decode_step(params, cfg: ArchConfig, batch, state, pos):
             x, st = jax.lax.scan(body, x, (params["blocks"], state["mamba"]))
             new_state = {"mamba": st}
     else:
-        with_moe = kind == "attn_moe"
-        live = batch.get("live")
-
-        if "pages_k" in state:
-            table = batch["page_table"]
-
-            # The stacked arenas ride in the carry, never in xs/ys: each
-            # layer scatters its rows into them in place and the kernel
-            # reads its pages where they lie, so no layer's pool is
-            # sliced out, written back or copied.
-            def body(carry, inputs):
-                x, pk, pv = carry
-                p, layer = inputs
-                x, pk, pv = _decode_attn_block_paged(
-                    p, x, cfg, pk, pv, layer, table, pos,
-                    with_moe=with_moe, live=live,
-                )
-                return (x, pk, pv), None
-
-            (x, pks, pvs), _ = jax.lax.scan(
-                body, (x, state["pages_k"], state["pages_v"]),
-                (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-            )
-            new_state = {"pages_k": pks, "pages_v": pvs}
-        else:
-            def body(x, inputs):
-                p, ck, cv = inputs
-                x, ck, cv = _decode_attn_block(
-                    p, x, cfg, ck, cv, pos, with_moe=with_moe, live=live
-                )
-                return x, (ck, cv)
-
-            x, (ks, vs) = jax.lax.scan(
-                body, x, (params["blocks"], state["k"], state["v"])
-            )
-            new_state = {"k": ks, "v": vs}
+        new_state = _decode_attention_stacks(params, cfg, batch, state, pos, x)
+        x = new_state.pop("x")
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = ops.gemm(x, params["lm_head"].astype(L.COMPUTE_DTYPE))
     return logits, new_state
 
 
-def prefill(params, cfg: ArchConfig, batch):
-    """Full-sequence inference forward; returns logits (no grad, remat off)."""
+def _decode_attention_stacks(params, cfg: ArchConfig, batch, state, pos, x):
+    """The attention families' decode: each stack of :func:`stages` scanned
+    in turn over the caches.  Paged arenas ride in the carry, never in
+    xs/ys: each layer scatters its rows into them in place and the kernel
+    reads its pages where they lie, so no layer's pool is sliced out,
+    written back or copied.  Dense lanes ride in xs/ys.  Returns the new
+    state with the hidden rows under ``"x"``."""
 
-    logits, _ = forward_lm(params, cfg, batch, remat=False)
+    live = batch.get("live")
+    table = batch.get("page_table")
+    paged = "pages_k" in state or "pages_c" in state
+    if cfg.mla is not None:
+        keys = ("pages_c", "pages_pe") if paged else ("c", "pe")
+    else:
+        keys = ("pages_k", "pages_v") if paged else ("k", "v")
+    acfg = attn_config(cfg)
+
+    def block(p, x, caches, layer, with_moe):
+        n1 = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+        if cfg.mla is not None and paged:
+            h, caches = L.decode_mla_paged(p["attn"], n1, cfg.mla, *caches, layer, table, pos,
+                                           live=live)
+        elif cfg.mla is not None:
+            h, caches = L.decode_mla(p["attn"], n1, cfg.mla, *caches, pos, live=live)
+        elif paged:
+            h, caches = L.decode_attention_paged(p["attn"], n1, acfg, *caches, layer, table,
+                                                 pos, live=live)
+        else:
+            h, caches = L.decode_attention(p["attn"], n1, acfg, *caches, pos, live=live)
+        x = x + h
+        n2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        if with_moe:
+            h, routed = M.apply_moe_dropless(p["moe"], n2, cfg.moe)
+        else:
+            h, routed = L.apply_glu(p["mlp"], n2), None
+        return x + h, caches, routed
+
+    caches = tuple(state[k] for k in keys)
+    new_state = {}
+    lanes = []
+    for name, lo, hi, with_moe in stages(cfg):
+        layers = jnp.arange(lo, hi, dtype=jnp.int32)
+        if paged:
+            def body(carry, inputs, with_moe=with_moe):
+                x, caches = carry
+                p, layer = inputs
+                x, caches, routed = block(p, x, caches, layer, with_moe)
+                return (x, caches), routed
+
+            (x, caches), routed = jax.lax.scan(body, (x, caches), (params[name], layers))
+        else:
+            def body(x, inputs, with_moe=with_moe):
+                p, *cs = inputs
+                x, cs, routed = block(p, x, tuple(cs), None, with_moe)
+                return x, (cs, routed)
+
+            x, (cs, routed) = jax.lax.scan(
+                body, x, (params[name], *(c[lo:hi] for c in caches)))
+            lanes.append(cs)
+        if with_moe:
+            new_state["moe_routed"] = routed
+    if not paged:
+        caches = tuple(jnp.concatenate(parts, 0) if len(parts) > 1 else parts[0]
+                       for parts in zip(*lanes))
+    new_state.update(zip(keys, caches))
+    new_state["x"] = x
+    return new_state
+
+
+def prefill(params, cfg: ArchConfig, batch):
+    """Full-sequence inference forward; returns logits (no grad, remat off,
+    MoE dropless)."""
+
+    logits, _ = forward_lm(params, cfg, batch, remat=False, dropless=True)
     return logits
 
 
@@ -440,4 +531,6 @@ __all__ = [
     "init_decode_state",
     "init_decode_state_paged",
     "cache_len",
+    "stages",
+    "n_moe_layers",
 ]

@@ -34,9 +34,8 @@ Layout invariants the decode path relies on:
     clip to an arbitrary page whose values are always masked off.
   * One shared **phantom page set per pod**: every free-but-refreshed
     lane points at the same pages, so phantom rows (which all carry the
-    identical zero-prompt content — required for MoE cross-row
-    bit-identity with the dense engine) cost one lane of pages per pod
-    instead of one per slot.
+    identical zero-prompt content, and decode row-locally) cost one lane
+    of pages per pod instead of one per slot.
 """
 
 from __future__ import annotations
@@ -172,14 +171,12 @@ class PagePool:
         sharing is exact, and the fixed overhead is one lane per pod
         instead of one per free slot.
 
-        ``per_slot=True`` (MoE archs): one lane per *slot* — ``(n_slots,
-        W)``, each row drawn from its slot's pod partition.  MoE capacity
-        routing ranks tokens by a cumsum over the merged decode group, so
-        *identical* pad rows can be dropped differentially when capacity
-        binds; their streams then diverge and a shared page would take
-        conflicting writes.  A private phantom lane per slot reproduces
-        the dense engine's pad lanes exactly (each owns its content), at
-        the dense cost for free lanes only.
+        ``per_slot=True``: one lane per *slot* — ``(n_slots, W)``, each
+        row drawn from its slot's pod partition, for decode steps whose
+        rows are coupled (a router that ranks tokens across rows could
+        treat identical pad rows differently, and a shared page would then
+        take conflicting writes).  The engine's rows are row-local (MoE
+        layers route dropless), so it reserves the shared lanes.
 
         Reserved once, never freed.
         """

@@ -64,11 +64,10 @@ state.  This engine amortizes all of it:
 Exactness contract (tested in tests/test_paged_serving.py): the paged
 engine's tokens are **bit-identical** to the dense slot-table engine's
 for the same requests.  Free-but-refreshed lanes decode the same pad
-streams as the dense engine's phantom rows through *phantom pages* — one
-shared lane per pod for row-local archs (all pad writers are identical),
-one private lane per slot for MoE archs (capacity routing can
-differentially drop identical pad rows, so they must own their content
-exactly as dense lanes do).  Retired-but-not-refreshed lanes are marked
+streams as the dense engine's phantom rows through *phantom pages*: one
+shared lane per pod, since every row of the decode step is row-local
+(MoE layers route dropless, so identical pad rows get identical
+results).  Retired-but-not-refreshed lanes are marked
 dead via the ``live`` mask in *both* engines (their attention output is
 zeroed, making their rows cache-independent), which is what lets the
 paged engine free a retired slot's pages immediately without its dead
@@ -143,6 +142,18 @@ def _metrics():
                 "Pods currently parked (power-gated) by the energy objective"),
         }
     return _M
+
+
+def _moe_counters():
+    """The MoE routing counters.  Counted whether or not tracing is on: the
+    counts come back with every MoE step's tokens, and the benchmark's
+    step-FLOPs reader takes the routed share from them."""
+
+    return (MET.counter("engine_moe_routed_tokens_total",
+                        "Decode tokens of busy slots routed to each held expert, "
+                        "summed over the MoE layers", labels=("expert",)),
+            MET.counter("engine_moe_step_tokens_total",
+                        "Decode tokens of busy slots in MoE decode steps"))
 
 
 # Modeled wall seconds for one slot-row of decode work on a pod of unit
@@ -409,22 +420,15 @@ class ServingEngine:
                 page_size = min(t.block.bm for t in asym.control_trees().values())
             ps = divisor_page_size(self.s_cache, page_size)
             w = self.s_cache // ps
-            # MoE capacity routing couples batch rows: pad lanes must own
-            # their phantom content like dense lanes do (see paging.py).
-            per_slot_phantom = TX.block_kind(cfg) == "attn_moe"
-            phantom_per_pod = self.c_max if per_slot_phantom else 1
             if pool_pages is None:
-                pool_pages = (self.c_max + phantom_per_pod) * w
+                pool_pages = (self.c_max + 1) * w   # every lane, plus the phantom
             spec = PageSpec(
                 page_size=ps, pages_per_slot=w,
                 pages_per_pod=int(pool_pages), n_pods=self.n_pods,
             )
             self.pool: Optional[PagePool] = PagePool(spec, self.c_max)
-            self.phantom = self.pool.alloc_phantom(per_slot=per_slot_phantom)
-            self._phantom_rows_idx = (
-                np.arange(self.n_slots) if per_slot_phantom else self._pod_of_row
-            )
-            self.state = Z.init_decode_state_paged(cfg, spec.n_pages, ps)
+            self.phantom = self.pool.alloc_phantom()
+            self.state = Z.init_decode_state_paged(cfg, spec.n_pages, ps, rows=self.n_slots)
         else:
             self.pool = None
             self.phantom = None
@@ -444,7 +448,8 @@ class ServingEngine:
         if self.paged:
             spec = self.pool.spec
             state_spec = jax.eval_shape(
-                lambda: Z.init_decode_state_paged(cfg, spec.n_pages, spec.page_size)
+                lambda: Z.init_decode_state_paged(cfg, spec.n_pages, spec.page_size,
+                                                  rows=self.n_slots)
             )
             batch_keys = ("tokens", "page_table", "live")
         else:
@@ -539,7 +544,7 @@ class ServingEngine:
         (writes dropped, reads masked by the zeroed ``live`` output)."""
 
         busy = self.slot_rid >= 0
-        table = self.phantom[self._phantom_rows_idx].copy()
+        table = self.phantom[self._pod_of_row].copy()
         table[busy] = self.pool.table[busy]
         table[~busy & ~self._live] = SENTINEL
         return self._localize(table)
@@ -820,7 +825,7 @@ class ServingEngine:
                 pbatch = {"tokens": jnp.asarray(prompts),
                           "live": jnp.ones((self.n_slots,), bool)}
                 if self.pool is not None:
-                    table = self.phantom[self._phantom_rows_idx].copy()
+                    table = self.phantom[self._pod_of_row].copy()
                     for slot, _ in batch:
                         table[slot] = self.pool.table[slot]
                     pbatch["page_table"] = jnp.asarray(self._localize(table))
@@ -978,8 +983,14 @@ class ServingEngine:
             with T.span("engine.step.launch", cat="engine"):
                 nxt, self.state = self._step(self.params, batch, self.state, pos)
             with T.span("engine.step.wait", cat="engine"):
-                toks = np.asarray(nxt)  # blocks: the step's wall time is real
+                # blocks: the step's wall time is real
+                if "moe_routed" in self.state:
+                    toks, routed = jax.device_get((nxt, self.state["moe_routed"]))
+                else:
+                    toks, routed = np.asarray(nxt), None
             with T.span("engine.step.retire", cat="engine"):
+                if routed is not None:
+                    self._note_routed(sp, routed, active)
                 self.tokens = nxt
                 dt = time.perf_counter() - t0
                 n_compiled, compile_s = compiles.since(mark)
@@ -1025,6 +1036,20 @@ class ServingEngine:
                     if times is not None:
                         self.asym.observe_step(units, list(times))
         return n_active
+
+    def _note_routed(self, sp, routed: np.ndarray, active: np.ndarray):
+        """The step's held-expert routing over busy slots: per MoE layer and
+        expert (``routed`` is (n_moe_layers, n_slots, n_experts)).  The
+        counts feed the ``engine.step`` span's ``moe_routed_local`` and
+        ``moe_max_expert_load`` and the :func:`_moe_counters`."""
+
+        load = routed[:, active].sum(axis=1)        # (n_moe_layers, n_experts)
+        sp.tag(moe_routed_local=int(load.sum()), moe_max_expert_load=int(load.max()))
+        per_expert, tokens = _moe_counters()
+        first = self.cfg.moe.first_expert
+        for e, n in enumerate(load.sum(axis=0)):
+            per_expert.labels(expert=str(first + e)).inc(int(n))
+        tokens.inc(int(active.sum()))
 
     def step_inputs(self) -> tuple[dict, jnp.ndarray]:
         """``(batch, pos)`` of the next decode step: the resident tokens,
@@ -1094,13 +1119,15 @@ class ServingEngine:
         dense engine allocates for the same slot table).
         """
 
-        arena = int(sum(x.nbytes for x in jax.tree.leaves(self.state)))
+        caches = {k: v for k, v in self.state.items() if k != "moe_routed"}
+        arena = int(sum(x.nbytes for x in jax.tree.leaves(caches)))
         if self.pool is None:
             return {"paged": False, "kv_bytes": arena}
         spec = self.pool.spec
-        itemsize = self.state["pages_k"].dtype.itemsize
-        per_tok = 2 * self.cfg.n_layers * self.cfg.n_kv_heads * self.cfg.head_dim
-        page_bytes = per_tok * spec.page_size * itemsize
+        # Bytes of one token's cache in every layer: K and V rows, or the
+        # latent row (MLA).
+        tok_bytes = arena // (spec.n_pages * spec.page_size)
+        page_bytes = tok_bytes * spec.page_size
         return {
             "paged": True,
             "page_size": spec.page_size,
@@ -1113,7 +1140,7 @@ class ServingEngine:
             "page_bytes": page_bytes,
             "peak_kv_bytes": self.pool.peak_live * page_bytes,
             "arena_kv_bytes": arena,
-            "dense_kv_bytes": per_tok * self.n_slots * self.s_cache * itemsize,
+            "dense_kv_bytes": tok_bytes * self.n_slots * self.s_cache,
         }
 
     # -- metrics (every method below only runs while tracing is enabled) ------

@@ -9,7 +9,8 @@ internlm2-1.8b's published widths (d_model 2048, d_ff 8192, vocab 92544,
 (2 pods x 4 slots, 1024-token lanes), and for the paged KV arena's memory
 guards deepseek-7b's (32/32 heads of 128, d_ff 11008, vocab 102400; 3
 layers) at the benchmark's slot table (2 pods x 4 slots, 4096-token
-lanes).
+lanes), and for the latent kernel and arenas DeepSeek-V2-Lite's (16 heads,
+``kv_lora_rank`` 512, rope 64; 8 of 64 experts) at 2 pods x 64 slots.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -20,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +77,7 @@ def _no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, *args, **jit_kw):
+def _compile(fn, *args, hlo: bool = False, **jit_kw):
     compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
     ma = compiled.memory_analysis()
     print(ma)
@@ -83,7 +85,7 @@ def _compile(fn, *args, **jit_kw):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert used <= V5E_HBM_BYTES
-    return ma
+    return (ma, compiled.as_text()) if hlo else ma
 
 
 def _spec(shape, dtype, sharding):
@@ -209,3 +211,94 @@ def test_paged_arena_is_updated_in_place(one_chip, program, monkeypatch):
     layer_pool_bytes = arena_bytes // (2 * cfg.n_layers)
     assert ma.alias_size_in_bytes >= arena_bytes
     assert ma.temp_size_in_bytes < layer_pool_bytes
+
+
+V2_SLOTS = 128     # chipbench's deepseek-v2-lite-1chip: 2 pods x 64 slots of 4096
+
+
+def _v2_config(n_layers):
+    """DeepSeek-V2-Lite at published widths, ``n_layers`` deep (layer 0
+    dense), this chip holding 8 of its 64 routed experts."""
+
+    cfg = get_config("deepseek-v2-lite")
+    moe = dataclasses.replace(cfg.moe, n_experts=8, n_router=64)
+    return dataclasses.replace(cfg, n_layers=n_layers, moe=moe)
+
+
+def test_paged_latent_kernel_compiles(one_chip, monkeypatch):
+    """The latent kernel over the engine's arena reads it where it lies:
+    no temporary (a latent row of 576 in one arena would be laid out
+    transposed by the compiler and copied for the kernel every call)."""
+
+    from repro.kernels.paged_attention import paged_mla_pallas
+
+    cfg = _v2_config(11)
+    m = cfg.mla
+    page_size = _engine_page_size(monkeypatch, LONG_LANE)
+    w = LONG_LANE // page_size
+    n_pages = 2 * (V2_SLOTS // 2 + 1) * w
+    args = (_spec((V2_SLOTS, m.n_heads, m.kv_lora_rank), jnp.bfloat16, one_chip),
+            _spec((V2_SLOTS, m.n_heads, m.qk_rope_head_dim), jnp.bfloat16, one_chip),
+            _spec((cfg.n_layers, n_pages, page_size, m.kv_lora_rank), jnp.bfloat16, one_chip),
+            _spec((cfg.n_layers, n_pages, m.qk_rope_head_dim, page_size), jnp.bfloat16, one_chip),
+            _spec((V2_SLOTS, w), jnp.int32, one_chip), _spec((V2_SLOTS,), jnp.int32, one_chip),
+            _spec((), jnp.int32, one_chip))
+    ma = _compile(lambda *a: paged_mla_pallas(*a, scale=m.scale), *args)
+    assert ma.temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_paged_latent_arena_is_updated_in_place(one_chip, program, monkeypatch):
+    """The engine's paged decode step and bulk prefill at DeepSeek-V2-Lite's
+    widths over 3 layers (bfloat16 weights): both latent arenas alias input
+    to output, no temporary is as large as one layer's ``c_kv`` pool, and
+    no operation copies or reshapes an arena — the roped-key arena
+    (positions minor) is written in place, never flattened for the scatter
+    and relaid out (that costs a temporary of the whole arena, and its
+    copy, in every layer)."""
+
+    cfg = _v2_config(3)
+    page_size = _engine_page_size(monkeypatch, LONG_LANE)
+    w = LONG_LANE // page_size
+    n_pages = 2 * (V2_SLOTS // 2 + 1) * w
+    ctx = AsymmetricMesh(biglittle_classes(chips_per_pod=1)).execution_context()
+    decode = Z.make_decode_fn(cfg)
+
+    def core(params, batch, state, pos):
+        with ctx:
+            return decode(params, batch, state, pos)
+
+    if program == "step":
+        def fn(params, batch, state, pos):
+            logits, state = core(params, batch, state, pos)
+            return jnp.argmax(logits[:, -1], axis=-1), state
+        tokens = (V2_SLOTS, 1)
+    else:
+        bulk = Z.bulk_prefill_from_decode(core)
+
+        def fn(params, batch, state, plens):
+            pos0 = jnp.zeros((V2_SLOTS,), jnp.int32)
+            logits, state = bulk(params, batch, state, pos0, plens=plens)
+            return jnp.argmax(logits[:, -1], axis=-1), state
+        tokens = (V2_SLOTS, 16)
+
+    place = lambda t, dt=None: jax.tree.map(  # noqa: E731
+        lambda s: _spec(s.shape, dt or s.dtype, one_chip), t)
+    params = place(jax.eval_shape(lambda: Z.init_params(jax.random.PRNGKey(0), cfg)),
+                   jnp.bfloat16)
+    state = place(jax.eval_shape(
+        lambda: Z.init_decode_state_paged(cfg, n_pages, page_size, rows=V2_SLOTS)))
+    batch = {"tokens": _spec(tokens, jnp.int32, one_chip),
+             "page_table": _spec((V2_SLOTS, w), jnp.int32, one_chip),
+             "live": _spec((V2_SLOTS,), jnp.bool_, one_chip)}
+    ma, text = _compile(fn, params, batch, state, _spec((V2_SLOTS,), jnp.int32, one_chip),
+                        hlo=True, donate_argnums=(2,))
+
+    arenas = state["pages_c"], state["pages_pe"]
+    for a in arenas:
+        shape = ",".join(map(str, a.shape))
+        assert not re.search(rf"= bf16\[{shape}\]\S* (copy|reshape)\(", text), shape
+    arena_bytes = sum(a.size * a.dtype.itemsize for a in arenas)
+    c_pool_bytes = arenas[0].size * arenas[0].dtype.itemsize // cfg.n_layers
+    assert ma.alias_size_in_bytes >= arena_bytes
+    assert ma.temp_size_in_bytes < c_pool_bytes
