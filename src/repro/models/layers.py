@@ -2,7 +2,8 @@
 
 Conventions:
   * params are stored fp32 (master weights); compute casts to bf16 at the
-    point of use (mixed-precision policy),
+    point of use (mixed-precision policy), which is the identity on the
+    :data:`GEMM_WEIGHTS` the serving engine casts once when it is built,
   * normalizations and softmax run in fp32,
   * every dense projection routes through :func:`repro.kernels.ops.gemm`
     so the paper's control-tree block configuration governs the hot loops,
@@ -24,6 +25,19 @@ from repro.kernels import ops
 
 COMPUTE_DTYPE = jnp.bfloat16
 PARAM_DTYPE = jnp.float32
+
+# Leaf names of the weights that every reader casts to COMPUTE_DTYPE before
+# its GEMM: a tree that holds them in COMPUTE_DTYPE computes the same
+# numbers without the per-use converts.  Every other leaf (norm gains, the
+# router, biases, embeddings, Mamba2's conv, dt and decay terms) is read in
+# float32 and keeps its dtype.  A name missing here costs speed; a name
+# added wrongly changes the result.
+GEMM_WEIGHTS = frozenset({
+    "wq", "wk", "wv", "wo", "wkv_a", "wkv_b",     # attention
+    "w1", "w2", "w3", "shared_gate",              # MLP and experts
+    "wz", "wx", "wbc", "wdt", "out_proj",         # Mamba2
+    "lm_head",
+})
 
 
 # ---------------------------------------------------------------------------
@@ -689,6 +703,7 @@ def sinusoidal_positions(s: int, d: int):
 __all__ = [
     "COMPUTE_DTYPE",
     "PARAM_DTYPE",
+    "GEMM_WEIGHTS",
     "AttnConfig",
     "dense_init",
     "embed_init",

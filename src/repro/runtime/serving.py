@@ -40,6 +40,9 @@ state.  This engine amortizes all of it:
     a request never moves: steady-state decode performs **zero host
     relayout** (no ``pad_requests``, no chunk-table re-derivation in the
     loop — asserted by tests).
+  * **Weights cast once** — the engine serves :func:`serving_params` of
+    the caller's tree: the GEMM weights, which every reader casts to
+    bfloat16, are cast when the engine is built, never in a step.
   * **Donated decode state** — the slot state (dense lanes or page
     arena) is threaded through the jitted step with ``donate_argnums``,
     so the caches update in place instead of being copied every token.
@@ -91,6 +94,7 @@ from repro.configs import ArchConfig
 from repro.core.asymmetric import AsymmetricMesh
 from repro.core.schedule import deficit_route
 from repro.distributed import sharding as SH
+from repro.models import layers as L
 from repro.models import model_zoo as Z
 from repro.models import transformer as TX
 from repro.observability import compiles
@@ -181,6 +185,37 @@ def _hook_takes_units(hook) -> bool:
     return n >= 2
 
 
+def serving_params(params):
+    """The weight tree the engine serves from: each
+    :data:`~repro.models.layers.GEMM_WEIGHTS` leaf in the compute dtype,
+    cast in one jitted call that keeps its sharding; every other leaf, and
+    one already in the compute dtype, is the caller's own array.  Each
+    reader casts those leaves to the compute dtype before its GEMM, so the
+    programs compute the float32 tree's numbers with no weight convert in
+    any step.  ``params`` is read, never changed or donated."""
+
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [w for _, w in flat]
+    pick = [i for i, (path, w) in enumerate(flat)
+            if getattr(path[-1], "key", None) in L.GEMM_WEIGHTS
+            and w.dtype != L.COMPUTE_DTYPE]
+    if pick:
+        cast = jax.jit(lambda ws: [w.astype(L.COMPUTE_DTYPE) for w in ws])(
+            [leaves[i] for i in pick])
+        for i, w in zip(pick, cast):
+            leaves[i] = w
+    return jax.tree.unflatten(tree, leaves)
+
+
+def weight_bytes(params) -> dict[str, int]:
+    """Bytes of ``params`` per dtype name."""
+
+    out = collections.Counter()
+    for w in jax.tree.leaves(params):
+        out[w.dtype.name] += w.size * w.dtype.itemsize
+    return dict(sorted(out.items()))
+
+
 def _paged_supported(cfg: ArchConfig) -> tuple[bool, str]:
     """Can this arch's decode state page?  (pure KV-cache families only)"""
 
@@ -243,6 +278,8 @@ class EngineStats:
     modeled_decode_s: float = 0.0 # modeled decode seconds those joules cover
     pod_parks: int = 0            # pods parked by the energy objective
     pod_unparks: int = 0          # pods re-admitted as load ramped
+    # Bytes of the served weight tree per dtype (``engine_weight_bytes``).
+    weight_bytes: dict = dataclasses.field(default_factory=dict)
 
     @property
     def tokens_per_s(self) -> float:
@@ -280,6 +317,10 @@ class ServingEngine:
     Parameters
     ----------
     cfg, params : the model (token-in archs only — serving contract).
+        The engine serves :func:`serving_params` of ``params``: the
+        allow-listed GEMM weights (:data:`repro.models.layers.GEMM_WEIGHTS`)
+        cast once to the compute dtype, every other leaf as given.  The
+        caller's tree is read, never changed.
     asym : the asymmetric mesh (scheduling state; per-class control trees).
     seq_cap : per-slot cache length (prompt + generation must fit).
     slots_per_pod : ``c_max`` — each pod's fixed slot-region size.
@@ -338,7 +379,7 @@ class ServingEngine:
         if paged not in ("auto", "on", "off"):
             raise ValueError(f"paged={paged!r}")
         self.cfg = cfg
-        self.params = params
+        self.params = serving_params(params)
         self.asym = asym
         self.seq_cap = int(seq_cap)
         self.c_max = int(slots_per_pod)
@@ -387,7 +428,11 @@ class ServingEngine:
         self._slot_toks: dict[int, list[int]] = {}
         self.budgets = [0] * self.n_pods
         self.completions: list[Completion] = []
-        self.stats = EngineStats()
+        self.stats = EngineStats(weight_bytes=weight_bytes(self.params))
+        served = MET.gauge("engine_weight_bytes", "Bytes of the served weight tree",
+                           labels=("dtype",))
+        for dtype, n in self.stats.weight_bytes.items():
+            served.labels(dtype=dtype).set(n)
         self._rebalances0 = asym.scheduler.rebalances
         # -- load-adaptive parking + modeled power (energy objective) ------
         # Parked pods draw a zero slot budget and model gated watts; the
@@ -1221,4 +1266,5 @@ class ServingEngine:
         return out
 
 
-__all__ = ["ServingEngine", "Request", "Completion", "EngineStats"]
+__all__ = ["ServingEngine", "Request", "Completion", "EngineStats", "serving_params",
+           "weight_bytes"]

@@ -158,22 +158,19 @@ def test_decode_step_compiles(one_chip):
 LONG_LANE = 4096   # chipbench's deepseek-7b-1chip: 2 pods x 4 slots of 4096
 
 
-@pytest.mark.parametrize("program", ["step", "prefill"])
-def test_paged_arena_is_updated_in_place(one_chip, program, monkeypatch):
-    """The engine's paged decode step and its bulk prefill (a 16-token
-    prompt), arena donated, at deepseek-7b's widths over 3 layers: the
-    stacked K/V arenas alias input to output, and no program keeps a
-    temporary as large as one layer's K pool — the arena is carried
-    through the layer loop and updated in place, never sliced out per
-    layer, transposed for the kernel, written back or copied.
+def _place(tree, one_chip, dtype=None):
+    return jax.tree.map(lambda s: _spec(s.shape, dtype or s.dtype, one_chip), tree)
 
-    Weights are bfloat16 here, so the float32 weights' converts (the
-    LM head's alone is 839 MB) do not hide the arena's copies."""
 
-    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=3)
+def _paged_engine_program(cfg, rows, program, prompt_len, params, one_chip, monkeypatch):
+    """The engine's paged decode step (``program="step"``) or bulk prefill
+    of a ``prompt_len``-token prompt over ``rows`` slots of 4096-token
+    lanes in 2 pods, as ``ServingEngine`` builds them on a TPU: ``(fn,
+    args, state)``, the arena ``state`` donated as argument 2."""
+
     page_size = _engine_page_size(monkeypatch, LONG_LANE)
     w = LONG_LANE // page_size
-    n_pages = 2 * (SLOTS // 2 + 1) * w
+    n_pages = 2 * (rows // 2 + 1) * w     # per pod: its slot lanes + 1 phantom lane
     ctx = AsymmetricMesh(biglittle_classes(chips_per_pod=1)).execution_context()
     assert ctx.paged_attn_backend() == "paged_attn_pallas"
     decode = Z.make_decode_fn(cfg)
@@ -186,26 +183,45 @@ def test_paged_arena_is_updated_in_place(one_chip, program, monkeypatch):
         def fn(params, batch, state, pos):
             logits, state = core(params, batch, state, pos)
             return jnp.argmax(logits[:, -1], axis=-1), state
-        tokens, extra = (SLOTS, 1), _spec((SLOTS,), jnp.int32, one_chip)
+        tokens = (rows, 1)
     else:
         bulk = Z.bulk_prefill_from_decode(core)
 
         def fn(params, batch, state, plens):
-            pos0 = jnp.zeros((SLOTS,), jnp.int32)
+            pos0 = jnp.zeros((rows,), jnp.int32)
             logits, state = bulk(params, batch, state, pos0, plens=plens)
             return jnp.argmax(logits[:, -1], axis=-1), state
-        tokens, extra = (SLOTS, 16), _spec((SLOTS,), jnp.int32, one_chip)
+        tokens = (rows, prompt_len)
 
-    place = lambda t, dt=None: jax.tree.map(  # noqa: E731
-        lambda s: _spec(s.shape, dt or s.dtype, one_chip), t)
-    params = place(jax.eval_shape(lambda: Z.init_params(jax.random.PRNGKey(0), cfg)),
-                   jnp.bfloat16)
-    state = place(jax.eval_shape(
-        lambda: Z.init_decode_state_paged(cfg, n_pages, page_size)))
+    state = _place(jax.eval_shape(
+        lambda: Z.init_decode_state_paged(cfg, n_pages, page_size, rows=rows)), one_chip)
     batch = {"tokens": _spec(tokens, jnp.int32, one_chip),
-             "page_table": _spec((SLOTS, w), jnp.int32, one_chip),
-             "live": _spec((SLOTS,), jnp.bool_, one_chip)}
-    ma = _compile(fn, params, batch, state, extra, donate_argnums=(2,))
+             "page_table": _spec((rows, w), jnp.int32, one_chip),
+             "live": _spec((rows,), jnp.bool_, one_chip)}
+    return fn, (params, batch, state, _spec((rows,), jnp.int32, one_chip)), state
+
+
+def _bf16_params(cfg, one_chip):
+    return _place(jax.eval_shape(lambda: Z.init_params(jax.random.PRNGKey(0), cfg)),
+                  one_chip, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_paged_arena_is_updated_in_place(one_chip, program, monkeypatch):
+    """The engine's paged decode step and its bulk prefill (a 16-token
+    prompt), arena donated, at deepseek-7b's widths over 3 layers: the
+    stacked K/V arenas alias input to output, and no program keeps a
+    temporary as large as one layer's K pool — the arena is carried
+    through the layer loop and updated in place, never sliced out per
+    layer, transposed for the kernel, written back or copied.
+
+    Every weight is bfloat16 here, so no convert of a weight (the engine
+    converts none) can hide the arena's copies."""
+
+    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=3)
+    fn, args, state = _paged_engine_program(cfg, SLOTS, program, 16,
+                                            _bf16_params(cfg, one_chip), one_chip, monkeypatch)
+    ma = _compile(fn, *args, donate_argnums=(2,))
 
     arena_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(state))
     layer_pool_bytes = arena_bytes // (2 * cfg.n_layers)
@@ -258,41 +274,9 @@ def test_paged_latent_arena_is_updated_in_place(one_chip, program, monkeypatch):
     copy, in every layer)."""
 
     cfg = _v2_config(3)
-    page_size = _engine_page_size(monkeypatch, LONG_LANE)
-    w = LONG_LANE // page_size
-    n_pages = 2 * (V2_SLOTS // 2 + 1) * w
-    ctx = AsymmetricMesh(biglittle_classes(chips_per_pod=1)).execution_context()
-    decode = Z.make_decode_fn(cfg)
-
-    def core(params, batch, state, pos):
-        with ctx:
-            return decode(params, batch, state, pos)
-
-    if program == "step":
-        def fn(params, batch, state, pos):
-            logits, state = core(params, batch, state, pos)
-            return jnp.argmax(logits[:, -1], axis=-1), state
-        tokens = (V2_SLOTS, 1)
-    else:
-        bulk = Z.bulk_prefill_from_decode(core)
-
-        def fn(params, batch, state, plens):
-            pos0 = jnp.zeros((V2_SLOTS,), jnp.int32)
-            logits, state = bulk(params, batch, state, pos0, plens=plens)
-            return jnp.argmax(logits[:, -1], axis=-1), state
-        tokens = (V2_SLOTS, 16)
-
-    place = lambda t, dt=None: jax.tree.map(  # noqa: E731
-        lambda s: _spec(s.shape, dt or s.dtype, one_chip), t)
-    params = place(jax.eval_shape(lambda: Z.init_params(jax.random.PRNGKey(0), cfg)),
-                   jnp.bfloat16)
-    state = place(jax.eval_shape(
-        lambda: Z.init_decode_state_paged(cfg, n_pages, page_size, rows=V2_SLOTS)))
-    batch = {"tokens": _spec(tokens, jnp.int32, one_chip),
-             "page_table": _spec((V2_SLOTS, w), jnp.int32, one_chip),
-             "live": _spec((V2_SLOTS,), jnp.bool_, one_chip)}
-    ma, text = _compile(fn, params, batch, state, _spec((V2_SLOTS,), jnp.int32, one_chip),
-                        hlo=True, donate_argnums=(2,))
+    fn, args, state = _paged_engine_program(cfg, V2_SLOTS, program, 16,
+                                            _bf16_params(cfg, one_chip), one_chip, monkeypatch)
+    ma, text = _compile(fn, *args, hlo=True, donate_argnums=(2,))
 
     arenas = state["pages_c"], state["pages_pe"]
     for a in arenas:
@@ -302,3 +286,35 @@ def test_paged_latent_arena_is_updated_in_place(one_chip, program, monkeypatch):
     c_pool_bytes = arenas[0].size * arenas[0].dtype.itemsize // cfg.n_layers
     assert ma.alias_size_in_bytes >= arena_bytes
     assert ma.temp_size_in_bytes < c_pool_bytes
+
+
+# The two cells' engines at their full geometry, with the benchmark's
+# longest prompt: (config, slots over 2 pods).
+CELLS = {"deepseek-7b-1chip": (dataclasses.replace(get_config("deepseek-7b"), n_layers=3), SLOTS),
+         "deepseek-v2-lite-1chip": (_v2_config(11), V2_SLOTS)}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_engine_fits_beside_the_float32_weights(one_chip, cell, program, monkeypatch):
+    """Each cell's paged step and 1536-token bulk prefill, compiled over the
+    engine's own weight tree (``serving_params``: GEMM weights bfloat16,
+    the rest float32), fit the chip beside the caller's float32 copies of
+    the cast weights, which the benchmark keeps for its reference check."""
+
+    from repro.models.layers import GEMM_WEIGHTS
+    from repro.runtime.serving import serving_params
+
+    cfg, rows = CELLS[cell]
+    tree = jax.eval_shape(lambda: Z.init_params(jax.random.PRNGKey(0), cfg))
+    params = _place(jax.eval_shape(serving_params, tree), one_chip)
+    fn, args, _ = _paged_engine_program(cfg, rows, program, 1536, params, one_chip, monkeypatch)
+    ma = _compile(fn, *args, donate_argnums=(2,))
+
+    caller_f32 = sum(4 * w.size for path, w in jax.tree_util.tree_flatten_with_path(tree)[0]
+                     if path[-1].key in GEMM_WEIGHTS)
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"{cell} {program}: program {used / 2**30:.2f} GiB + float32 weights "
+          f"{caller_f32 / 2**30:.2f} GiB")
+    assert used + caller_f32 <= V5E_HBM_BYTES
