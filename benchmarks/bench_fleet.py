@@ -2,7 +2,7 @@
 
 Exercises the fault-tolerant fleet layer (ISSUE 10 / ROADMAP
 "Multi-engine fleet") on the **modeled clock** — the deterministic
-power-model time base every engine accumulates per decode step
+time base every engine accumulates per decode step
 (``stats.modeled_decode_s``), host-independent by construction:
 
   * **no-fault lane** — the N-engine fleet against a single engine on

@@ -192,87 +192,9 @@ def paged_ab(cfg, params, gen_len, seq_cap, reps, *, slots_per_pod=8,
     }
 
 
-def objective_ab(cfg, params, gen_len, seq_cap, reps, *,
-                 objectives=("energy",), wave=3, prompt_len=8,
-                 slots_per_pod=4):
-    """``perf`` vs objective-engine A/B at low offered load.
-
-    Every side serves identical low-depth request waves (``wave`` requests
-    against ``2 × slots_per_pod`` slots — the regime where the non-perf
-    objectives park the big pod and serve from little).  Compared on the
-    *modeled* power-clock columns (``energy_j`` / ``tokens_per_j`` /
-    ``modeled_tokens_per_s``), which are deterministic across hosts; the
-    wall-clock SPMD program is the same on every side, so tokens are
-    asserted bit-identical and the existing speedup gate is untouched.
-    The single ``perf`` reference run is shared across all requested
-    ``objectives``; one block per objective is returned, each carrying the
-    shared perf columns so every block is self-contained (the RPR202
-    artifact shape).  The check gate asserts the requested objective
-    actually buys joules (``energy_ratio`` strictly < 1) at a bounded
-    modeled-throughput loss.
-    """
-
-    from repro.runtime.serving import ServingEngine
-
-    def side(obj):
-        asym = AsymmetricMesh(
-            biglittle_classes(chips_per_pod=1), strategy="ca-das",
-            batch_tile=1, objective=obj,
-        )
-        eng = ServingEngine(
-            cfg, params, asym, seq_cap=seq_cap, slots_per_pod=slots_per_pod,
-            class_sharded="off",
-        )
-        rng = np.random.default_rng(2)
-        outs = []
-        for _ in range(reps):
-            prompts = rng.integers(0, cfg.vocab, (wave, prompt_len),
-                                   dtype=np.int32)
-            outs.append(eng.generate(prompts, gen_len))
-        return eng, outs
-
-    def cols(st):
-        return {
-            "energy_j": round(st.energy_j, 4),
-            "tokens_per_j": round(st.tokens_per_j, 3),
-            "modeled_tokens_per_s": round(st.modeled_tokens_per_s, 1),
-            "pod_parks": st.pod_parks,
-            "pod_unparks": st.pod_unparks,
-        }
-
-    perf_eng, perf_outs = side("perf")
-    ps = perf_eng.stats
-    blocks = {}
-    for objective in objectives:
-        obj_eng, obj_outs = side(objective)
-        for a, b in zip(perf_outs, obj_outs):
-            assert np.array_equal(a, b), (
-                f"{objective}-objective tokens diverged from perf"
-            )
-        os_ = obj_eng.stats
-        energy_ratio = os_.energy_j / ps.energy_j if ps.energy_j else 0.0
-        throughput_ratio = (
-            os_.modeled_tokens_per_s / ps.modeled_tokens_per_s
-            if ps.modeled_tokens_per_s else 0.0
-        )
-        blocks[objective] = {
-            "objective": objective,
-            "wave": wave,
-            "reps": reps,
-            "gen_len": gen_len,
-            "perf": cols(ps),
-            objective: cols(os_),
-            "tokens_identical": True,
-            "energy_ratio": round(energy_ratio, 3),
-            "throughput_ratio": round(throughput_ratio, 3),
-        }
-    return blocks
-
-
 def run(arch: str = "internlm2-1.8b", batch: int = 8, prompt_len: int = 8,
         gen_len: int = 48, seq_cap: int = 512, reps: int = 3,
-        mixed: bool = False, obs: bool = False, paged: bool = False,
-        objective: str | None = None) -> list[Row]:
+        mixed: bool = False, obs: bool = False, paged: bool = False) -> list[Row]:
     """Both sides on identical prompts/layout; writes ``BENCH_serving.json``.
 
     ``seq_cap`` is deliberately larger than prompt+gen: the decode-state
@@ -361,31 +283,7 @@ def run(arch: str = "internlm2-1.8b", batch: int = 8, prompt_len: int = 8,
             1e6 / max(ab["paged"]["tokens_per_s"], 1e-9),
             f"tokens_per_s={ab['paged']['tokens_per_s']:.1f} "
             f"memory_reduction={ab['memory_reduction']:.2f}"))
-    records = [record]
-    if objective:
-        # The objective A/B on the modeled power clock: lower modeled
-        # joules than the perf run on the same trace, tokens bit-identical.
-        # Both non-perf objectives run against ONE shared perf reference;
-        # the requested one lands in this record's ``objective_ab`` (and
-        # is what --check gates), the other becomes its own informational
-        # record so BENCH_serving.json always carries the energy-vs-edp
-        # comparison.
-        both = ("energy", "edp")
-        blocks = objective_ab(cfg, params, gen_len, seq_cap, reps,
-                              objectives=both)
-        record["objective_ab"] = blocks[objective]
-        for obj in both:
-            ab = blocks[obj]
-            if obj != objective:
-                records.append(
-                    {"name": f"serve_objective_{obj}", "objective_ab": ab}
-                )
-            rows.append(Row(
-                f"serve_engine_{obj}", 0.0,
-                f"energy_ratio={ab['energy_ratio']:.3f} "
-                f"throughput_ratio={ab['throughput_ratio']:.3f} "
-                f"tokens_per_j={ab[obj]['tokens_per_j']:.3f}"))
-    path = write_json("BENCH_serving.json", records, bench="serving",
+    path = write_json("BENCH_serving.json", [record], bench="serving",
                       arch=cfg.name)
     print(f"wrote {path}")
     return rows
@@ -407,20 +305,13 @@ def main():
     ap.add_argument("--paged", action="store_true",
                     help="add the paged-vs-dense KV A/B rows (high slot "
                          "count, mixed lengths, memory_reduction field)")
-    ap.add_argument("--objective", default=None, choices=["energy", "edp"],
-                    help="add the perf-vs-objective engine A/B (modeled "
-                         "energy_j / tokens_per_j columns; tokens must stay "
-                         "bit-identical)")
     ap.add_argument("--check", action="store_true",
                     help="exit non-zero unless the engine is strictly faster "
                          "(with --paged, the paged pool at least halves peak "
-                         "KV memory; with --objective, modeled joules drop "
-                         "strictly below the perf run at a bounded modeled-"
-                         "throughput loss)")
+                         "KV memory)")
     args = ap.parse_args()
     rows = run(args.arch, args.batch, args.prompt_len, args.gen_len,
-               args.seq_cap, args.reps, args.mixed, args.obs, args.paged,
-               args.objective)
+               args.seq_cap, args.reps, args.mixed, args.obs, args.paged)
     for r in rows:
         print(f"{r.name},{r.us_per_call:.1f},{r.derived}")
     if args.check:
@@ -433,26 +324,6 @@ def main():
             if red < 2.0:
                 raise SystemExit(
                     f"paged KV pool reduction below 2x: {red}"
-                )
-        if args.objective:
-            obj_row = next(
-                r for r in rows if r.name == f"serve_engine_{args.objective}"
-            )
-            eratio = float(
-                obj_row.derived.split("energy_ratio=")[1].split()[0]
-            )
-            tratio = float(
-                obj_row.derived.split("throughput_ratio=")[1].split()[0]
-            )
-            if eratio >= 1.0:
-                raise SystemExit(
-                    f"{args.objective} objective saved no modeled energy: "
-                    f"energy_ratio={eratio}"
-                )
-            if tratio < 0.2:
-                raise SystemExit(
-                    f"{args.objective} objective lost too much modeled "
-                    f"throughput: throughput_ratio={tratio}"
                 )
 
 

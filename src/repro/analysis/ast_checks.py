@@ -23,12 +23,7 @@
   comparison or ``in`` test against a ``*backend``-named expression, a
   subscript of a registry table) whose value is outside the vocabulary
   the caller passes in — which the CLI builds from the *live* registries,
-  so the lint can never itself drift from the code.  The same pass guards
-  the *objective* vocabulary (``schedule.OBJECTIVES`` — perf/energy/edp):
-  an ``objective=`` keyword or a comparison against an
-  ``objective``-named expression with a literal outside the live tuple is
-  the identical bug class (a misspelled ``"engery"`` silently selecting
-  the default objective).
+  so the lint can never itself drift from the code.
 
 * **RPR006 — fault-point drift.**  The fleet's fault-injection registry
   (``runtime.faults.FAULT_POINTS``) is the vocabulary every injection
@@ -81,14 +76,6 @@ def _is_backend_named(node: ast.AST) -> bool:
     return last == "backend" or any(
         last == h or last.endswith("_" + h) for h in _BACKEND_NAME_HINTS
     )
-
-
-def _is_objective_named(node: ast.AST) -> bool:
-    name = dotted_name(node)
-    if name is None:
-        return False
-    last = name.split(".")[-1]
-    return last == "objective" or last.endswith("_objective")
 
 
 # ---------------------------------------------------------------------------
@@ -283,15 +270,9 @@ def _str_literals(node: ast.AST) -> list[ast.Constant]:
 
 
 class _BackendDriftVisitor(ast.NodeVisitor):
-    def __init__(
-        self,
-        path: str,
-        vocabulary: frozenset[str],
-        objectives: Optional[frozenset[str]] = None,
-    ):
+    def __init__(self, path: str, vocabulary: frozenset[str]):
         self.path = path
         self.vocab = vocabulary
-        self.objectives = objectives
         self.diags: list[Diagnostic] = []
 
     def _check(self, lit: ast.Constant, where: str) -> None:
@@ -310,22 +291,6 @@ class _BackendDriftVisitor(ast.NodeVisitor):
                 )
             )
 
-    def _check_objective(self, lit: ast.Constant, where: str) -> None:
-        if self.objectives is not None and lit.value not in self.objectives:
-            self.diags.append(
-                Diagnostic(
-                    code="RPR005",
-                    path=self.path,
-                    line=lit.lineno,
-                    col=lit.col_offset,
-                    message=(
-                        f"objective name {lit.value!r} ({where}) is not in "
-                        "the scheduling-objective vocabulary "
-                        "(schedule.OBJECTIVES) — fix the drift"
-                    ),
-                )
-            )
-
     def visit_Call(self, node: ast.Call) -> None:
         callee = dotted_name(node.func)
         last = callee.split(".")[-1] if callee else ""
@@ -334,30 +299,18 @@ class _BackendDriftVisitor(ast.NodeVisitor):
                 if kw.arg in ("backend", "kernel_backend"):
                     for lit in _str_literals(kw.value):
                         self._check(lit, f"keyword {kw.arg}=")
-                elif kw.arg == "objective":
-                    for lit in _str_literals(kw.value):
-                        self._check_objective(lit, "keyword objective=")
         if last in _BACKEND_FUNCS:
             for arg in node.args:
                 for lit in _str_literals(arg):
                     self._check(lit, f"argument of {last}")
-        if last == "validate_objective":
-            for arg in node.args:
-                for lit in _str_literals(arg):
-                    self._check_objective(lit, f"argument of {last}")
         self.generic_visit(node)
 
     def visit_Compare(self, node: ast.Compare) -> None:
         sides = [node.left, *node.comparators]
-        backendish = any(_is_backend_named(s) for s in sides)
-        if backendish:
+        if any(_is_backend_named(s) for s in sides):
             for s in sides:
                 for lit in _str_literals(s):
                     self._check(lit, "comparison with a backend value")
-        elif any(_is_objective_named(s) for s in sides):
-            for s in sides:
-                for lit in _str_literals(s):
-                    self._check_objective(lit, "comparison with an objective value")
         self.generic_visit(node)
 
     def visit_Subscript(self, node: ast.Subscript) -> None:
@@ -372,9 +325,8 @@ def check_backend_drift(
     path: str,
     tree: ast.Module,
     vocabulary: frozenset[str],
-    objectives: Optional[frozenset[str]] = None,
 ) -> list[Diagnostic]:
-    v = _BackendDriftVisitor(path, vocabulary, objectives)
+    v = _BackendDriftVisitor(path, vocabulary)
     v.visit(tree)
     return v.diags
 
@@ -449,7 +401,6 @@ def run_ast_checks(
     path: str,
     source: str,
     vocabulary: frozenset[str],
-    objectives: Optional[frozenset[str]] = None,
     fault_points: Optional[frozenset[str]] = None,
 ) -> list[Diagnostic]:
     """All AST passes (donation included) over one file's source."""
@@ -461,7 +412,7 @@ def run_ast_checks(
     diags.extend(donation.check_module(path, tree))
     diags.extend(check_loop_jit(path, tree))
     diags.extend(check_contextvar_sets(path, tree))
-    diags.extend(check_backend_drift(path, tree, vocabulary, objectives))
+    diags.extend(check_backend_drift(path, tree, vocabulary))
     if fault_points is not None:
         diags.extend(check_fault_point_drift(path, tree, fault_points))
     return diags

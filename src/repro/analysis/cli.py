@@ -53,18 +53,6 @@ def build_vocabulary() -> frozenset[str]:
     return frozenset(backend_vocabulary()) | frozenset(MEASURE_BACKEND_NAMES)
 
 
-def build_objectives() -> frozenset[str]:
-    """The scheduling-objective vocabulary, keyed off the live tuple.
-
-    Sourced from ``repro.core.schedule.OBJECTIVES`` so the drift check
-    can never disagree with what ``validate_objective`` accepts.
-    """
-
-    from repro.core.schedule import OBJECTIVES
-
-    return frozenset(OBJECTIVES)
-
-
 def build_fault_points() -> frozenset[str]:
     """The fault-injection point vocabulary, keyed off the live registry.
 
@@ -103,22 +91,19 @@ def discover(paths: list[str]) -> tuple[list[str], list[str]]:
 def analyze_file(
     path: str,
     vocabulary: Optional[frozenset[str]] = None,
-    objectives: Optional[frozenset[str]] = None,
     fault_points: Optional[frozenset[str]] = None,
 ) -> list[Diagnostic]:
     """All applicable AST passes + suppressions for one Python file."""
 
     if vocabulary is None:
         vocabulary = build_vocabulary()
-    if objectives is None:
-        objectives = build_objectives()
     if fault_points is None:
         fault_points = build_fault_points()
     with open(path, encoding="utf-8") as f:
         source = f.read()
     try:
         diags = ast_checks.run_ast_checks(
-            path, source, vocabulary, objectives, fault_points
+            path, source, vocabulary, fault_points
         )
     except SyntaxError as e:
         # Not our diagnostic to own: surface as a hard error.
@@ -132,21 +117,18 @@ def analyze_paths(
     contracts: bool = True,
     artifacts: Optional[str] = None,
     vocabulary: Optional[frozenset[str]] = None,
-    objectives: Optional[frozenset[str]] = None,
     fault_points: Optional[frozenset[str]] = None,
 ) -> list[Diagnostic]:
     """The full analyzer: AST passes over ``paths`` + contract checks."""
 
     if vocabulary is None:
         vocabulary = build_vocabulary()
-    if objectives is None:
-        objectives = build_objectives()
     if fault_points is None:
         fault_points = build_fault_points()
     diags: list[Diagnostic] = []
     py_files, json_files = discover(paths)
     for path in py_files:
-        diags.extend(analyze_file(path, vocabulary, objectives, fault_points))
+        diags.extend(analyze_file(path, vocabulary, fault_points))
     for path in json_files:
         diags.extend(configcheck.check_tuning_cache_file(path))
     if contracts:

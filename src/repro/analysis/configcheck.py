@@ -259,46 +259,7 @@ def check_bench_artifact(path: str) -> list[Diagnostic]:
         bad("missing/non-list `records`")
     elif not all(isinstance(r, dict) for r in records):
         bad("every record must be an object")
-    else:
-        for i, rec in enumerate(records):
-            if "objective_ab" in rec:
-                _check_objective_ab(rec["objective_ab"], i, bad)
     return diags
-
-
-def _check_objective_ab(block, idx: int, bad) -> None:
-    """Schema for a record's ``objective_ab`` A/B comparison block.
-
-    Emitted by ``benchmarks.bench_serving.objective_ab``: a perf side and
-    one non-perf side, each carrying the modeled energy columns the CI
-    energy gate reads (``energy_j``, ``tokens_per_j``), plus the derived
-    ratios the ``--check`` gate thresholds.
-    """
-
-    where = f"records[{idx}].objective_ab"
-    if not isinstance(block, dict):
-        bad(f"{where} must be an object, got {type(block).__name__}")
-        return
-    obj = block.get("objective")
-    if not isinstance(obj, str) or obj == "perf":
-        bad(f"{where}.objective must name a non-perf objective, got {obj!r}")
-        return
-    for side in ("perf", obj):
-        cols = block.get(side)
-        if not isinstance(cols, dict):
-            bad(f"{where}.{side} side missing/non-object")
-            continue
-        for col in ("energy_j", "tokens_per_j"):
-            v = cols.get(col)
-            if not isinstance(v, (int, float)) or isinstance(v, bool):
-                bad(f"{where}.{side}.{col} must be a number, got {v!r}")
-    for ratio in ("energy_ratio", "throughput_ratio"):
-        v = block.get(ratio)
-        if not isinstance(v, (int, float)) or isinstance(v, bool):
-            bad(f"{where}.{ratio} must be a number, got {v!r}")
-    if block.get("tokens_identical") is not True:
-        bad(f"{where}.tokens_identical must be true — the objective knob "
-            "must not change decoded tokens")
 
 
 def check_artifacts_dir(art_dir: str) -> list[Diagnostic]:

@@ -38,9 +38,8 @@ CODES: dict[str, str] = {
               "(per-iteration retrace/recompile hazard)",
     "RPR004": "raw ContextVar.set without token-reset-in-finally outside the "
               "blessed helpers (execution.py / trace.py discipline)",
-    "RPR005": "backend-name or scheduling-objective string literal outside "
-              "the live vocabulary (execution.BACKENDS / schedule.OBJECTIVES "
-              "drift)",
+    "RPR005": "backend-name string literal outside the live vocabulary "
+              "(execution.BACKENDS drift)",
     "RPR006": "fault-point name string literal outside the live injection "
               "registry (runtime.faults.FAULT_POINTS drift)",
     "RPR101": "backend-registry closure violation (BACKENDS / BACKEND_OPS / "

@@ -100,7 +100,6 @@ class AsymmetricMesh:
         init_ratio: Optional[float] = None,
         tree_shape: tuple[int, int, int] = (1024, 1024, 1024),
         backend: str = "auto",
-        objective: str = "perf",
     ):
         if strategy not in ("sss", "sas", "ca-sas", "das", "ca-das"):
             raise ValueError(f"unknown strategy {strategy!r}")
@@ -109,7 +108,6 @@ class AsymmetricMesh:
         self.batch_tile = batch_tile
         self.tree_shape = tuple(tree_shape)  # canonical GEMM shape for the trees
         self.backend = backend
-        self.objective = S.validate_objective(objective)
         self._trees: dict[tuple[int, int, int], dict] = {}
         self.calibration = None  # set by from_calibration()
         self.n_pods = sum(c.n_pods for c in self.classes)
@@ -127,8 +125,6 @@ class AsymmetricMesh:
             init_ratios=ratios,
             workers=workers,
             tiles=tiles if strategy in ("ca-sas", "ca-das") else [batch_tile] * self.n_pods,
-            objective=objective,
-            powers=self.pod_active_watts() if objective != "perf" else None,
         )
 
     @classmethod
@@ -360,53 +356,6 @@ class AsymmetricMesh:
             pod_class_spec=pod_spec,
         )
 
-    # -- power ------------------------------------------------------------
-
-    def pod_active_watts(self) -> list[float]:
-        """Modeled draw per pod while executing at its sustained rates.
-
-        Per-chip active power from the class spec's :class:`~repro.core.
-        blocking.PowerModel` (idle + per-FLOP + per-byte at the chip's peak
-        rates), scaled by chips per pod.
-        """
-
-        return [
-            c.spec.power.active_w(c.peak_flops, c.hbm_bw) * c.chips_per_pod
-            for _, c in self._pod_class
-        ]
-
-    def pod_idle_watts(self) -> list[float]:
-        """Modeled draw per pod while powered but idle."""
-
-        return [c.spec.power.idle_w * c.chips_per_pod for _, c in self._pod_class]
-
-    def pod_poll_watts(self) -> list[float]:
-        """Modeled draw per pod while busy-waiting (powered, no work)."""
-
-        return [
-            c.spec.power.poll_w(c.peak_flops, c.hbm_bw) * c.chips_per_pod
-            for _, c in self._pod_class
-        ]
-
-    def pod_gated_watts(self) -> list[float]:
-        """Modeled draw per pod while parked (power-gated)."""
-
-        return [c.spec.power.gated_w * c.chips_per_pod for _, c in self._pod_class]
-
-    def pods_by_efficiency(self) -> list[int]:
-        """Pod indices sorted most energy-efficient first (fewest modeled
-        joules per unit of work: active watts / aggregate throughput),
-        ties broken by pod index."""
-
-        active = self.pod_active_watts()
-        agg = [
-            c.rel_throughput * c.chips_per_pod for _, c in self._pod_class
-        ]
-        return sorted(
-            range(self.n_pods),
-            key=lambda i: (active[i] / agg[i] if agg[i] > 0 else float("inf"), i),
-        )
-
     # -- scheduling -------------------------------------------------------
 
     def chunk_table(self, global_batch: int) -> S.ChunkTable:
@@ -420,13 +369,7 @@ class AsymmetricMesh:
         if self.strategy in ("das", "ca-das"):
             self.scheduler.observe(per_pod_units, per_pod_times)
 
-    def slot_budgets(
-        self,
-        slots_per_pod: int,
-        n_work: int,
-        *,
-        parked: Optional[Sequence[int]] = None,
-    ) -> list[int]:
+    def slot_budgets(self, slots_per_pod: int, n_work: int) -> list[int]:
         """Per-pod admission budgets over a fixed ``n_pods × slots_per_pod``
         slot table (the serving engine's slot regions).
 
@@ -442,29 +385,21 @@ class AsymmetricMesh:
         serving analogue of the paper's smaller LITTLE panel.  Budgets
         change only when the scheduler re-derives its table (drift past
         the threshold) or the load level changes — never mid-step.
-
-        ``parked`` pods (the energy objective's power-gated pods) get a
-        hard zero budget; their share and any spill go to unparked pods
-        only, and the total is capped by unparked capacity.
         """
 
         cap = int(slots_per_pod)
-        parked_set = set(int(p) for p in parked) if parked else set()
-        unparked = [i for i in range(self.n_pods) if i not in parked_set]
-        total = min(int(n_work), len(unparked) * cap)
-        if total <= 0 or not unparked:
+        total = min(int(n_work), self.n_pods * cap)
+        if total <= 0:
             return [0] * self.n_pods
         sizes = list(self.chunk_table(total).sizes())
         while len(sizes) < self.n_pods:
             sizes.append(0)
-        budgets = [
-            0 if i in parked_set else min(cap, int(s)) for i, s in enumerate(sizes)
-        ]
+        budgets = [min(cap, int(s)) for s in sizes]
         spill = total - sum(budgets)
         # Highest-aggregate-throughput pods absorb the spill first
-        # (stable by pod order); parked pods never do.
+        # (stable by pod order).
         order = sorted(
-            unparked,
+            range(self.n_pods),
             key=lambda i: (
                 -(self._pod_class[i][1].rel_throughput
                   * self._pod_class[i][1].chips_per_pod),

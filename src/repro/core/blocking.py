@@ -57,52 +57,6 @@ class CacheHierarchy:
 
 
 @dataclasses.dataclass(frozen=True)
-class PowerModel:
-    """Per-device-class power model: idle + per-FLOP + per-byte terms.
-
-    The structure mirrors the calibrated big.LITTLE simulator
-    (``repro.core.simulator.ClusterModel.p_static / p_core / poll_frac``):
-    a static floor drawn whenever the device is powered, an activity term
-    proportional to work executed, and a polling fraction for the
-    busy-wait-while-idle state the paper measures on the Cortex-A15
-    (spinning cores burn ~80% of active power).  ``gated_w`` is the draw
-    of a *parked* device (power-gated / hot-unplugged, the mechanism of
-    the energy-aware AMP follow-on work) — 0 by default.
-
-    :meth:`repro.core.simulator.ClusterModel.power_model` derives an
-    instance from the Exynos constants so the two models cross-check.
-    """
-
-    idle_w: float
-    flop_j: float            # joules per FLOP when active
-    byte_j: float = 0.0      # joules per HBM byte moved
-    poll_frac: float = 0.8   # fraction of active-over-idle power while polling
-    gated_w: float = 0.0     # draw when parked (power-gated)
-
-    def active_w(self, flops_per_s: float, bytes_per_s: float = 0.0) -> float:
-        """Modeled draw while executing at the given rates."""
-        return self.idle_w + self.flop_j * flops_per_s + self.byte_j * bytes_per_s
-
-    def poll_w(self, flops_per_s: float, bytes_per_s: float = 0.0) -> float:
-        """Modeled draw while busy-waiting (powered but starved of work)."""
-        over = self.active_w(flops_per_s, bytes_per_s) - self.idle_w
-        return self.idle_w + self.poll_frac * over
-
-    def energy_j(self, time_s: float, flops: float, bytes_moved: float = 0.0) -> float:
-        """Joules for a unit of work taking ``time_s`` wall seconds."""
-        return self.idle_w * time_s + self.flop_j * flops + self.byte_j * bytes_moved
-
-
-# Modeled power constants.  Chosen so the big:little *active*-power ratio
-# (~290 W : ~30 W at sustained rates, about 9.5x) mirrors the measured
-# Exynos 5422 cluster ratio (A15 quad ~3.5 W : A7 quad ~0.37 W), while the
-# little class lands ~2.4x more energy-efficient per unit of work — the
-# paper's headline asymmetry (big is faster, LITTLE is cheaper per FLOP).
-TPU_V5E_POWER = PowerModel(idle_w=60.0, flop_j=1.0e-12, byte_j=4.0e-11)
-TPU_LITTLE_POWER = PowerModel(idle_w=8.0, flop_j=1.6e-13, byte_j=1.5e-11)
-
-
-@dataclasses.dataclass(frozen=True)
 class TpuCoreSpec:
     """A TPU TensorCore as seen by the blocking derivation."""
 
@@ -116,7 +70,6 @@ class TpuCoreSpec:
     # Fraction of VMEM available to the GEMM pipeline (the rest holds
     # semaphores, spills, and the scalar prefetch state).
     vmem_fill: float = 0.9
-    power: PowerModel = TPU_V5E_POWER
 
 
 # Paper's platform (Section 3.2): per-core L1d 32 KiB; L2 shared per
@@ -136,7 +89,6 @@ TPU_LITTLE = TpuCoreSpec(
     vmem_bytes=8 * 1024 * 1024,
     peak_flops=99e12,
     hbm_bw=410e9,
-    power=TPU_LITTLE_POWER,
 )
 
 

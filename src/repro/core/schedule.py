@@ -74,65 +74,6 @@ class ChunkTable:
             raise ValueError(f"chunk table covers {pos} of {self.n_units} units")
 
 
-# ---------------------------------------------------------------------------
-# Scheduling objectives
-# ---------------------------------------------------------------------------
-
-# What the scheduler optimizes.  ``perf`` is the paper's baseline (minimize
-# makespan); ``energy`` minimizes modeled joules (the companion work's
-# throughput-per-Watt goal); ``edp`` minimizes the energy-delay product,
-# the standard compromise between the two.
-OBJECTIVES = ("perf", "energy", "edp")
-
-# Exponent applied to the per-class energy-efficiency discount: perf
-# ignores efficiency entirely, energy weighs it fully, edp takes the
-# geometric middle (sqrt) — minimizing E*t trades each factor evenly.
-_OBJECTIVE_EXP = {"perf": 0.0, "energy": 1.0, "edp": 0.5}
-
-
-def validate_objective(objective: str) -> str:
-    if objective not in OBJECTIVES:
-        raise ValueError(
-            f"unknown objective {objective!r}; expected one of {OBJECTIVES}"
-        )
-    return objective
-
-
-def objective_discounts(
-    objective: str,
-    rates: Sequence[float],
-    powers: Optional[Sequence[float]] = None,
-) -> np.ndarray:
-    """Per-class efficiency discounts in ``(0, 1]`` for an objective.
-
-    ``powers[i]`` is class ``i``'s modeled active draw in watts; the energy
-    cost of a unit of work on class ``i`` is then ``powers[i] / rates[i]``
-    joules.  The discount is ``(c_min / c_i) ** exp`` — 1.0 for the most
-    efficient class, smaller for classes that burn more joules per unit —
-    raised to the objective's exponent (0 for perf, 1 for energy, 0.5 for
-    edp).  Under a *uniform* power model (powers proportional to rates,
-    i.e. identical joules per unit) every discount is exactly 1.0, so the
-    energy and edp objectives reduce bit-identically to perf.
-    """
-
-    validate_objective(objective)
-    rates = np.asarray(rates, dtype=np.float64)
-    n = len(rates)
-    if objective == "perf" or powers is None:
-        return np.ones(n)
-    powers = np.asarray(powers, dtype=np.float64)
-    if len(powers) != n:
-        raise ValueError(f"expected {n} class powers, got {len(powers)}")
-    disc = np.ones(n)
-    live = (rates > 0.0) & (powers > 0.0)
-    if not live.any():
-        return disc
-    cost = np.where(live, powers / np.maximum(rates, 1e-300), np.inf)  # J/unit
-    rel = cost[live].min() / cost[live]
-    disc[live] = rel ** _OBJECTIVE_EXP[objective]
-    return disc
-
-
 def _largest_remainder(weights: np.ndarray, total: int) -> np.ndarray:
     """Apportion ``total`` integer units proportionally to ``weights``."""
 
@@ -246,7 +187,6 @@ class DasResult:
     assignments: list[Chunk]
     makespan: float
     busy: list[float]  # per-class busy time
-    energy_j: Optional[float] = None  # modeled joules (when powers given)
 
     def sizes(self) -> list[int]:
         n_cls = len(self.busy)
@@ -263,9 +203,6 @@ def das_schedule(
     *,
     grab_overhead: float = 0.0,
     unit_cost: float = 1.0,
-    objective: str = "perf",
-    powers: Optional[Sequence[float]] = None,
-    idle_powers: Optional[Sequence[float]] = None,
 ) -> DasResult:
     """Greedy dynamic chunk distribution (paper Section 5.4).
 
@@ -275,17 +212,6 @@ def das_schedule(
     aggregate class throughput in units/second).  ``grab_overhead`` models
     the critical section.  Deterministic: ties broken by class index.
 
-    Non-``perf`` objectives bias the greedy choice toward energy-efficient
-    classes via *virtual time*: class ``i`` advances its selection clock by
-    ``dur / discount_i`` (see :func:`objective_discounts`), so a class that
-    burns more joules per unit looks proportionally slower to the selector
-    and grabs proportionally less work — while physical times, busy, and
-    makespan still account real seconds.  Under a uniform power model every
-    discount is 1.0 and the schedule is bit-identical to ``perf``.  When
-    ``powers`` is given, ``energy_j`` reports the modeled joules (active
-    draw while busy plus, when ``idle_powers`` is given, idle draw for the
-    remainder of the makespan).
-
     A zero-rate class (a dead pod) never grabs work — it is skipped by the
     greedy loop, exactly as a hung cluster leader would never re-enter the
     paper's critical section.  All classes dead is unschedulable and raises.
@@ -293,35 +219,23 @@ def das_schedule(
 
     rates = list(map(float, rates))
     strides = [max(1, int(s)) for s in strides]
-    disc = objective_discounts(objective, rates, powers)
     alive = [i for i, r in enumerate(rates) if r > 0.0]
     if not alive and n_units > 0:
         raise ValueError("all class rates are zero — nothing can grab work")
-    t = [0.0] * len(rates)   # next-free physical time per class
-    tv = [0.0] * len(rates)  # virtual time: physical / efficiency discount
+    t = [0.0] * len(rates)  # next-free time per class
     busy = [0.0] * len(rates)
     pos = 0
     assignments: list[Chunk] = []
     while pos < n_units:
-        cls = min(alive, key=lambda i: (tv[i], i))
+        cls = min(alive, key=lambda i: (t[i], i))
         size = min(strides[cls], n_units - pos)
         dur = grab_overhead + size * unit_cost / rates[cls]
         assignments.append(Chunk(cls=cls, start=pos, size=size))
         pos += size
         t[cls] += dur
-        tv[cls] += dur / disc[cls] if disc[cls] > 0 else float("inf")
         busy[cls] += dur
     makespan = max(t) if t else 0.0
-    energy = None
-    if powers is not None:
-        p = np.asarray(powers, dtype=np.float64)
-        energy = float(np.dot(p, busy))
-        if idle_powers is not None:
-            ip = np.asarray(idle_powers, dtype=np.float64)
-            energy += float(np.dot(ip, makespan - np.asarray(busy)))
-    return DasResult(
-        assignments=assignments, makespan=makespan, busy=busy, energy_j=energy
-    )
+    return DasResult(assignments=assignments, makespan=makespan, busy=busy)
 
 
 class DynamicScheduler:
@@ -354,21 +268,11 @@ class DynamicScheduler:
         workers: Optional[Sequence[int]] = None,
         ema: float = 0.5,
         rebalance_threshold: float = 0.05,
-        objective: str = "perf",
-        powers: Optional[Sequence[float]] = None,
     ):
         self.n_classes = n_classes
         self.ema = float(ema)
         self.tiles = list(tiles) if tiles is not None else None
         self.workers = list(workers) if workers is not None else None
-        self.objective = validate_objective(objective)
-        self.powers = (
-            np.asarray(powers, dtype=np.float64).copy() if powers is not None else None
-        )
-        if self.powers is not None and len(self.powers) != n_classes:
-            raise ValueError(
-                f"expected {n_classes} class powers, got {len(self.powers)}"
-            )
         self.rates = np.asarray(
             init_ratios if init_ratios is not None else np.ones(n_classes), dtype=np.float64
         ).copy()
@@ -446,13 +350,9 @@ class DynamicScheduler:
         ):
             return self._last_table
         drift = self.drift()  # trigger magnitude, before _table_rates resets
-        # Non-perf objectives shrink inefficient classes' shares by their
-        # efficiency discount; under uniform power every discount is 1.0
-        # and the weights (hence the table) are bit-identical to perf.
-        weights = self.rates * objective_discounts(
-            self.objective, self.rates, self.powers
+        t = sas_partition(
+            n_units, self.rates, workers=self.workers, tiles=self.tiles
         )
-        t = sas_partition(n_units, weights, workers=self.workers, tiles=self.tiles)
         sizes = np.asarray(t.sizes())
         if (
             self._last_sizes is not None
@@ -507,8 +407,6 @@ def fleet_scheduler(
     *,
     ema: float = 0.5,
     rebalance_threshold: float = 0.05,
-    objective: str = "perf",
-    powers: Optional[Sequence[float]] = None,
 ) -> DynamicScheduler:
     """The engines-as-classes adapter: a :class:`DynamicScheduler` whose
     "classes" are whole serving engines.
@@ -528,8 +426,6 @@ def fleet_scheduler(
         init_ratios=rel,
         ema=ema,
         rebalance_threshold=rebalance_threshold,
-        objective=objective,
-        powers=powers,
     )
 
 
@@ -554,9 +450,6 @@ __all__ = [
     "ChunkTable",
     "DasResult",
     "DynamicScheduler",
-    "OBJECTIVES",
-    "validate_objective",
-    "objective_discounts",
     "sss_partition",
     "sas_partition",
     "ca_sas_partition",

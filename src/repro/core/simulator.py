@@ -59,33 +59,6 @@ class ClusterModel:
     def rate(self, n_cores: int) -> float:
         return self.cum_gflops[min(n_cores, self.n_cores) - 1] * 1e9
 
-    def power_model(
-        self, n_cores: Optional[int] = None, effective_rate: Optional[float] = None
-    ) -> B.PowerModel:
-        """The spec-level :class:`~repro.core.blocking.PowerModel` equivalent
-        of this cluster's Exynos constants.
-
-        ``idle_w`` is the cluster static draw; the per-core active power
-        becomes a per-FLOP term at ``effective_rate`` (achieved FLOP/s,
-        default the calibrated :meth:`rate` for ``n_cores``).  By
-        construction, energy scored through the returned model equals the
-        simulator's :func:`_energy` accounting for this cluster (less the
-        shared ``P_BASE`` board term) whenever the workload runs at
-        ``effective_rate`` — the cross-check tested in
-        ``tests/test_energy.py``.
-        """
-
-        nc = self.n_cores if n_cores is None else int(n_cores)
-        rate = self.rate(nc) if effective_rate is None else float(effective_rate)
-        if rate <= 0:
-            raise ValueError("effective_rate must be positive")
-        return B.PowerModel(
-            idle_w=self.p_static,
-            flop_j=nc * self.p_core / rate,
-            byte_j=0.0,
-            poll_frac=self.poll_frac,
-        )
-
 
 A15 = ClusterModel(
     name="cortex-a15",

@@ -194,7 +194,7 @@ def serving_mesh(args) -> AsymmetricMesh:
     pod."""
 
     return AsymmetricMesh(biglittle_classes(chips_per_pod=1), strategy=args.strategy,
-                          batch_tile=1, objective=args.objective)
+                          batch_tile=1)
 
 
 def make_engine(cfg, params, asym, args, seq_cap, c_max):
@@ -254,7 +254,7 @@ def _fleet(cfg, params, asym, prompts, args, seq_cap):
         a = serving_mesh(args)
         layout = a.batch_layout(max(1, args.batch // args.fleet))
         engines.append(make_engine(cfg, params, a, args, seq_cap, layout.c_max))
-    fleet = Fleet(engines, objective=args.objective)
+    fleet = Fleet(engines)
     print("fleet rel_throughput:", [round(r, 3) for r in fleet.rel_throughput])
     out = fleet.generate(prompts, args.gen_len)
     # Engines tick in lockstep and would run concurrently in production,
@@ -292,10 +292,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen-len", type=int, default=8)
     ap.add_argument("--strategy", default="ca-das")
-    ap.add_argument("--objective", default="perf", choices=["perf", "energy", "edp"],
-                    help="scheduling objective: perf (default, bit-identical "
-                         "to before), energy (park inefficient pods at low "
-                         "load, weight shares by joules/unit), or edp")
     ap.add_argument("--device-class", default=None,
                     help="serve under this class's control tree (default: fastest)")
     ap.add_argument("--class-sharded", default="auto", choices=["auto", "on", "off"],
@@ -354,8 +350,6 @@ def main():
 
     # Asymmetric request routing: split the request batch across classes.
     asym = serving_mesh(args)
-    if args.one_shot and args.objective != "perf":
-        raise SystemExit("--objective applies to the engine path only")
     if args.class_sharded == "on" and args.device_class is not None:
         raise SystemExit(
             "--class-sharded on serves every class simultaneously; "
@@ -400,7 +394,6 @@ def main():
         "arch": cfg.name,
         "path": ("one-shot" if args.one_shot
                  else f"fleet:{args.fleet}" if args.fleet else "engine"),
-        "objective": args.objective,
         "device_class": device_class,
         "exec_backend": exec_backend,
         "class_sharded": shard_classes is not None,

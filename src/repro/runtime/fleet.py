@@ -27,7 +27,7 @@ heuristics:
   hysteresis applied to liveness: a single hiccup must not thrash
   placement.
 * **Queued-request migration** — *not-yet-admitted* requests move away
-  from dead, unhealthy, parked, or saturated engines
+  from dead, unhealthy, or saturated engines
   (:meth:`ServingEngine.withdraw` / :meth:`~ServingEngine.export_queued`
   roll back the engine router's counts).  Admitted work never migrates:
   a decode slot's tokens are already flowing, and exactness comes from
@@ -36,14 +36,6 @@ heuristics:
   deadline migrates; a request in flight on a dying engine is
   re-submitted after an exponential backoff (``retry_backoff · 2^(k-1)``
   ticks).
-* **Fleet-level parking** — under ``objective="energy"|"edp"`` the
-  fleet drains and gates whole *engines* the load does not need,
-  reusing PR 9's pod-parking protocol one level up: park the least
-  energy-efficient engine while offered load fits the remaining
-  capacity with hysteresis margin (``n_work ≤ remaining·(1−h)``,
-  ``h`` = the scheduler's ``rebalance_threshold``), re-admit most
-  efficient first, never park the most efficient or last engine.
-  Parking only blocks new routing — in-flight work drains naturally.
 
 **Exactness contract** (tested): every submitted request completes
 *exactly once*, with tokens bit-identical to a fault-free single-engine
@@ -56,7 +48,7 @@ when it admits, what the scheduler observes.
 
 The tick loop is cooperative and deterministic: :meth:`tick` runs one
 scheduling round (faults → admit → step → observe → harvest → deadlines
-→ retries → parking → migration) over every live engine.  ``async``
+→ retries → migration) over every live engine.  ``async``
 surfaces (:meth:`submit_async`, :meth:`stream`, :meth:`run_async`) wrap
 the same loop for streaming clients.
 """
@@ -86,10 +78,7 @@ def _metrics():
         _M = {
             "engines_alive": MET.gauge(
                 "fleet_engines_alive",
-                "Engines alive (not killed), including parked ones"),
-            "engines_parked": MET.gauge(
-                "fleet_engines_parked",
-                "Engines drained and gated by the energy objective"),
+                "Engines alive (not killed)"),
             "queue_depth": MET.gauge(
                 "fleet_queue_depth", "Queued requests per engine",
                 labels=("engine",)),
@@ -125,8 +114,6 @@ class FleetStats:
     stalled_ticks: int = 0
     admission_faults: int = 0
     latency_spikes: int = 0
-    engine_parks: int = 0
-    engine_unparks: int = 0
     health_trips: int = 0            # healthy -> unhealthy transitions
     health_recoveries: int = 0
     ticks: int = 0
@@ -171,13 +158,8 @@ class Fleet:
     engines : the serving engines (heterogeneous class mixes welcome).
     rel_throughput : per-engine calibrated tokens/s; defaults to each
         engine's :meth:`~ServingEngine.calibrated_tps`.
-    powers : per-engine modeled active watts (for the energy/edp routing
-        discount and parking order); defaults to the sum of each
-        engine's per-pod active watts.
-    objective : "perf" | "energy" | "edp" — non-perf objectives discount
-        inefficient engines' routing shares and enable engine parking.
     ema, rebalance_threshold : forwarded to the fleet scheduler
-        (hysteresis governs both share re-derivation and parking).
+        (hysteresis governs share re-derivation).
     unhealthy_after, healthy_after : health hysteresis in ticks.
     retry_backoff : base backoff (ticks) before retrying a request lost
         to an engine death; doubles per attempt.
@@ -191,8 +173,6 @@ class Fleet:
         engines: Sequence[ServingEngine],
         *,
         rel_throughput: Optional[Sequence[float]] = None,
-        powers: Optional[Sequence[float]] = None,
-        objective: str = "perf",
         ema: float = 0.5,
         rebalance_threshold: float = 0.05,
         unhealthy_after: int = 2,
@@ -208,16 +188,10 @@ class Fleet:
         if rel_throughput is None:
             rel_throughput = [e.calibrated_tps() for e in engines]
         self.rel_throughput = [float(r) for r in rel_throughput]
-        if powers is None:
-            powers = [float(sum(e.asym.pod_active_watts())) for e in engines]
-        self.powers = [float(p) for p in powers]
-        self.objective = objective
         self.scheduler = fleet_scheduler(
             self.rel_throughput,
             ema=ema,
             rebalance_threshold=rebalance_threshold,
-            objective=objective,
-            powers=self.powers,
         )
         self.unhealthy_after = int(unhealthy_after)
         self.healthy_after = int(healthy_after)
@@ -229,7 +203,6 @@ class Fleet:
         self._unhealthy = [False] * self.n_engines
         self._bad = [0] * self.n_engines      # consecutive bad ticks
         self._good = [0] * self.n_engines     # consecutive good ticks
-        self._parked: set[int] = set()
         # frid bookkeeping: at most one live placement per fleet rid.
         self._pending: dict[int, _Pending] = {}
         self._rid_map: list[dict[int, int]] = [dict() for _ in engines]
@@ -267,8 +240,8 @@ class Fleet:
 
     def _candidates(self, exclude: frozenset = frozenset()) -> list[int]:
         """Routable engines, in degradation order: prefer healthy live
-        unparked engines, fall back to parked/unhealthy ones (graceful
-        degradation beats rejecting work), never a dead engine."""
+        engines, fall back to unhealthy ones (graceful degradation beats
+        rejecting work), never a dead engine."""
 
         def pick(pred):
             return [
@@ -276,9 +249,7 @@ class Fleet:
                 if self._alive[i] and i not in exclude and pred(i)
             ]
 
-        cands = pick(lambda i: i not in self._parked and not self._unhealthy[i])
-        if not cands:
-            cands = pick(lambda i: not self._unhealthy[i])
+        cands = pick(lambda i: not self._unhealthy[i])
         if not cands:
             cands = pick(lambda i: True)
         return cands
@@ -316,7 +287,7 @@ class Fleet:
         return e
 
     def _can_migrate(self, p: _Pending) -> bool:
-        """Optional migrations (deadline, park drain, saturation) skip
+        """Optional migrations (deadline, saturation) skip
         rather than burn the last placement attempts — a request that has
         moved a lot stays queued where it is and completes there; only a
         *mandatory* re-place (engine death) may exhaust the cap and
@@ -356,7 +327,7 @@ class Fleet:
         admit/step, the scheduler observes the tick's per-engine
         progress on the modeled clock, completions are harvested
         (exactly-once bookkeeping), then the control actions — deadline
-        requeues, backoff retries, parking, saturation migration — run
+        requeues, backoff retries, saturation migration — run
         on the post-step state.
         """
 
@@ -402,7 +373,6 @@ class Fleet:
         self._harvest()
         self._check_deadlines()
         self._retry_due()
-        self._update_parking()
         self._migrate_from_saturated()
         if T.enabled():
             self._record_tick_telemetry()
@@ -447,7 +417,6 @@ class Fleet:
         """
 
         self._alive[e] = False
-        self._parked.discard(e)
         self._unhealthy[e] = False
         self.stats.engine_kills += 1
         eng = self.engines[e]
@@ -546,96 +515,6 @@ class Fleet:
                     rid=p.rid, dst=e, attempt=p.attempts,
                 )
 
-    # -- fleet-level parking (PR 9's pod protocol, one level up) -------------
-
-    def _capacity(self, engines: Sequence[int]) -> int:
-        return sum(self.engines[i].n_slots for i in engines)
-
-    def _offered_load(self) -> int:
-        n = sum(
-            1 for p in self._pending.values() if p.engine < 0
-        )  # unplaced retries still need a seat
-        for e, eng in enumerate(self.engines):
-            if self._alive[e]:
-                n += sum(len(q) for q in eng.queues)
-                n += int((eng.slot_rid >= 0).sum())
-        return n
-
-    def _engines_by_efficiency(self) -> list[int]:
-        """Alive engines, most energy-efficient first (modeled active
-        watts per unit of calibrated throughput, ascending)."""
-
-        alive = [i for i in range(self.n_engines) if self._alive[i]]
-        return sorted(
-            alive,
-            key=lambda i: (self.powers[i] / max(self.scheduler.rates[i], 1e-12), i),
-        )
-
-    def _update_parking(self) -> None:
-        if self.objective == "perf" or self.n_engines < 2:
-            return
-        h = self.scheduler.rebalance_threshold
-        n_work = self._offered_load()
-        order = self._engines_by_efficiency()
-        if not order:
-            return
-        unparked = [i for i in order if i not in self._parked]
-        # Re-admit most efficient first while capacity is short.
-        for i in order:
-            if self._capacity(unparked) >= n_work:
-                break
-            if i in self._parked:
-                self._unpark(i)
-                unparked = [j for j in order if j not in self._parked]
-        # Park least efficient while the rest holds the load with margin.
-        for i in reversed(order):
-            if i in self._parked or len(unparked) <= 1 or i == order[0]:
-                continue
-            remaining = [j for j in unparked if j != i]
-            if n_work <= self._capacity(remaining) * (1.0 - h):
-                self._park(i)
-                unparked = remaining
-            else:
-                break
-
-    def _park(self, e: int) -> None:
-        """Drain and gate one engine: queued requests migrate, routing
-        excludes it, in-flight work finishes (parking never preempts)."""
-
-        self._parked.add(e)
-        self.stats.engine_parks += 1
-        drained = 0
-        for req in self.engines[e].export_queued():
-            rid = self._rid_map[e].pop(req.rid, None)
-            if rid is None:
-                continue
-            p = self._pending[rid]
-            self._routed[e] -= 1
-            p.engine, p.erid = -1, -1
-            if self._can_migrate(p):
-                self._migrate(p, e, reason="engine_park")
-                drained += 1
-            else:
-                # Hand it back under a fresh engine rid: a parked engine
-                # still admits what it kept (parking blocks routing, not
-                # progress).
-                erid = self.engines[e].submit(p.prompt, p.max_new_tokens)
-                self._rid_map[e][erid] = rid
-                self._routed[e] += 1
-                p.engine, p.erid = e, erid
-        if T.enabled():
-            _metrics()["engines_parked"].set(len(self._parked))
-            T.instant(
-                "fleet.engine_park", cat="fleet", engine=e, drained=drained,
-            )
-
-    def _unpark(self, e: int) -> None:
-        self._parked.discard(e)
-        self.stats.engine_unparks += 1
-        if T.enabled():
-            _metrics()["engines_parked"].set(len(self._parked))
-            T.instant("fleet.engine_unpark", cat="fleet", engine=e)
-
     # -- queued-request migration off saturated engines ----------------------
 
     def _migrate_from_saturated(self) -> None:
@@ -656,8 +535,7 @@ class Fleet:
             queued = [r for q in eng.queues for r in q]
             if not queued:
                 continue
-            drain_all = self._unhealthy[e] or e in self._parked
-            if not drain_all:
+            if not self._unhealthy[e]:
                 full = int((eng.slot_rid >= 0).sum()) >= eng.n_slots
                 idle_room = sum(
                     max(
@@ -689,7 +567,6 @@ class Fleet:
     def _record_tick_telemetry(self) -> None:
         m = _metrics()
         m["engines_alive"].set(sum(self._alive))
-        m["engines_parked"].set(len(self._parked))
         for e, eng in enumerate(self.engines):
             if not self._alive[e]:
                 continue
@@ -708,7 +585,6 @@ class Fleet:
         return {
             "tick": self._tick,
             "alive": sum(self._alive),
-            "parked": sorted(self._parked),
             "unhealthy": [
                 i for i in range(self.n_engines) if self._unhealthy[i]
             ],

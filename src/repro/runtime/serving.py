@@ -55,14 +55,6 @@ state.  This engine amortizes all of it:
   * **Rebalance hysteresis** — per-pod step timings feed
     ``DynamicScheduler.observe``; slot-region budgets re-derive *only*
     past the scheduler's drift threshold, and only between steps.
-  * **Load-adaptive parking** (``AsymmetricMesh(objective="energy"|"edp")``)
-    — at low offered load the engine parks the least energy-efficient
-    pods (zero slot budget, modeled gated watts) and serves from the
-    efficient ones; past a hysteresis threshold on offered load the
-    parked pods re-admit.  Modeled ``energy_j`` / ``tokens_per_j``
-    accumulate per decode step from the class specs' PowerModels —
-    deterministic, host-independent figures the serving bench gates on.
-    The default ``perf`` objective never parks and stays bit-identical.
 
 Exactness contract (tested in tests/test_paged_serving.py): the paged
 engine's tokens are **bit-identical** to the dense slot-table engine's
@@ -141,9 +133,6 @@ def _metrics():
                 "engine_page_allocs_total",
                 "KV pages allocated at admission",
                 labels=("device_class",)),
-            "pods_parked": MET.gauge(
-                "engine_pods_parked",
-                "Pods currently parked (power-gated) by the energy objective"),
         }
     return _M
 
@@ -163,8 +152,8 @@ def _moe_counters():
 # Modeled wall seconds for one slot-row of decode work on a pod of unit
 # aggregate throughput (``rel_throughput × chips_per_pod == 1``).  The
 # absolute scale is arbitrary — only ratios between pods matter for the
-# modeled energy/throughput columns — but a fixed constant keeps the
-# figures deterministic across hosts (unlike wall clocks).
+# modeled throughput — but a fixed constant keeps the figures
+# deterministic across hosts (unlike wall clocks).
 MODELED_ROW_S = 1e-3
 
 
@@ -272,12 +261,9 @@ class EngineStats:
     # counter exists for the JSON reporting contract, not as the guard.
     host_relayouts: int = 0
     rebalances: int = 0           # slot-budget re-derivations past hysteresis
-    # Modeled (power-model clock, not wall clock) energy accounting over
-    # the decode steps; deterministic across hosts.
-    energy_j: float = 0.0         # modeled joules burned by decode steps
-    modeled_decode_s: float = 0.0 # modeled decode seconds those joules cover
-    pod_parks: int = 0            # pods parked by the energy objective
-    pod_unparks: int = 0          # pods re-admitted as load ramped
+    # Decode seconds on the modeled clock (:data:`MODELED_ROW_S`, not wall
+    # clock); deterministic across hosts.
+    modeled_decode_s: float = 0.0
     # Bytes of the served weight tree per dtype (``engine_weight_bytes``).
     weight_bytes: dict = dataclasses.field(default_factory=dict)
 
@@ -286,12 +272,6 @@ class EngineStats:
         """Decode throughput, compiles excluded."""
 
         return self.tokens / self.decode_s if self.decode_s > 0 else 0.0
-
-    @property
-    def tokens_per_j(self) -> float:
-        """Modeled energy efficiency of decode."""
-
-        return self.tokens / self.energy_j if self.energy_j > 0 else 0.0
 
     @property
     def modeled_tokens_per_s(self) -> float:
@@ -306,7 +286,6 @@ class EngineStats:
 
         out = dataclasses.asdict(self)
         out["tokens_per_s"] = round(self.tokens_per_s, 3)
-        out["tokens_per_j"] = round(self.tokens_per_j, 3)
         out["modeled_tokens_per_s"] = round(self.modeled_tokens_per_s, 3)
         return out
 
@@ -434,16 +413,6 @@ class ServingEngine:
         for dtype, n in self.stats.weight_bytes.items():
             served.labels(dtype=dtype).set(n)
         self._rebalances0 = asym.scheduler.rebalances
-        # -- load-adaptive parking + modeled power (energy objective) ------
-        # Parked pods draw a zero slot budget and model gated watts; the
-        # ``perf`` objective never parks, keeping today's behavior
-        # bit-identical.  Per-pod watts are precomputed from the class
-        # specs' PowerModels (see core/blocking.py).
-        self._parked: set[int] = set()
-        self._active_w = asym.pod_active_watts()
-        self._idle_w = asym.pod_idle_watts()
-        self._poll_w = asym.pod_poll_watts()
-        self._gated_w = asym.pod_gated_watts()
         self._pod_agg = [
             asym.class_of_pod(p).rel_throughput * asym.class_of_pod(p).chips_per_pod
             for p in range(self.n_pods)
@@ -654,7 +623,7 @@ class ServingEngine:
     def export_queued(self) -> list[Request]:
         """Drain every class queue, in submission (rid) order.
 
-        The fleet's migration path: a saturated, parked, or dead engine
+        The fleet's migration path: a saturated or dead engine
         hands its not-yet-admitted requests back so they can be re-routed
         elsewhere.  Router counts roll back as in :meth:`withdraw`.
         """
@@ -695,7 +664,6 @@ class ServingEngine:
             "queued": sum(len(q) for q in self.queues),
             "active": int((self.slot_rid >= 0).sum()),
             "slots": self.n_slots,
-            "parked_pods": sorted(self._parked),
             "calibrated_tps": self.calibrated_tps(),
             "completed": self.stats.completed,
             "admission_deferrals": self.stats.admission_deferrals,
@@ -707,10 +675,7 @@ class ServingEngine:
         old_budgets = list(self.budgets)
         old_count = self.stats.rebalances
         n_work = int((self.slot_rid >= 0).sum()) + sum(len(q) for q in self.queues)
-        self._update_parking(n_work)
-        self.budgets = self.asym.slot_budgets(
-            self.c_max, n_work, parked=sorted(self._parked)
-        )
+        self.budgets = self.asym.slot_budgets(self.c_max, n_work)
         # The scheduler re-derives its table (counting a rebalance) only
         # past the hysteresis threshold — whether the trigger was a budget
         # refresh or the batch path's routing table.
@@ -723,85 +688,6 @@ class ServingEngine:
                 n_work=n_work, drift=self.asym.scheduler.drift(),
                 rebalances=self.stats.rebalances,
             )
-
-    # -- load-adaptive pod parking (energy objective only) --------------------
-
-    def _update_parking(self, n_work: int):
-        """Park/unpark pods against the offered load, with hysteresis.
-
-        The energy objective's serving move: at low queue depth the
-        engine parks the least energy-efficient pods (big, under the
-        default power models) — zero slot budget, modeled gated watts —
-        and serves from the efficient ones; as offered load ramps past
-        what the unparked capacity covers, parked pods re-admit, most
-        efficient first.  The hysteresis margin reuses the scheduler's
-        drift threshold: a pod parks only when the load sits below the
-        *remaining* capacity by that margin (``n_work <= cap·(1-h)``)
-        and unparks as soon as capacity falls short — the gap between
-        the two prevents park/unpark thrash at the boundary.  The most
-        efficient pod never parks; existing requests on a freshly parked
-        pod run to completion (parking only blocks new admissions).
-        ``perf`` never parks — today's behavior stays bit-identical.
-        """
-
-        if self.asym.objective == "perf" or self.n_pods < 2:
-            return
-        h = self.asym.scheduler.rebalance_threshold
-        order = self.asym.pods_by_efficiency()  # most efficient first
-        for p in order:
-            if (self.n_pods - len(self._parked)) * self.c_max >= n_work:
-                break
-            if p in self._parked:
-                self._unpark(p, n_work)
-        for p in reversed(order):
-            if p in self._parked:
-                continue
-            if len(self._parked) >= self.n_pods - 1:
-                break
-            remaining = (self.n_pods - len(self._parked) - 1) * self.c_max
-            if n_work <= remaining * (1.0 - h):
-                self._park(p, n_work)
-            else:
-                break
-
-    def _park(self, pod: int, n_work: int):
-        self._parked.add(pod)
-        self.stats.pod_parks += 1
-        if T.enabled():
-            _metrics()["pods_parked"].set(len(self._parked))
-            T.instant(
-                "engine.pod_park", cat="engine", pod=pod,
-                device_class=self.asym.class_of_pod(pod).name,
-                n_work=n_work, parked=sorted(self._parked),
-            )
-
-    def _unpark(self, pod: int, n_work: int):
-        self._parked.discard(pod)
-        self.stats.pod_unparks += 1
-        if T.enabled():
-            _metrics()["pods_parked"].set(len(self._parked))
-            T.instant(
-                "engine.pod_unpark", cat="engine", pod=pod,
-                device_class=self.asym.class_of_pod(pod).name,
-                n_work=n_work, parked=sorted(self._parked),
-            )
-
-    def _admission_pods(self, ci: int) -> list[int]:
-        """The pods class ``ci``'s queue may admit into: the class's
-        unparked pods; when the whole class is parked, the unparked pods
-        of other classes, most efficient first (the queue must not starve
-        behind a parked class — nor silently defeat parking by admitting
-        into it)."""
-
-        pods = [
-            p for p, c in enumerate(self._pod_class)
-            if c == ci and p not in self._parked
-        ]
-        if not pods:
-            pods = [
-                p for p in self.asym.pods_by_efficiency() if p not in self._parked
-            ]
-        return pods
 
     def _pod_active(self) -> list[int]:
         act = (self.slot_rid >= 0).reshape(self.n_pods, self.c_max)
@@ -922,7 +808,7 @@ class ServingEngine:
 
         out = []
         for ci, q in enumerate(self.queues):
-            pods = self._admission_pods(ci)
+            pods = [p for p, c in enumerate(self._pod_class) if c == ci]
             while q:
                 req = q[0]
                 slot = None
@@ -1045,7 +931,7 @@ class ServingEngine:
                 self.stats.decode_s += dt - compile_s
                 self.stats.decode_steps += 1
                 self.stats.tokens += n_active
-                self._account_energy(units)
+                self._advance_modeled_clock(units)
                 self._step_calls += 1
                 self._pos += 1  # every slot ages (phantom rows match one-shot padding)
 
@@ -1121,36 +1007,16 @@ class ServingEngine:
         act = active_mask.reshape(self.n_pods, self.c_max)
         return [int(a.sum()) for a in act]
 
-    def _account_energy(self, units: Sequence[int]):
-        """Modeled joules for one steady-state decode step.
+    def _advance_modeled_clock(self, units: Sequence[int]):
+        """Advance ``stats.modeled_decode_s`` by one decode step: the
+        slowest pod's row count over its aggregate throughput (×
+        :data:`MODELED_ROW_S` — the SPMD barrier means every pod waits for
+        the straggler).  Deterministic — no wall clocks."""
 
-        The step's modeled span is the slowest pod's row count over its
-        aggregate throughput (× :data:`MODELED_ROW_S` — the SPMD barrier
-        means every pod waits for the straggler).  Per-pod draw over the
-        span: a pod with rows interpolates idle→active by occupancy; an
-        empty parked pod draws gated watts; an empty unparked pod polls
-        (the paper's idle-but-active cores).  Deterministic — no wall
-        clocks — so the bench's energy column is host-independent.
-        """
-
-        span = MODELED_ROW_S * max(
+        self.stats.modeled_decode_s += MODELED_ROW_S * max(
             (u / agg for u, agg in zip(units, self._pod_agg) if agg > 0),
             default=0.0,
         )
-        if span <= 0:
-            return
-        watts = 0.0
-        for p, u in enumerate(units):
-            if u > 0:
-                watts += self._idle_w[p] + (
-                    self._active_w[p] - self._idle_w[p]
-                ) * u / self.c_max
-            elif p in self._parked:
-                watts += self._gated_w[p]
-            else:
-                watts += self._poll_w[p]
-        self.stats.energy_j += watts * span
-        self.stats.modeled_decode_s += span
 
     # -- KV memory accounting ---------------------------------------------------
 

@@ -35,12 +35,6 @@ search stored the scorer under ``"backend"`` — consumers treat any value
 outside the dispatch table as "no variant recorded", so old caches keep
 working with the default kernel.
 
-``"objective"`` records what the search minimized (``"perf"`` seconds,
-``"energy"`` modeled joules, ``"edp"`` joules·seconds); entries predating
-the field are ``"perf"``.  ``time_s``/``analytical_time_s`` are in the
-objective's units.  The tuner treats an entry tuned under a different
-objective as a miss — its winner optimized the wrong metric.
-
 Writes are atomic and durable (``repro.util.atomic``: tempfile + fsync +
 ``os.replace``) so a crashed tuner never leaves a torn cache for a
 training job to read.

@@ -9,11 +9,6 @@ fill.  The analytical optimum of :func:`derive_block_config` is always a
 member — the search can therefore only match or beat it — and an explicit
 neighborhood around it provides the paper's "refine near the model's
 prediction" structure.
-
-Candidates are objective-agnostic: the same feasible set is scored in
-seconds, joules, or J·s depending on the tuner's ``--objective`` (see
-``measure.cost_model_score``); each spec's :class:`~repro.core.blocking.
-PowerModel` prices the energy objectives.
 """
 
 from __future__ import annotations

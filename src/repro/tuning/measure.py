@@ -29,9 +29,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional
 
-from repro.core.blocking import TPU_V5E, BlockConfig, PowerModel, TpuCoreSpec, pad_to_blocks
+from repro.core.blocking import TPU_V5E, BlockConfig, TpuCoreSpec, pad_to_blocks
 from repro.core.execution import backend_double_buffers
-from repro.core.schedule import validate_objective
 
 # Fixed cost per grid step (DMA issue + pipeline bubble).  Order of
 # magnitude from TPU kernel practice; the precise value only needs to rank
@@ -56,10 +55,6 @@ class CostBreakdown:
     grid: tuple[int, int, int]
     # Micro-kernel variant the estimate models; decides stream overlap.
     kernel_backend: str = "pallas"
-    # Work totals and the power model that prices them (energy objectives).
-    flops: float = 0.0
-    hbm_bytes: float = 0.0
-    power: Optional[PowerModel] = None
 
     @property
     def time_s(self) -> float:
@@ -77,30 +72,6 @@ class CostBreakdown:
     @property
     def bottleneck(self) -> str:
         return "compute" if self.compute_s >= self.memory_s else "memory"
-
-    @property
-    def energy_j(self) -> float:
-        """Modeled joules: idle draw over the step plus activity terms."""
-
-        if self.power is None:
-            raise ValueError("CostBreakdown has no power model attached")
-        return self.power.energy_j(self.time_s, self.flops, self.hbm_bytes)
-
-    @property
-    def edp(self) -> float:
-        """Energy-delay product (J·s), the balanced objective."""
-
-        return self.energy_j * self.time_s
-
-    def score(self, objective: str = "perf") -> float:
-        """The scalar the tuner minimizes under ``objective``."""
-
-        validate_objective(objective)
-        if objective == "perf":
-            return self.time_s
-        if objective == "energy":
-            return self.energy_j
-        return self.edp
 
 
 def cost_breakdown(
@@ -138,9 +109,6 @@ def cost_breakdown(
         overhead_s=gm * gn * gk * GRID_STEP_OVERHEAD_S,
         grid=(gm, gn, gk),
         kernel_backend=kernel_backend,
-        flops=flops,
-        hbm_bytes=float(a_bytes + b_bytes + c_bytes),
-        power=spec.power,
     )
 
 
@@ -153,29 +121,11 @@ def cost_model_time(
     spec: TpuCoreSpec = TPU_V5E,
     kernel_backend: str = "pallas",
 ) -> float:
-    """Scalar objective (seconds) of the cost-model backend."""
+    """Seconds the cost-model backend estimates."""
 
     return cost_breakdown(
         m, k, n, cfg, spec=spec, kernel_backend=kernel_backend
     ).time_s
-
-
-def cost_model_score(
-    m: int,
-    k: int,
-    n: int,
-    cfg: BlockConfig,
-    *,
-    spec: TpuCoreSpec = TPU_V5E,
-    kernel_backend: str = "pallas",
-    objective: str = "perf",
-) -> float:
-    """Scalar objective of the cost-model backend: seconds (``perf``),
-    joules (``energy``), or J·s (``edp``) — see :meth:`CostBreakdown.score`."""
-
-    return cost_breakdown(
-        m, k, n, cfg, spec=spec, kernel_backend=kernel_backend
-    ).score(objective)
 
 
 def wallclock_time(
@@ -238,30 +188,19 @@ def make_backend(
     *,
     spec: TpuCoreSpec = TPU_V5E,
     dtype=None,
-    objective: str = "perf",
 ) -> Callable[..., float]:
-    """Resolve a backend name to a ``(m, k, n, cfg) -> score`` scorer.
+    """Resolve a backend name to a ``(m, k, n, cfg) -> seconds`` scorer.
 
     Scorers also accept ``kernel_backend=`` (the micro-kernel variant
     being scored; default ``"pallas"``) — the search passes it when the
-    variant dimension is enabled.  ``objective`` selects what the score
-    measures (seconds / joules / J·s); only the cost model can price
-    energy — a wall clock measures seconds, not watts — so ``wallclock``
-    with a non-``perf`` objective raises.
+    variant dimension is enabled.
     """
 
-    validate_objective(objective)
     if name == "cost-model":
-        return lambda m, k, n, cfg, kernel_backend="pallas": cost_model_score(
-            m, k, n, cfg, spec=spec, kernel_backend=kernel_backend,
-            objective=objective,
+        return lambda m, k, n, cfg, kernel_backend="pallas": cost_model_time(
+            m, k, n, cfg, spec=spec, kernel_backend=kernel_backend
         )
     if name == "wallclock":
-        if objective != "perf":
-            raise ValueError(
-                f"wallclock backend cannot score objective {objective!r}; "
-                "the host clock measures seconds, not joules — use cost-model"
-            )
         return lambda m, k, n, cfg, kernel_backend="pallas": wallclock_time(
             m, k, n, cfg, dtype=dtype, kernel_backend=kernel_backend
         )
@@ -274,7 +213,6 @@ __all__ = [
     "CostBreakdown",
     "cost_breakdown",
     "cost_model_time",
-    "cost_model_score",
     "wallclock_time",
     "make_backend",
 ]

@@ -231,7 +231,6 @@ def tune_shapes(
     two_stage: Optional[bool] = None,
     coarse_keep: int = 8,
     kernel_backends: Sequence[str] = CAND.KERNEL_BACKENDS,
-    objective: str = "perf",
 ) -> list[SearchResult]:
     """Library entry point: search ``shapes``, updating ``cache`` in place.
 
@@ -242,18 +241,10 @@ def tune_shapes(
     The micro-kernel variant is a search dimension by default
     (``kernel_backends``); the cache entry records the winner under
     ``"backend"`` and the scorer under ``"measured_with"``.
-
-    ``objective`` selects what the search minimizes (seconds, joules, or
-    energy-delay product — cost-model backend only); the cache entry
-    records it, and a cached entry tuned under a *different* objective is
-    re-scored rather than trusted (its winner optimized the wrong metric).
     """
 
-    from repro.core.schedule import validate_objective
-
-    validate_objective(objective)
     dtype_name, dtype_bytes = DTYPES[dtype]
-    backend = M.make_backend(backend_name, spec=spec, objective=objective)
+    backend = M.make_backend(backend_name, spec=spec)
     if two_stage is None:
         two_stage = backend_name == "wallclock"
     prefilter = (
@@ -270,17 +261,6 @@ def tune_shapes(
         cached = cache.get(spec.name, dtype_name, m, k, n) if cache else None
         if cached is not None and not force:
             key = C.shape_bucket_key(spec.name, dtype_name, m, k, n)
-            # Entries tuned under a different objective optimized the wrong
-            # metric — their winner is not this search's winner.  Treat as a
-            # miss (entries predating the objective field scored seconds).
-            entry_obj = cache.entries.get(key, {}).get("objective", "perf")
-            if entry_obj != objective:
-                log.info(
-                    "cache entry for %s tuned for objective %r, want %r — re-searching",
-                    key, entry_obj, objective,
-                )
-                cached = None
-        if cached is not None and not force:
             log.info("cache hit for %s — skipping search (use --force to redo)", key)
             if T.enabled():
                 _obs_metrics()["cache"].labels(result="hit").inc()
@@ -350,7 +330,6 @@ def tune_shapes(
                 measured_with=backend_name,
                 time_s=res.best_time_s,
                 analytical_time_s=res.analytical_time_s,
-                objective=objective,
             )
         results.append(res)
     return results
@@ -365,9 +344,6 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
     ap.add_argument("--shapes", default=None, help="comma-separated MxKxN list")
     ap.add_argument("--dtype", default="bf16", choices=sorted(DTYPES))
     ap.add_argument("--backend", default="cost-model", choices=["cost-model", "wallclock"])
-    ap.add_argument("--objective", default="perf", choices=["perf", "energy", "edp"],
-                    help="what the search minimizes: seconds, modeled joules, "
-                         "or energy-delay product (cost-model backend only)")
     ap.add_argument(
         "--kernel-backends", default=",".join(CAND.KERNEL_BACKENDS),
         help="comma-separated micro-kernel variants to search (e.g. "
@@ -416,13 +392,11 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
         two_stage={"auto": None, "on": True, "off": False}[args.two_stage],
         coarse_keep=args.coarse_keep,
         kernel_backends=kernel_backends,
-        objective=args.objective,
     )
 
     summary: dict = {
         "spec": spec.name,
         "backend": args.backend,
-        "objective": args.objective,
         "dtype": args.dtype,
         "cache_path": None if args.dry_run else cache_path,
         "shapes": [

@@ -44,23 +44,13 @@ class _StubStats:
         self.modeled_decode_s = 0.0
 
 
-class _StubAsym:
-    """Just enough ``asym`` for Fleet's default ``powers``."""
-
-    def __init__(self, watts: float):
-        self._watts = watts
-
-    def pod_active_watts(self):
-        return [self._watts]
-
-
 class StubEngine:
     """Slot-table serving semantics without jax: one class queue,
     ``speed`` generated tokens per slot per step on a modeled clock of
     ``1/speed`` seconds per step (so calibrated tps == active slots ×
     speed, like the real engine's row-rate calibration)."""
 
-    def __init__(self, n_slots: int = 2, speed: float = 1.0, watts: float = 10.0):
+    def __init__(self, n_slots: int = 2, speed: float = 1.0):
         if n_slots < 1 or speed <= 0:
             raise ValueError("need n_slots >= 1 and speed > 0")
         self.n_slots = int(n_slots)
@@ -73,7 +63,6 @@ class StubEngine:
         self._next_rid = 0
         self.completions: list[StubCompletion] = []
         self.stats = _StubStats()
-        self.asym = _StubAsym(watts)
 
     # -- the engine API the fleet drives ----------------------------------
 

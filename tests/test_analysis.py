@@ -103,18 +103,6 @@ class TestFixtureCorpus:
             ("RPR005", 14),
         ]
 
-    def test_objective_drift_bug_class(self):
-        # Same bug class, objective arm: comparison, keyword, and
-        # validate_objective funnel — the valid tokens and the argparse
-        # choices enum must pass.
-        diags = analyze_file(fx("objective_drift.py"))
-        assert code_lines(diags) == [
-            ("RPR005", 11),
-            ("RPR005", 12),
-            ("RPR005", 13),
-        ]
-        assert all("schedule.OBJECTIVES" in d.message for d in diags)
-
     def test_fault_point_drift_bug_class(self):
         # The ISSUE-10 class: each trigger form (funnel argument, point=
         # keyword, FAULT_POINTS subscript) fires once; the valid-token
@@ -136,20 +124,7 @@ class TestFixtureCorpus:
             src = f.read()
         vocab = analysis_cli.build_vocabulary()
         assert ast_checks.run_ast_checks(
-            fx("fault_point_drift.py"), src, vocab,
-            objectives=analysis_cli.build_objectives(), fault_points=None,
-        ) == []
-
-    def test_objective_checks_off_without_vocabulary(self):
-        # objectives=None disables only the objective arm; backend drift
-        # still fires.
-        from repro.analysis import ast_checks
-
-        with open(fx("objective_drift.py"), encoding="utf-8") as f:
-            src = f.read()
-        vocab = analysis_cli.build_vocabulary()
-        assert ast_checks.run_ast_checks(
-            fx("objective_drift.py"), src, vocab, objectives=None
+            fx("fault_point_drift.py"), src, vocab, fault_points=None,
         ) == []
 
     def test_suppression_semantics(self):
@@ -210,43 +185,6 @@ class TestConfigContracts:
     def test_artifacts_dir_globs_bench_files(self):
         diags = configcheck.check_artifacts_dir(FIXTURES)
         assert diags and all(d.code == "RPR202" for d in diags)
-
-    def test_objective_ab_block_schema(self, tmp_path):
-        # The serving bench's energy A/B block: a well-formed block is
-        # clean; dropping a column, faking the objective name, or losing
-        # token identity each surface as RPR202.
-        def artifact(ab):
-            payload = {
-                "meta": {"git_sha": "x", "jax_version": "y", "timestamp": "z"},
-                "records": [{"name": "serve", "objective_ab": ab}],
-            }
-            p = tmp_path / "BENCH_serving.json"
-            p.write_text(json.dumps(payload))
-            return str(p)
-
-        good = {
-            "objective": "energy",
-            "perf": {"energy_j": 7.5, "tokens_per_j": 4.0},
-            "energy": {"energy_j": 2.9, "tokens_per_j": 10.2},
-            "tokens_identical": True,
-            "energy_ratio": 0.39,
-            "throughput_ratio": 0.33,
-        }
-        assert configcheck.check_bench_artifact(artifact(good)) == []
-
-        no_col = json.loads(json.dumps(good))
-        del no_col["energy"]["energy_j"]
-        diags = configcheck.check_bench_artifact(artifact(no_col))
-        assert {d.code for d in diags} == {"RPR202"}
-        assert "energy_j" in diags[0].message
-
-        perf_named = dict(good, objective="perf")
-        diags = configcheck.check_bench_artifact(artifact(perf_named))
-        assert any("non-perf" in d.message for d in diags)
-
-        diverged = dict(good, tokens_identical=False)
-        diags = configcheck.check_bench_artifact(artifact(diverged))
-        assert any("tokens_identical" in d.message for d in diags)
 
 
 # ---------------------------------------------------------------------------

@@ -12,14 +12,12 @@ The ISSUE-10 acceptance criteria, as tests:
     never consults a plan;
   * **health hysteresis** — ``unhealthy_after`` consecutive bad ticks
     trip an engine, ``healthy_after`` good ticks restore it;
-  * **fleet parking** — under the energy objective the least efficient
-    engine drains and gates at low load and re-admits as load ramps;
   * the engine's fleet surface (``withdraw`` / ``export_queued``) rolls
     the router's counts back so future routing reflects kept work only.
 
 Real engines (row-local arch — greedy decode is a pure function of each
 request's own prompt) prove bit-identity; the numpy ``fleetstub`` engine
-covers the control-plane paths (health, parking, deadlines, streaming)
+covers the control-plane paths (health, deadlines, streaming)
 where jit time would buy nothing.
 """
 
@@ -260,7 +258,7 @@ def test_fleet_routes_proportional_to_throughput():
 
 
 # ---------------------------------------------------------------------------
-# Control plane on the stub: health, parking, deadlines, streaming
+# Control plane on the stub: health, deadlines, streaming
 # ---------------------------------------------------------------------------
 
 
@@ -284,29 +282,6 @@ def test_health_hysteresis_trip_and_recover():
         fleet.run()
     assert fleet.stats.completed == fleet.stats.submitted
     assert fleet.stats.duplicate_completions == 0
-
-
-def test_energy_objective_parks_and_unparks_engines():
-    thrifty = StubEngine(n_slots=2, watts=1.0)
-    hungry = StubEngine(n_slots=2, watts=100.0)
-    fleet = Fleet([thrifty, hungry], objective="energy")
-    fleet.submit(np.asarray([1], np.int32), 2)
-    fleet.tick()
-    assert fleet.health()["parked"] == [1]  # watts/rate orders the parking
-    assert fleet.stats.engine_parks >= 1
-    for i in range(6):  # load past the survivor's capacity re-admits
-        fleet.submit(np.asarray([i], np.int32), 4)
-    fleet.tick()
-    assert fleet.stats.engine_unparks >= 1
-    fleet.run()
-    assert fleet.stats.completed == fleet.stats.submitted
-
-
-def test_perf_objective_never_parks():
-    fleet = _stub_fleet()
-    fleet.submit(np.asarray([1], np.int32), 2)
-    fleet.run()
-    assert fleet.stats.engine_parks == 0
 
 
 def test_deadline_requeues_stranded_request():

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.core import schedule as S
+from repro.core.asymmetric import AsymmetricMesh, DeviceClass
 
 
 class TestStatic:
@@ -203,3 +204,21 @@ class TestDynamicScheduler:
             S.balanced_ratio([])
         with pytest.raises(ValueError):
             S.balanced_ratio([1.0, 0.0])
+
+
+class TestSlotBudgets:
+    def test_slot_spill_prefers_aggregate_throughput(self):
+        # Regression: spill used to rank by rel_throughput alone, so a
+        # one-chip pod with high per-chip throughput absorbed spill before
+        # a many-chip pod with far more aggregate capacity.  chips 1/2/8 at rel 1.0/0.9/0.5 → aggregate
+        # 1.0/1.8/4.0 → spill lands on pod 2 first.
+        classes = [
+            DeviceClass(name="solo", chips_per_pod=1, rel_throughput=1.0),
+            DeviceClass(name="duo", chips_per_pod=2, rel_throughput=0.9),
+            DeviceClass(name="octo", chips_per_pod=8, rel_throughput=0.5),
+        ]
+        asym = AsymmetricMesh(classes, strategy="ca-das", batch_tile=1)
+        budgets = asym.slot_budgets(4, 10)
+        assert sum(budgets) == 10
+        assert budgets[2] == 4  # largest aggregate pod saturates first
+        assert budgets == [2, 4, 4]
